@@ -6,15 +6,17 @@ compile cache turns a recompile into a disk deserialize. This bench
 measures all three arms on the same train-grade function (SwinIR loss +
 grad, the headline model):
 
-    loop_cold    unrolled RSTB layers, empty persistent cache
+    loop_cold    unrolled RSTB layers, persistent cache off
     loop_cached  same program, cache populated -> deserialize
-    scan_cold    nn.scan'd RSTB pairs, empty persistent cache
+    scan_cold    nn.scan'd RSTB pairs, persistent cache off
     scan_cached  same, cache populated
 
 Between arms the in-process jit/tracing caches are cleared
 (``jax.clear_caches()``) so "cached" isolates the PERSISTENT cache path —
-what a fresh process would pay — and each cold arm compiles into its own
-empty cache dir.
+what a fresh process would pay. Cold arms compile with persistence off, so
+they are cold whatever earlier runs left in the shared cache directory
+(``runtime.cache.cache_dir()``); cached arms compile once untimed to
+populate it, then time the reload.
 
 Prints one JSON line per arm {"arm", "compile_s", "cache_entries"} and a
 final {"summary": ...} with the scan-vs-loop cold speedup. Runs on any
@@ -29,8 +31,6 @@ from __future__ import annotations
 
 import json
 import os
-import shutil
-import tempfile
 import time
 
 import _bootstrap  # noqa: F401  (repo root on sys.path)
@@ -46,10 +46,12 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
+    from jax.experimental.compilation_cache import compilation_cache as cc
 
     from pytorch_distributedtraining_tpu.models.swinir import SwinIR
     from pytorch_distributedtraining_tpu.runtime.cache import (
         cache_entry_count,
+        enable_compile_cache,
     )
 
     heads = max(1, DIM // 10)
@@ -72,20 +74,19 @@ def main() -> None:
         rng.random((BATCH, 2 * PATCH, 2 * PATCH, 3), dtype=np.float32)
     )
 
-    def timed_compile(model, params, cache_dir: str) -> tuple[float, int]:
-        """Seconds to AOT-compile loss+grad with the given persistent
-        cache dir; in-process caches cleared first so the persistent tier
-        is the only reuse path (what a fresh process would see)."""
-        jax.clear_caches()
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        try:  # the cache module latches its dir at first use — re-point it
-            from jax.experimental.compilation_cache import (
-                compilation_cache as cc,
-            )
+    cdir = enable_compile_cache()
+    if cdir is None:
+        raise SystemExit("compile_bench needs the persistent compile cache")
+    # even tiny programs must land in the persistent cache
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
-            cc.reset_cache()
-        except Exception:
-            pass
+    def compile_once(model, params, *, persistent: bool) -> float:
+        """Seconds to AOT-compile loss+grad; in-process caches cleared
+        first so the persistent tier is the only reuse path (what a fresh
+        process would see)."""
+        jax.clear_caches()
+        jax.config.update("jax_enable_compilation_cache", persistent)
+        cc.reset_cache()  # the cache module latches on/off at first use
 
         def loss_fn(p):
             out = model.apply({"params": p}, lr_img)
@@ -93,30 +94,21 @@ def main() -> None:
 
         t0 = time.perf_counter()
         jax.jit(jax.value_and_grad(loss_fn)).lower(params).compile()
-        return time.perf_counter() - t0, cache_entry_count(cache_dir)
-
-    try:  # even tiny programs must land in the persistent cache
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
-        pass
+        return time.perf_counter() - t0
 
     rows = []
-    tmp = tempfile.mkdtemp(prefix="compile_bench_cache_")
-    try:
-        for kind, scan in (("loop", False), ("scan", True)):
-            model = build(scan)
-            params = model.init(jax.random.PRNGKey(0), lr_img)["params"]
-            cdir = os.path.join(tmp, kind)
-            os.makedirs(cdir, exist_ok=True)
-            for arm in (f"{kind}_cold", f"{kind}_cached"):
-                dt, entries = timed_compile(model, params, cdir)
-                rows.append(
-                    {"arm": arm, "compile_s": round(dt, 3),
-                     "cache_entries": entries}
-                )
-                print(json.dumps(rows[-1]), flush=True)
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
+    for kind, scan in (("loop", False), ("scan", True)):
+        model = build(scan)
+        params = model.init(jax.random.PRNGKey(0), lr_img)["params"]
+        cold = compile_once(model, params, persistent=False)
+        compile_once(model, params, persistent=True)  # populate, untimed
+        cached = compile_once(model, params, persistent=True)
+        for arm, dt in ((f"{kind}_cold", cold), (f"{kind}_cached", cached)):
+            rows.append(
+                {"arm": arm, "compile_s": round(dt, 3),
+                 "cache_entries": cache_entry_count(cdir)}
+            )
+            print(json.dumps(rows[-1]), flush=True)
 
     by_arm = {r["arm"]: r["compile_s"] for r in rows}
     print(json.dumps({

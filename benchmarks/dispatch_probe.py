@@ -1,6 +1,6 @@
 """Micro-probe: per-dispatch cost vs per-step compute through the link.
 
-Round-4 anomaly (BASELINE.md): a 200-step on-device `lax.scan` of the
+Round-4 anomaly: a 200-step on-device `lax.scan` of the
 flagship step replayed ~90x SLOWER than 200 host dispatches of the same
 body, while the host loop itself is dispatch-bound (~1.5 ms/step on a
 1-core VM against ~0.8 ms of compute). This probe separates the candidate

@@ -1,6 +1,6 @@
 """Quality experiment: which perceptual loss trains the better SR model?
 
-VERDICT r1 item 8: the reference's ``feat_loss`` is a pretrained-VGG
+The reference's ``feat_loss`` is a pretrained-VGG
 perceptual loss (`/root/reference/Stoke-DDP.py:35,224`); no VGG weights can
 exist in this zero-egress build env, so this experiment quantifies what the
 shipped fallbacks give up. Trains the same ESPCN ``Net`` from the same init
@@ -15,7 +15,7 @@ reports held-out PSNR/MAE (the reference's own quality metrics,
 
 Images are sums of random low-frequency Fourier modes plus sharp box edges
 — smooth regions AND discontinuities, so pixel vs feature losses actually
-trade off. One JSON line per arm. Results recorded in BASELINE.md.
+trade off. One JSON line per arm.
 """
 
 from __future__ import annotations

@@ -28,7 +28,7 @@ post-degrade steps (zero hung ranks) land in the summary record.
 
 Prints one JSON line per arm plus a final summary record
 (``metric: "hier"``, headline ``dcn_bytes`` — lower is better) for
-harvest_results.py and the regression sentry.
+the regression sentry.
 ``GRAFT_HIER_BENCH_STEPS`` / ``_BATCH`` / ``_DIM`` / ``_FAULT_S``
 resize the run.
 """

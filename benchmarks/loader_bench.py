@@ -1,10 +1,9 @@
-"""DataLoader worker-mode benchmark: threads vs processes (VERDICT r3 #8).
+"""DataLoader worker-mode benchmark: threads vs processes.
 
 Two workloads over the same synthetic dataset:
 
 - ``decode``: PIL-style work that RELEASES the GIL (numpy box-downsample
-  on a large buffer) — the case the thread pool was measured adequate for
-  (BASELINE.md input-pipeline table);
+  on a large buffer) — the case the thread pool was measured adequate for;
 - ``gil``: a pure-Python per-sample transform that HOLDS the GIL (the
   numpy-heavy-augmentation-in-Python-loops case) — the workload the
   ``multiprocessing_context`` process-pool escape hatch exists for.

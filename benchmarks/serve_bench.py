@@ -372,7 +372,7 @@ def run_serve_bench(*, realtime: bool = True) -> dict:
         "spec": spec,
         "kvq": kvq,
         "continuous_beats_static": beats,
-        # decode fast-path headlines (harvest_results.py serve_spec stage)
+        # decode fast-path headlines
         "spec_k": spec["spec_k"],
         "accept_rate": spec["accept_rate"],
         "decode_tokens_per_sec_spec": spec["decode_tokens_per_sec"],
@@ -405,9 +405,11 @@ def main() -> None:
         jax.config.update("jax_platforms", "cpu")
     import jax
 
-    from pytorch_distributedtraining_tpu.runtime.cache import cache_dir
+    from pytorch_distributedtraining_tpu.runtime.cache import (
+        enable_compile_cache,
+    )
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir("bench"))
+    enable_compile_cache()
     record = run_serve_bench()
     assert record["steady_recompiles"] == 0, (
         "serving engine recompiled during the steady-state window: "

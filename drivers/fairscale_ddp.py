@@ -522,9 +522,9 @@ def main(argv=None):
     ):
         os.environ["GRAFT_CAPTURE"] = opt.capture
 
-    # GRAFT_PLATFORM=cpu forces the backend (see runtime.dist docstring:
-    # some images re-latch JAX_PLATFORMS before user code runs)
+    # GRAFT_PLATFORM=cpu selects the backend after jax is imported
     runtime.force_platform_from_env()
+    runtime.enable_compile_cache()
 
     # env rendezvous exactly like the reference __main__ (:122-123); under
     # SPMD the single controller drives all devices, no mp.spawn fork

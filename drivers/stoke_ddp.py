@@ -311,9 +311,9 @@ def main(argv=None):
     opt = build_parser().parse_args(argv)
     epochs = opt.nEpochs
 
-    # GRAFT_PLATFORM=cpu forces the backend (see runtime.dist docstring:
-    # some images re-latch JAX_PLATFORMS before user code runs)
+    # GRAFT_PLATFORM=cpu selects the backend after jax is imported
     runtime.force_platform_from_env()
+    runtime.enable_compile_cache()
 
     amp_config = AMPConfig(init_scale=2.0**14)
     local_rank = os.getenv("LOCAL_RANK")

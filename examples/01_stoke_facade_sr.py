@@ -5,8 +5,7 @@ This is the loop of `/root/reference/Stoke-DDP.py:70-86` — forward via
 reporting — with the same declarative knobs (grad accumulation x2, grad-norm
 clip 0.1, AdamW + OneCycle). Under the eager-feeling surface each
 backward()+step() accumulation window runs as ONE compiled XLA program
-(``fuse_eager_step``, measured 0.989x of the raw compiled TrainStep on a
-real TPU chip — BASELINE.md round 4).
+(``fuse_eager_step``).
 
 Runs on host CPU by default (seconds); ``EXAMPLE_PLATFORM=tpu`` uses real
 hardware.

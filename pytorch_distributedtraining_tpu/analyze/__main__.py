@@ -324,8 +324,7 @@ def _main_source(args, ignore) -> int:
     report = source_report(ignore=ignore)
     print("analyzing repo source (plane: source)")
     print(report.render())
-    # one JSON summary line: benchmarks/harvest_results.py renders stage
-    # output from JSON lines only — this is what the `source` stage shows
+    # one JSON summary line, for tooling that reads JSON lines only
     import json
 
     print(json.dumps({

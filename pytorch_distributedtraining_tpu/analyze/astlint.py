@@ -212,10 +212,7 @@ def iter_source_files(root: str):
     for d in _SCAN_DIRS:
         base = os.path.join(root, d)
         for dirpath, dirnames, filenames in os.walk(base):
-            dirnames[:] = sorted(
-                x for x in dirnames
-                if x not in ("__pycache__", "results_r5")
-            )
+            dirnames[:] = sorted(x for x in dirnames if x != "__pycache__")
             for fn in sorted(filenames):
                 if fn.endswith(".py"):
                     rel = os.path.relpath(os.path.join(dirpath, fn), root)

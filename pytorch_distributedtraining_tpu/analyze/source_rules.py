@@ -79,7 +79,6 @@ _FENCE_WINDOW_LINES = 4
 # and the fleet/serve tooling routes — all on hosts where the jax wheel
 # may be broken mid-incident. Grow this list, never shrink it silently.
 STDLIB_ONLY_MODULES = (
-    "pytorch_distributedtraining_tpu/_hostfp.py",
     "pytorch_distributedtraining_tpu/runtime/membership.py",
     "pytorch_distributedtraining_tpu/runtime/recovery_drill.py",
     "pytorch_distributedtraining_tpu/observe/trace.py",

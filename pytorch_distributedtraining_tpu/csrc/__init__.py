@@ -6,7 +6,11 @@ the binding layer is a plain C ABI + ctypes — zero-copy in both directions
 (numpy owns the buffers; C++ only reads/writes through raw pointers).
 
 Every entry point has a numpy fallback, so the package works without a
-toolchain; ``available()`` reports which path is live.
+toolchain; ``available()`` reports which path is live, and a build that
+fails says so (a warning naming the compiler's error) before the numpy path
+takes over. ``rebuild()`` discards whatever library is on disk and builds
+the source again, raising on failure — chip_smoke.py's guard against a
+stale or foreign ``_fastpipe.so`` riding along in a copied tree.
 """
 
 from __future__ import annotations
@@ -16,6 +20,7 @@ import os
 import subprocess
 import tempfile
 import threading
+import warnings
 
 import numpy as np
 
@@ -27,7 +32,11 @@ _lib = None
 _tried = False
 
 
-def _build() -> str | None:
+class BuildError(RuntimeError):
+    """g++ could not build fastpipe.cpp (message carries its stderr)."""
+
+
+def _build() -> str:
     if os.path.exists(_LIB_PATH) and os.path.getmtime(_LIB_PATH) >= os.path.getmtime(_SRC):
         return _LIB_PATH
     # build into a temp file then atomically rename (parallel-import safe)
@@ -41,10 +50,32 @@ def _build() -> str | None:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, _LIB_PATH)
         return _LIB_PATH
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as e:
         if os.path.exists(tmp):
             os.unlink(tmp)
-        return None
+        stderr = getattr(e, "stderr", b"") or b""
+        raise BuildError(
+            f"{' '.join(cmd[:-2])}: {e}\n{stderr.decode(errors='replace')}"
+        ) from e
+
+
+def _bind(path: str):
+    lib = ctypes.CDLL(path)
+    lib.fp_stack.argtypes = [
+        ctypes.POINTER(ctypes.c_void_p), ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_void_p, ctypes.c_int,
+    ]
+    lib.fp_normalize_u8.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_float), ctypes.POINTER(ctypes.c_float),
+        ctypes.c_int,
+    ]
+    lib.fp_stack_strided.argtypes = [
+        ctypes.POINTER(ctypes.c_void_p), ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p, ctypes.c_int,
+    ]
+    lib.fp_version.restype = ctypes.c_int
+    return lib
 
 
 def _load():
@@ -53,29 +84,29 @@ def _load():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        path = _build()
-        if path is None:
-            return None
         try:
-            lib = ctypes.CDLL(path)
-        except OSError:
-            return None
-        lib.fp_stack.argtypes = [
-            ctypes.POINTER(ctypes.c_void_p), ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_void_p, ctypes.c_int,
-        ]
-        lib.fp_normalize_u8.argtypes = [
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_float), ctypes.POINTER(ctypes.c_float),
-            ctypes.c_int,
-        ]
-        lib.fp_stack_strided.argtypes = [
-            ctypes.POINTER(ctypes.c_void_p), ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p, ctypes.c_int,
-        ]
-        lib.fp_version.restype = ctypes.c_int
-        _lib = lib
+            _lib = _bind(_build())
+        except (BuildError, OSError) as e:
+            warnings.warn(
+                f"native fastpipe unavailable, numpy fallback is live: {e}",
+                RuntimeWarning,
+                stacklevel=3,
+            )
         return _lib
+
+
+def rebuild() -> bool:
+    """Discard any ``_fastpipe.so`` on disk, build the source as it stands
+    and load the result. Raises :class:`BuildError` / ``OSError`` instead
+    of falling back. Returns whether a library had to be discarded."""
+    global _lib, _tried
+    with _lock:
+        found = os.path.exists(_LIB_PATH)
+        if found:
+            os.unlink(_LIB_PATH)
+        _lib, _tried = None, True
+        _lib = _bind(_build())
+        return found
 
 
 def available() -> bool:

@@ -147,7 +147,7 @@ class PatchStore(Dataset):
 
     The reference re-decodes PNG patches through 16 worker processes every
     epoch (`/root/reference/Stoke-DDP.py:286-298`); on a TPU host the
-    decode is the input-pipeline bottleneck (BASELINE.md: ~1.8k img/s/core
+    decode is the input-pipeline bottleneck (CPU timing: ~1.8k img/s/core
     PIL vs 7.4k img/s from a memmap store on ONE core). ``PatchStore.build``
     runs the decode exactly once, writing uint8 ``lr.npy``/``hr.npy``
     arrays; training then streams patches at memcpy speed via memmap (no

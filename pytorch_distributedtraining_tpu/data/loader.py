@@ -346,8 +346,7 @@ class DataLoader:
         asymmetric ``iter()`` (one rank re-creating an iterator, or a
         mid-epoch resume) silently desyncs the shards across ranks. Warn
         once, on the second auto-bumped epoch of a multi-process run where
-        the user never called ``set_epoch`` explicitly (VERDICT r2 weak #5
-        — the guard was previously only a docstring note).
+        the user never called ``set_epoch`` explicitly.
         """
         if self._warned_desync or self._explicit_epoch or self._iter_count < 2:
             return

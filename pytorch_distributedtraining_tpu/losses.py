@@ -60,7 +60,7 @@ class FeatLoss:
        not initialize a backend), which changed the filter values for a
        given ``seed``. Loss *curves* are therefore not numerically
        comparable across that upgrade; convergence behavior and the
-       SR-quality ablation (BASELINE.md r2) are unaffected. See
+       SR-quality ablation are unaffected. See
        MIGRATION.md.
     """
 
@@ -94,8 +94,7 @@ class VGGFeatLoss:
     No VGG weights ship in this repo (zero-egress build environment), so
     the no-argument constructor falls back to deterministic He-init
     filters. The quality experiment backing that fallback is
-    ``benchmarks/feat_loss_ablation.py`` with results recorded in
-    BASELINE.md — random deep features still provide multi-scale structure
+    ``benchmarks/feat_loss_ablation.py`` — random deep features still provide multi-scale structure
     the pixel losses miss, but users wanting exact reference parity should
     pass the checkpoint.
     """

@@ -141,11 +141,13 @@ class WindowAttention(nn.Module):
     # math for every choice (checkpoints are interchangeable):
     #   'xla'       per-head einsums (baseline)
     #   'pallas'    fused VMEM-resident kernel (ops/pallas_window_attn.py):
-    #               probabilities never round-trip HBM
+    #               probabilities never round-trip HBM. Compiles for the
+    #               TPU or raises; 'pallas_interpret' runs the same kernel
+    #               interpreted (CPU tests)
     #   'paired'    two windows packed into one [2n, 2n] attention with a
     #               cross-window kill mask: score/AV matmuls fill full
     #               128-row MXU tiles at ws=8 instead of two half-empty
-    #               64-row passes (BASELINE.md roofline lever)
+    #               64-row passes
     #   'blockdiag' QK^T/AV as block-diagonal-packed gemms: contraction 60
     #               instead of head_dim 10 (6x MXU K-utilization) at the
     #               cost of materializing packed operands
@@ -156,10 +158,12 @@ class WindowAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, mask=None):
-        if self.attn_impl not in ("xla", "pallas", "paired", "blockdiag"):
+        if self.attn_impl not in (
+            "xla", "pallas", "pallas_interpret", "paired", "blockdiag"
+        ):
             raise ValueError(
-                "attn_impl must be one of 'xla'/'pallas'/'paired'/"
-                f"'blockdiag', got {self.attn_impl!r}"
+                "attn_impl must be one of 'xla'/'pallas'/'pallas_interpret'/"
+                f"'paired'/'blockdiag', got {self.attn_impl!r}"
             )
         bn, n, c = x.shape  # [B*nW, ws^2, C]
         h = self.num_heads
@@ -185,7 +189,7 @@ class WindowAttention(nn.Module):
         if self.attn_impl == "blockdiag":
             return self._blockdiag(q, k, v, bias, mask)
 
-        if self.attn_impl == "pallas":
+        if self.attn_impl in ("pallas", "pallas_interpret"):
             if self.softmax_dtype != jnp.float32:
                 # the kernel always accumulates softmax in f32; refusing a
                 # bf16 request keeps ablation arms honestly labeled
@@ -208,7 +212,7 @@ class WindowAttention(nn.Module):
                 None if mask is None else jnp.asarray(mask),
                 pk,
                 max(1, 16 // pk),
-                pwa.auto_interpret(),
+                self.attn_impl == "pallas_interpret",
             )  # [bn, h, n, d], softmax in f32 in-kernel
             out = out.transpose(0, 2, 1, 3).reshape(bn, n, c)
             out = checkpoint_name(out, "attn_out")
@@ -484,7 +488,7 @@ class SwinIR(nn.Module):
     # see benchmarks/profile_swinir.py) at ~1e-2 output tolerance.
     norm_dtype: jnp.dtype = jnp.float32
     softmax_dtype: jnp.dtype = jnp.float32  # attention softmax accumulation
-    # 'xla' | 'pallas' | 'paired' | 'blockdiag' — see
+    # 'xla' | 'pallas' | 'pallas_interpret' | 'paired' | 'blockdiag' — see
     # WindowAttention.attn_impl for what each computes
     attn_impl: str = "xla"
     attn_pack: int = 1  # pallas impl: windows fused per attention tile

@@ -182,8 +182,8 @@ class GoodputLedger:
 #
 # Training cost as 3x forward (fwd + ~2x bwd), the standard estimate the
 # roofline guard in bench.py already uses (SwinIR-S x2 @64x64 ≈ 21
-# GFLOPs/image trained, BASELINE.md derivation — swinir_train_flops
-# computes the same quantity from the config instead of hardcoding it).
+# GFLOPs/image trained — swinir_train_flops computes the same quantity
+# from the config instead of hardcoding it).
 
 _TRAIN_MULT = 3.0  # fwd + bwd ≈ 3x fwd matmul FLOPs
 
@@ -244,8 +244,8 @@ def swinir_train_flops(
     Window attention: the qk^T/att*v matmuls see ``window_size**2``-long
     sequences, so their cost is linear in tokens. Defaults are the
     SwinIR-S flagship (bench.py) — at 64x64/x2 this lands in the same
-    ~20-26 GFLOPs/image band as the ~21 GFLOPs/image roofline derivation
-    in BASELINE.md (which rounds the conv tail down).
+    ~20-26 GFLOPs/image band as the ~21 GFLOPs/image hand derivation
+    (which rounds the conv tail down).
     """
     tokens = h * w
     c = embed_dim
@@ -290,28 +290,31 @@ def model_train_flops(model, batch: int, input_hw=None) -> float | None:
 
 # -- per-backend peak FLOPs and MFU ------------------------------------
 
-# dense bf16 peak per chip, matched by substring against the device kind
-# (jax.devices()[0].device_kind); the bare-platform rows are the fallback.
-# CPU has no meaningful tensor peak — the placeholder keeps MFU defined on
-# CPU-mesh smoke runs (it reads as "fraction of a 100 GFLOP/s core").
+# Dense bf16 peak per chip, keyed by ``jax.devices()[0].device_kind``
+# exactly as the runtime reports it. Kind strings: "TPU v5 lite" is what
+# libtpu 0.0.34 reports for a v5e (chip_smoke.py prints it); the others are
+# the strings jax's own ``test_util.is_device_tpu`` matches. Peaks: Google
+# Cloud TPU documentation, the system-architecture page of each version.
+# A TPU kind that is not listed raises: a wrong peak passes silently, a
+# missing one does not.
 PEAK_FLOPS = {
-    "v6e": 918e12,
-    "v5p": 459e12,
-    "v5e": 197e12,
-    "v5litepod": 197e12,
-    "v4": 275e12,
-    "v3": 123e12,
-    "v2": 45e12,
-    "tpu": 197e12,   # unrecognized TPU kind: assume v5e-class
-    "gpu": 312e12,   # A100-class bf16 dense
-    "cpu": 100e9,
-    "": 100e9,
+    "TPU v5 lite": 197e12,  # v5e
+    "TPU v6 lite": 918e12,  # v6e
+    "TPU v5": 459e12,       # v5p
+    "TPU v4": 275e12,
+    "TPU v3": 123e12,
+    "TPU v2": 45e12,
 }
+# NOT a peak: CPU has no meaningful tensor peak. The placeholder keeps MFU
+# defined on CPU-mesh test runs (it reads as "fraction of a 100 GFLOP/s
+# core") and is never written under the name of a device metric.
+CPU_PLACEHOLDER_FLOPS = 100e9
 
 
-def peak_flops(platform: str = "", device_kind: str = "") -> float:
+def peak_flops(platform: str, device_kind: str = "") -> float:
     """Per-device peak from the table; ``GRAFT_PEAK_FLOPS`` overrides
-    (a deployment knows its chip better than a substring table)."""
+    (a deployment knows its chip better than a table). Raises for a
+    platform or TPU kind the table does not know."""
     env = os.environ.get("GRAFT_PEAK_FLOPS")
     if env:
         try:
@@ -320,11 +323,16 @@ def peak_flops(platform: str = "", device_kind: str = "") -> float:
             raise ValueError(
                 f"GRAFT_PEAK_FLOPS must be a float, got {env!r}"
             ) from None
-    kind = (device_kind or "").lower().replace(" ", "")
-    for key, val in PEAK_FLOPS.items():
-        if key and key in kind:
-            return val
-    return PEAK_FLOPS.get((platform or "").lower(), PEAK_FLOPS[""])
+    plat = (platform or "").lower()
+    if plat == "cpu":
+        return CPU_PLACEHOLDER_FLOPS
+    if plat == "tpu" and device_kind in PEAK_FLOPS:
+        return PEAK_FLOPS[device_kind]
+    raise ValueError(
+        f"no peak FLOP/s known for platform {platform!r}, device_kind "
+        f"{device_kind!r}: add a sourced row to observe.goodput.PEAK_FLOPS "
+        "or set GRAFT_PEAK_FLOPS"
+    )
 
 
 def mfu(
@@ -477,7 +485,7 @@ def read_step_logs(
 class StragglerReport:
     """Robust z-scores of per-rank median step time, plus the flagged set.
 
-    ``outage_class`` feeds the shared classifier's taxonomy: a flagged
+    ``outage_class`` feeds the shared classifier's classes: a flagged
     straggler is OUTAGE-class (a contended host / flaky link — waiting,
     rescheduling or excluding the rank helps), never DETERMINISTIC (the
     same program runs on every rank under SPMD).

@@ -37,40 +37,7 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-# -- shard_map resolver ------------------------------------------------------
-#
-# `jax.shard_map` graduated from `jax.experimental.shard_map` in newer jax
-# (>= 0.6, with `check_rep` renamed to `check_vma` under the varying-manual-
-# axes tracker). This repo targets the new surface; on builds that predate
-# it (this image ships 0.4.37) every `jax.shard_map(...)` call raises
-# AttributeError. All in-repo call sites (and tests) import THIS resolver
-# instead, so one place owns the fallback and the kwarg translation.
-
-try:  # new surface (jax >= 0.6)
-    from jax import shard_map as _shard_map_impl
-
-    _SHARD_MAP_LEGACY = False
-except ImportError:  # 0.4.x/0.5.x: the experimental module is the only home
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _SHARD_MAP_LEGACY = True
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """``jax.shard_map`` with a legacy-jax fallback (one resolver repo-wide).
-
-    Accepts the NEW keyword surface (``check_vma``); on legacy jax the flag
-    is forwarded as ``check_rep`` (the same replication/varying check under
-    its pre-vma name). Extra kwargs pass through to whichever impl is live.
-    """
-    if check_vma is not None:
-        kwargs["check_rep" if _SHARD_MAP_LEGACY else "check_vma"] = check_vma
-    return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
-
+from jax import lax, shard_map  # noqa: F401  (shard_map re-exported)
 
 # -- in-jit SPMD collectives -------------------------------------------------
 
@@ -80,14 +47,6 @@ _REDUCERS = {
     "max": lax.pmax,
     "min": lax.pmin,
 }
-
-
-def _axis_size(axis_name: str):
-    # jax < 0.5 has no lax.axis_size; psum of a literal 1 constant-folds
-    # to the (static) axis size, so this stays usable for perm lists
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
 
 
 def all_reduce(x, axis_name: str = "dp", op: str = "sum"):
@@ -116,7 +75,7 @@ def reduce_scatter(x, axis_name: str = "dp", scatter_axis: int = 0, op: str = "s
     """
     out = lax.psum_scatter(x, axis_name, scatter_dimension=scatter_axis, tiled=True)
     if op == "mean":
-        out = out / _axis_size(axis_name)
+        out = out / lax.axis_size(axis_name)
     elif op != "sum":
         raise ValueError(f"reduce_scatter supports sum|mean, got {op!r}")
     return out
@@ -156,7 +115,7 @@ def permute(x, axis_name: str, perm: list[tuple[int, int]]):
 
 def ring_shift(x, axis_name: str, offset: int = 1):
     """Shift shards by ``offset`` around the axis ring (wraps)."""
-    n = int(_axis_size(axis_name))
+    n = int(lax.axis_size(axis_name))
     perm = [(i, (i + offset) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
 
@@ -166,7 +125,7 @@ def axis_index(axis_name: str = "dp"):
 
 
 def axis_size(axis_name: str = "dp"):
-    return _axis_size(axis_name)
+    return lax.axis_size(axis_name)
 
 
 # -- host-level (outside jit) ------------------------------------------------
@@ -284,9 +243,9 @@ def hier_all_reduce(
     if ici_axis is None:
         out = lax.psum(x, dcn_axis)
         if op == "mean":
-            out = out / _axis_size(dcn_axis)
+            out = out / lax.axis_size(dcn_axis)
         return out
-    n_ici = int(_axis_size(ici_axis))
+    n_ici = int(lax.axis_size(ici_axis))
     flat = x.reshape(-1)
     pad = (-flat.size) % n_ici
     flat = jnp.pad(flat, (0, pad))
@@ -297,7 +256,7 @@ def hier_all_reduce(
         full = full[:-pad]
     out = full.reshape(x.shape)
     if op == "mean":
-        out = out / (n_ici * _axis_size(dcn_axis))
+        out = out / (n_ici * lax.axis_size(dcn_axis))
     return out
 
 

@@ -5,8 +5,7 @@ SwinIR's hot op is (shifted-)window attention over tiny 64-token windows
 XLA path materializes the per-window attention probabilities
 ``[B*nW, heads, 64, 64]`` through HBM every layer — at the flagship bench
 shape that is ~113 MB per STL in f32, by far the largest activation the
-model touches, and the roofline in BASELINE.md puts the step firmly in
-bandwidth-bound territory. This kernel keeps scores, bias, mask and
+model touches. This kernel keeps scores, bias, mask and
 softmax entirely in VMEM: one grid step loads a block of ``wb`` windows'
 q/k/v for one head, computes softmax(q·kᵀ·scale + bias + mask)·v in f32,
 and writes only the [wb, n, d] output back.
@@ -19,8 +18,9 @@ the revisited output block (grid iterates windows innermost per head).
 
 ``window_attention`` is a drop-in for the einsum path in
 `models/swinir.py:WindowAttention` — same math, same parameters — and is
-exposed there as ``attn_impl='pallas'``. Off-TPU the kernels run in
-interpret mode so CPU tests exercise identical code.
+exposed there as ``attn_impl='pallas'`` (compiled, TPU) and
+``attn_impl='pallas_interpret'`` (the same kernels interpreted, which is
+how CPU tests exercise identical code).
 """
 
 from __future__ import annotations
@@ -280,8 +280,3 @@ def window_attention_packed(
     out = window_attention(qp, kp, vp, bias_p, mask_p, wb, interpret)
     return (out.reshape(bn // p, h, p, n, d).transpose(0, 2, 1, 3, 4)
             .reshape(bn, h, n, d))
-
-
-def auto_interpret() -> bool:
-    """Interpret kernels off-TPU so CPU tests run the same code."""
-    return jax.devices()[0].platform != "tpu"

@@ -43,7 +43,7 @@ def ring_attention(q, k, v, *, axis_name: str = "sp", causal: bool = True):
 
     q/k/v: [B, Tc, H, Dh] — the local sequence chunk. Returns [B, Tc, H, Dh].
     """
-    n = jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, tc, h, dh = q.shape
     scale = 1.0 / jnp.sqrt(dh)
@@ -91,7 +91,7 @@ def ulysses_attention(
     H divides nicely and the all-to-all fits ICI; exact same math.
     q/k/v: [B, Tc, H, Dh] local chunks inside ``shard_map``.
     """
-    n = jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
     if q.shape[2] % n:
         raise ValueError(
             f"ulysses needs heads ({q.shape[2]}) divisible by the '{axis_name}'"

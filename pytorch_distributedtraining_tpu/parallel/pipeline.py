@@ -715,22 +715,20 @@ def pipeline_value_and_grad(
 
 
 # ---------------------------------------------------------------------------
-# forward-only GPipe apply (legacy surface; AD through the scan = backward)
+# forward-only GPipe apply (AD through the scan = backward)
 # ---------------------------------------------------------------------------
 
 
 def _gpipe_local(stage_params, x, *, stage_fn, n_micro, axis_name):
     """Runs inside shard_map: one pp rank, local stage params [1, ...]."""
     sparams = jax.tree.map(lambda a: a[0], stage_params)
-    n = jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
     r = jax.lax.axis_index(axis_name)
 
     b = x.shape[0]
     micro = x.reshape(n_micro, b // n_micro, *x.shape[1:])
     # promote to pp-varying so scan carries have a uniform vma type
-    # (older jax has no pvary; with check_vma/check_rep off it is a no-op)
-    if hasattr(jax.lax, "pvary"):
-        micro = jax.lax.pvary(micro, (axis_name,))
+    micro = jax.lax.pcast(micro, (axis_name,), to="varying")
 
     state0 = micro[0] * 0
     outs0 = micro * 0
@@ -809,7 +807,7 @@ def pipeline_apply(
         mesh=mesh,
         in_specs=(pspec, xspec),
         out_specs=xspec,
-        check_vma=False,  # ppermute ring has no replication rule on legacy jax
+        check_vma=False,
     )(stage_params, x)
 
 

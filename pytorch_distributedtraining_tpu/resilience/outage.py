@@ -39,9 +39,8 @@ class OutageClass(enum.Enum):
     UNKNOWN = "unknown"
 
 
-# gRPC status names the TPU runtime raises during pool outages
-# (BASELINE.md outage signatures) — matched case-sensitively, they are
-# uppercase canonical tokens.
+# gRPC status names the TPU runtime raises during pool outages — matched
+# case-sensitively, they are uppercase canonical tokens.
 _GRPC_SENTINELS = ("UNAVAILABLE", "DEADLINE_EXCEEDED")
 
 # transport-level phrases — matched case-insensitively; connection text

@@ -1,68 +1,66 @@
-"""Machine-keyed persistent compile-cache directories.
+"""Where the persistent XLA compile cache lives — one decision, one place.
 
-XLA:CPU AOT artifacts are specialized to the compiling host's CPU
-features; reusing a cache dir across machines (shared /tmp images, copied
-containers) risks SIGILL on the consumer ("machine features don't match"
-warnings in MULTICHIP_r03.json's tail). Every persistent cache dir in the
-repo (tests, dryrun, bench) is therefore keyed by a fingerprint of the
-host CPU so a foreign machine gets a fresh, compatible cache instead of
-foreign AOT code.
+If ``JAX_COMPILATION_CACHE_DIR`` is set, jax's own handling of it stands
+and nothing here names another directory. Otherwise the cache is
+``.jax_cache/`` at the root of the checkout (git-ignored): a fixed path, so
+two runs from the same checkout — or two processes of one run — share it.
 
-The fingerprint itself lives in the stdlib-only ``.._hostfp`` so jax-free
-entry points (bench.py's parent, tpu_chain.sh) can use it too.
+jax keys every entry by the program, the jaxlib version and the backend's
+platform, version and device kinds (``jax/_src/cache_key.py``), so entries a
+CPU run wrote are never loaded for a TPU program that shares the directory.
+
+This module imports jax only inside :func:`enable_compile_cache`, so
+jax-free parents (bench.py's orchestrator) can ask for the path.
 """
 
 from __future__ import annotations
 
 import os
 
-from .._hostfp import machine_fingerprint
-
-ENV_VAR = "GRAFT_COMPILE_CACHE"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+DISABLE_VAR = "GRAFT_COMPILE_CACHE"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+# jax's default (1 s) skips most programs of a CPU test run
+MIN_COMPILE_SECS = 0.5
 
 __all__ = [
-    "cache_dir", "machine_fingerprint", "enable_compile_cache",
-    "cache_entry_count", "jit_cache_size", "ENV_VAR",
+    "cache_dir", "cache_disabled", "enable_compile_cache",
+    "cache_entry_count", "jit_cache_size", "ENV_VAR", "DISABLE_VAR",
+    "DEFAULT_DIR",
 ]
 
 
-def cache_dir(label: str) -> str:
-    """Per-user, per-machine compile-cache path for ``label``.
-
-    ``/tmp/jax_{label}_cache_{uid}_{fingerprint}``; honors an explicit
-    ``JAX_COMPILATION_CACHE_DIR`` by returning it unchanged so callers can
-    share one externally managed cache (e.g. tpu_chain.sh).
-    """
-    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if env:
-        return env
-    return f"/tmp/jax_{label}_cache_{os.getuid()}_{machine_fingerprint()}"
+def cache_dir() -> str:
+    """The compile-cache directory: ``$JAX_COMPILATION_CACHE_DIR`` if set,
+    else the fixed in-checkout :data:`DEFAULT_DIR`."""
+    return os.environ.get(ENV_VAR) or DEFAULT_DIR
 
 
-def enable_compile_cache(
-    label: str = "graft", env_var: str = ENV_VAR
-) -> str | None:
-    """Turn on jax's persistent compilation cache; return its path.
+def cache_disabled() -> bool:
+    """``GRAFT_COMPILE_CACHE=0`` (or ``off``/``false``) turns persistence off."""
+    return os.environ.get(DISABLE_VAR, "").strip().lower() in (
+        "0", "off", "false",
+    )
 
-    Honors ``$GRAFT_COMPILE_CACHE``: ``0``/``off``/``false`` disables and
-    returns None; empty or ``1`` uses the machine-keyed default from
-    :func:`cache_dir`; any other value is taken as the cache directory
-    itself. Lowers the persistent-cache min-compile-time threshold so even
-    small test programs land in the cache (the 1s default would skip most
-    of a CPU smoke run).
-    """
-    raw = os.environ.get(env_var, "").strip()
-    if raw.lower() in ("0", "off", "false"):
-        return None
-    path = cache_dir(label) if raw in ("", "1") else raw
-    os.makedirs(path, exist_ok=True)
+
+def enable_compile_cache() -> str | None:
+    """Turn on jax's persistent compilation cache; return its directory
+    (None when :func:`cache_disabled`). Call before the first compile."""
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", path)
-    try:  # knob moved/renamed across jax versions; the dir alone suffices
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-    except Exception:
-        pass
+    if cache_disabled():
+        jax.config.update("jax_enable_compilation_cache", False)
+        return None
+    path = cache_dir()
+    if not os.environ.get(ENV_VAR):
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", MIN_COMPILE_SECS
+    )
     return path
 
 
@@ -86,14 +84,6 @@ def jit_cache_size(*jitted) -> int:
     The in-process twin of :func:`cache_entry_count`: snapshotting the sum
     before and after a steady-state window detects mid-run retraces even
     when the persistent cache is disabled (a serving engine asserts this
-    stays flat once its buckets are warm). Returns 0 for callables whose
-    runtime doesn't expose ``_cache_size`` — absence must read as "no
-    evidence of recompiles", not a recompile.
+    stays flat once its buckets are warm).
     """
-    total = 0
-    for fn in jitted:
-        try:
-            total += int(fn._cache_size())
-        except Exception:  # noqa: BLE001 — introspection, version-dependent
-            pass
-    return total
+    return sum(int(fn._cache_size()) for fn in jitted)

@@ -28,12 +28,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 import jax
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: F401  (re-export)
-
-try:  # moved across jax versions
-    from jax.experimental import mesh_utils
-except ImportError:  # pragma: no cover
-    mesh_utils = None
 
 AXIS_ORDER = ("pp", "dp", "fsdp", "sp", "tp", "ep")
 
@@ -132,7 +128,7 @@ def make_mesh(spec: MeshSpec | None = None, *, devices=None, **axes) -> Mesh:
         )
     shape = tuple(spec.shape().values())
     names = tuple(spec.shape().keys())
-    if mesh_utils is not None and devices[0].platform == "tpu":
+    if devices[0].platform == "tpu":
         dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
     else:
         dev_array = np.asarray(devices).reshape(shape)
@@ -161,7 +157,6 @@ def make_hybrid_mesh(
     stands in (processes are contiguous in ``jax.devices()`` order).
     """
     import dataclasses
-    import warnings
 
     if spec is None:
         spec = MeshSpec(**axes)
@@ -184,11 +179,7 @@ def make_hybrid_mesh(
         return make_mesh(full, devices=devices)
 
     names = tuple(full.shape().keys())
-    on_tpu = devices[0].platform == "tpu"
-    has_hybrid = mesh_utils is not None and hasattr(
-        mesh_utils, "create_hybrid_device_mesh"
-    )
-    if on_tpu and has_hybrid:
+    if devices[0].platform == "tpu":
         ici_shape = tuple(1 if n == "dp" else getattr(spec, n) for n in names)
         dcn_shape = tuple(dcn_dp if n == "dp" else 1 for n in names)
         dev_array = mesh_utils.create_hybrid_device_mesh(
@@ -197,12 +188,6 @@ def make_hybrid_mesh(
         mesh = Mesh(dev_array, names)
         _register_slice_axis(mesh, "dp")
         return mesh
-    if on_tpu:  # multi-slice TPU without the slice-aware builder
-        warnings.warn(
-            "mesh_utils.create_hybrid_device_mesh unavailable: hybrid mesh "
-            "device order ignores slice boundaries — model-axis collectives "
-            "may ride DCN. Upgrade jax for the slice-aware layout."
-        )
     # reshape with the DCN axis OUTERMOST (slices are contiguous in device
     # order), then move it into the "dp" slot — a straight reshape would
     # hand contiguous slices to whatever axis precedes dp (e.g. pp)

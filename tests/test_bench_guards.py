@@ -1,14 +1,7 @@
-"""Round-5 instrument hardening: roofline guards + harvest rendering.
+"""Roofline guards: the benchmarks refuse to publish physically
+impossible numbers."""
 
-The benchmarks refuse to publish physically impossible numbers (VERDICT
-r4 #5) and the watcher's harvester must carry a violation's cause into
-BASELINE.md instead of dropping it as a non-JSON line.
-"""
-
-import json
 import os
-import subprocess
-import sys
 
 import pytest
 
@@ -44,34 +37,3 @@ class TestGuard:
         # ladder's per-config isolation catches Exception, not SystemExit
         with pytest.raises(RuntimeError, match=VIOLATION_PREFIX):
             guard("cfg4", 2.0, "tok/s", 1.0, "d", soft=True)
-
-
-class TestHarvestViolations:
-    def test_violation_line_becomes_error_row(self, tmp_path):
-        (tmp_path / "decode.txt").write_text(
-            "# progress line\n"
-            f"{VIOLATION_PREFIX}: decode 2550000 tok/s exceeds the 33000 "
-            "tok/s bound (weights) — refusing to publish\n"
-        )
-        out = subprocess.run(
-            [sys.executable, os.path.join(BENCHMARKS, "harvest_results.py"),
-             str(tmp_path)],
-            capture_output=True, text=True, cwd=BENCHMARKS,
-        )
-        assert out.returncode == 0, out.stderr
-        assert VIOLATION_PREFIX in out.stdout
-        # rendered as a row under the decode stage, not dropped
-        assert "**decode**" in out.stdout
-
-    def test_never_staged_arms_are_skipped(self, tmp_path):
-        (tmp_path / "bench.txt").write_text(
-            json.dumps({"metric": "m", "value": 1.0, "unit": "u"}) + "\n"
-        )
-        out = subprocess.run(
-            [sys.executable, os.path.join(BENCHMARKS, "harvest_results.py"),
-             str(tmp_path), "--window", "2"],
-            capture_output=True, text=True, cwd=BENCHMARKS,
-        )
-        assert out.returncode == 0, out.stderr
-        assert "not run" not in out.stdout
-        assert "pool window 2" in out.stdout

@@ -1,0 +1,557 @@
+"""Compile for a TPU that is described, not attached.
+
+The TPU compiler is installed beside jax and compiles for a ``v5e:2x2`` it
+only has a description of (on-chip-measurement guide, section 2, rehearsal
+3). Nothing runs, so these say nothing about results or times — but what the
+compiler refuses here (a tile Mosaic cannot lay out, more fast memory than a
+kernel may use, a libtpu flag it does not know) it refuses on the chip too,
+and here it costs no chip time.
+
+The cheap cases are the kernels at the widths the chip runs; they stay in
+tier-1. The whole-step compiles (about half a minute each) are marked
+``slow``: run them before a chip call that depends on them::
+
+    python -m pytest tests/test_chip_compile.py -m slow
+
+Code that asks ``jax.devices()`` still sees the CPU here, so every case
+hands the described devices and shapes to the jitted function itself.
+"""
+
+import dataclasses
+import os
+import subprocess
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+V5E_KIND = "TPU v5 lite"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described v5e:2x2 — or skip the module where libtpu cannot give
+    one. The persistent cache is off around these compiles: an executable
+    for a chip that is not there is written but can never be read back."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+def _on(device_or_sharding, shape, dtype):
+    sh = device_or_sharding
+    if not isinstance(sh, jax.sharding.Sharding):
+        sh = SingleDeviceSharding(sh)
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled, compiled.as_text()
+
+
+# -- kernels at real widths (tier-1) -----------------------------------------
+
+WINDOW = (18 * 64, 6, 64, 10)  # SwinIR-S, batch 18 of 64x64: [B*nW, h, n, d]
+FLASH = (8, 1024, 12, 64)  # GPT-2 125M: [B, T, H, Dh]
+GPT2_HEADS, GPT2_HEAD_DIM, PAGE = 12, 64, 16
+
+
+def _window(dev, *, mask, grad):
+    from pytorch_distributedtraining_tpu.ops.pallas_window_attn import (
+        window_attention,
+    )
+
+    bn, h, n, d = WINDOW
+    qkv = _on(dev, WINDOW, jnp.float32)
+    bias = _on(dev, (h, n, n), jnp.float32)
+    m = _on(dev, (64, n, n), jnp.float32) if mask else None
+
+    def fwd(q, k, v, bias, m):
+        return window_attention(q, k, v, bias, m, 16, False)
+
+    if not grad:
+        return fwd, (qkv, qkv, qkv, bias, m)
+    return (
+        jax.grad(lambda *a: jnp.sum(fwd(*a)), argnums=(0, 1, 2, 3)),
+        (qkv, qkv, qkv, bias, m),
+    )
+
+
+def _flash(dev, *, dtype, grad):
+    from pytorch_distributedtraining_tpu.ops.pallas_attn import flash_attention
+
+    qkv = _on(dev, FLASH, dtype)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, True, 128, 128, False)
+
+    if not grad:
+        return fwd, (qkv, qkv, qkv)
+    return (
+        jax.grad(
+            lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)), argnums=(0, 1, 2)
+        ),
+        (qkv, qkv, qkv),
+    )
+
+
+def _int8_block():
+    from pytorch_distributedtraining_tpu.models.generate import kv_scale_block
+    from pytorch_distributedtraining_tpu.serve.kv_cache import kv_wire_format
+
+    fmt = kv_wire_format("int8_block")
+    return fmt, kv_scale_block(fmt, GPT2_HEADS, GPT2_HEAD_DIM)
+
+
+def _paged_decode(dev):
+    """The engine's decode-tick attention: 4 slots, one new token each,
+    against int8 block-scaled pages for 1,024 positions a slot."""
+    from pytorch_distributedtraining_tpu.models.generate import paged_attention
+
+    fmt, blk = _int8_block()
+    slots, max_pages = 4, 1024 // PAGE
+    n_pages = 1 + slots * max_pages
+    n_scales = GPT2_HEADS * GPT2_HEAD_DIM // blk
+    pages = _on(
+        dev, (n_pages, PAGE, GPT2_HEADS, GPT2_HEAD_DIM), fmt.payload_dtype
+    )
+    scales = _on(dev, (n_pages, PAGE, n_scales), jnp.float32)
+    return (
+        lambda q, kp, vp, tbl, ln, ks, vs: paged_attention(
+            q, kp, vp, tbl, ln, k_scales=ks, v_scales=vs
+        ),
+        (
+            _on(dev, (slots, 1, GPT2_HEADS, GPT2_HEAD_DIM), jnp.bfloat16),
+            pages, pages, _on(dev, (slots, max_pages), jnp.int32),
+            _on(dev, (slots,), jnp.int32), scales, scales,
+        ),
+    )
+
+
+def _kv_quant_pair(dev):
+    from pytorch_distributedtraining_tpu.models.generate import (
+        dequantize_kv,
+        quantize_kv,
+    )
+
+    fmt, blk = _int8_block()
+
+    def roundtrip(x):
+        payload, scales = quantize_kv(x, fmt, blk)
+        return dequantize_kv(payload, scales, x.dtype)
+
+    return roundtrip, (
+        _on(dev, (4, 32, GPT2_HEADS, GPT2_HEAD_DIM), jnp.bfloat16),
+    )
+
+
+def _fp8_dot(dev):
+    """GPT-2's MLP-in contraction ([B*T, 768] x [768, 3072]) with e4m3
+    operands, forward and both transposed backward matmuls."""
+    from pytorch_distributedtraining_tpu.precision import (
+        FP8_DTYPES,
+        fp8_dot_general,
+    )
+
+    dn = (((1,), (0,)), ((), ()))
+
+    def loss(x, w, sx, sw):
+        return jnp.sum(fp8_dot_general(x, w, sx, sw, dn, FP8_DTYPES["e4m3"]))
+
+    scale = _on(dev, (), jnp.float32)
+    return jax.grad(loss, argnums=(0, 1)), (
+        _on(dev, (8 * 1024, 768), jnp.bfloat16),
+        _on(dev, (768, 3072), jnp.bfloat16), scale, scale,
+    )
+
+
+KERNEL_CASES = {
+    "window_fwd": (lambda d: _window(d, mask=False, grad=False), True),
+    "window_fwd_shift_mask": (lambda d: _window(d, mask=True, grad=False), True),
+    "window_bwd": (lambda d: _window(d, mask=True, grad=True), True),
+    "flash_fwd_bf16": (
+        lambda d: _flash(d, dtype=jnp.bfloat16, grad=False), True,
+    ),
+    "flash_bwd_bf16": (
+        lambda d: _flash(d, dtype=jnp.bfloat16, grad=True), True,
+    ),
+    "flash_bwd_f32": (lambda d: _flash(d, dtype=jnp.float32, grad=True), True),
+    "paged_decode_attention_int8": (_paged_decode, False),
+    "kv_quantize_dequantize": (_kv_quant_pair, False),
+    "fp8_dot_fwd_bwd": (_fp8_dot, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_kernel_compiles_for_v5e(topo, case):
+    build, is_pallas = KERNEL_CASES[case]
+    dev = topo.devices[0]
+    assert dev.device_kind == V5E_KIND
+    fn, args = build(dev)
+    _, text = _compile(fn, *args)
+    if is_pallas:  # compiled by Mosaic, not interpreted and not replaced
+        assert "tpu_custom_call" in text
+
+
+def test_libtpu_accepts_latency_hiding_flags():
+    """libtpu reads LIBTPU_INIT_ARGS when it is loaded and kills the process
+    on a flag it does not know — so load it, in a process of its own, with
+    exactly what ``runtime.initialize()`` would set."""
+    code = (
+        "import os\n"
+        "from pytorch_distributedtraining_tpu.runtime import dist\n"
+        "os.environ.pop('LIBTPU_INIT_ARGS', None)\n"
+        "assert dist.enable_latency_hiding_scheduler()\n"
+        "flags = os.environ['LIBTPU_INIT_ARGS'].split()\n"
+        "assert flags == list(dist.LATENCY_HIDING_FLAGS), flags\n"
+        "from jax.experimental import topologies\n"
+        "try:\n"
+        "    topologies.get_topology_desc(platform='tpu',\n"
+        "                                 topology_name='v5e:2x2')\n"
+        "except Exception as e:\n"
+        "    print('SKIP', e); raise SystemExit(77)\n"
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
+               TPU_LOG_DIR="disabled")
+    env.pop("GRAFT_OVERLAP", None)
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True,
+        text=True, timeout=120,
+    )
+    if out.returncode == 77:
+        pytest.skip(f"cannot load libtpu here: {out.stdout[-300:]}")
+    assert out.returncode == 0, out.stderr[-2000:]
+
+
+def test_peak_table_knows_the_chip_and_refuses_strangers(monkeypatch):
+    from pytorch_distributedtraining_tpu.observe.goodput import (
+        PEAK_FLOPS,
+        peak_flops,
+    )
+
+    monkeypatch.delenv("GRAFT_PEAK_FLOPS", raising=False)
+    assert peak_flops("tpu", V5E_KIND) == PEAK_FLOPS[V5E_KIND] == 197e12
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        peak_flops("tpu", "TPU v9 mega")
+    with pytest.raises(ValueError, match="no peak"):
+        peak_flops("tpu", "")
+    with pytest.raises(ValueError, match="no peak"):
+        peak_flops("gpu", "NVIDIA A100")
+
+
+# -- whole programs at real widths (slow) ------------------------------------
+
+
+def _abstract_state(init_fn, tx, mesh, policy):
+    """What ``create_train_state`` would place on ``mesh``, as shapes with
+    shardings: a described device cannot hold an array."""
+    from pytorch_distributedtraining_tpu.parallel.spec import tree_shardings
+    from pytorch_distributedtraining_tpu.parallel.state import TrainState
+
+    def build(rng):
+        params, model_state = init_fn(rng)
+        return TrainState(
+            step=jnp.int32(0), params=params, opt_state=tx.init(params),
+            model_state=model_state, rng=rng, scaler=None,
+        )
+
+    shapes = jax.eval_shape(build, jax.random.PRNGKey(0))
+    specs = TrainState(
+        step=P(), params=policy.params_specs(shapes.params, mesh),
+        opt_state=policy.opt_specs(shapes.opt_state, mesh),
+        model_state=jax.tree.map(lambda _: P(), shapes.model_state),
+        rng=P(), scaler=None,
+    )
+    return shapes, tree_shardings(specs, mesh)
+
+
+def _with_shardings(shapes, shardings):
+    return jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, shardings,
+    )
+
+
+def _gpt2_step(mesh, policy, cfg, *, attn_fn=None, step_cls=None, **kw):
+    from pytorch_distributedtraining_tpu import optim
+    from pytorch_distributedtraining_tpu.models import GPT2, cross_entropy_loss
+    from pytorch_distributedtraining_tpu.parallel import TrainStep
+    from pytorch_distributedtraining_tpu.precision import Policy as Precision
+    from pytorch_distributedtraining_tpu.runtime.mesh import batch_spec
+
+    model = GPT2(cfg, **({"attn_fn": attn_fn} if attn_fn else {}))
+    init_model = GPT2(cfg)  # same params; init needs no sharded attention
+    fp8 = cfg.fp8 is not None
+
+    def init_fn(rng):
+        v = dict(init_model.init(rng, jnp.zeros((1, 8), jnp.int32)))
+        return v.pop("params"), v
+
+    def loss_fn(params, batch, rng, model_state):
+        tok, tgt = batch
+        if fp8:
+            logits, new = model.apply(
+                {"params": params, **model_state}, tok, mutable=["fp8"]
+            )
+            return cross_entropy_loss(logits, tgt), {"model_state": dict(new)}
+        return cross_entropy_loss(model.apply({"params": params}, tok), tgt), {}
+
+    tx = optim.adamw(lr=3e-4, clip_grad_norm=1.0)
+    shapes, shardings = _abstract_state(init_fn, tx, mesh, policy)
+    if step_cls is None:
+        step = TrainStep(
+            loss_fn, tx, mesh, policy, state_shardings=shardings,
+            precision=Precision.from_name("bf16"), **kw,
+        )
+    else:
+        step = step_cls(loss_fn, tx, mesh, policy, **kw)
+    tokens = _on(NamedSharding(mesh, batch_spec(mesh)), (8, 1024), jnp.int32)
+    return step, _with_shardings(shapes, shardings), (tokens, tokens)
+
+
+def _lower(step, state, batch):
+    with step.mesh:
+        compiled = step._jitted.lower(state, batch, jnp.float32(1.0)).compile()
+    return compiled, compiled.as_text()
+
+
+def _mesh(topo, n=None, **axes):
+    from pytorch_distributedtraining_tpu.runtime.mesh import MeshSpec, make_mesh
+
+    devices = topo.devices if n is None else topo.devices[:n]
+    return make_mesh(MeshSpec(**axes), devices=devices)
+
+
+@pytest.mark.slow
+def test_gpt2_125m_train_step_one_chip(topo):
+    """``jax.grad`` of GPT-2 125M at [8, 1024] through TrainStep — and
+    through the ``create_device_mesh`` branch of ``make_mesh``, which runs
+    only for devices whose platform is ``tpu``."""
+    from pytorch_distributedtraining_tpu.models import GPT2Config
+    from pytorch_distributedtraining_tpu.parallel import DDP
+
+    step, state, batch = _gpt2_step(
+        _mesh(topo, 1, dp=1), DDP(), GPT2Config.gpt2_125m()
+    )
+    compiled, _ = _lower(step, state, batch)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+@pytest.mark.slow
+def test_gpt2_125m_zero3_on_four_chips(topo):
+    from pytorch_distributedtraining_tpu.models import GPT2Config
+    from pytorch_distributedtraining_tpu.observe import hlo
+    from pytorch_distributedtraining_tpu.parallel import FSDP
+
+    step, state, batch = _gpt2_step(
+        _mesh(topo, fsdp=4), FSDP(), GPT2Config.gpt2_125m()
+    )
+    _, text = _lower(step, state, batch)
+    # XLA:TPU spells the gradient reduce-scatter as all-reduce/all-to-all
+    counts = hlo.counts(text)
+    assert counts.get("all-gather"), counts
+    assert counts.get("all-reduce") or counts.get("reduce-scatter"), counts
+
+
+@pytest.mark.slow
+def test_gpt2_block_with_fp8_dots(topo):
+    from pytorch_distributedtraining_tpu.models import GPT2Config
+    from pytorch_distributedtraining_tpu.parallel import DDP
+
+    cfg = dataclasses.replace(GPT2Config.gpt2_125m(), n_layer=1, fp8="e4m3")
+    step, state, batch = _gpt2_step(_mesh(topo, 1, dp=1), DDP(), cfg)
+    _, text = _lower(step, state, batch)
+    assert "f8e4m3fn" in text and "f8e5m2" in text
+
+
+@pytest.mark.slow
+def test_fp8_wire_grad_step_on_four_chips(topo):
+    """Block-scaled fp8 gradient wire (parallel/compressed.py)."""
+    from pytorch_distributedtraining_tpu.models import GPT2Config
+    from pytorch_distributedtraining_tpu.parallel import DDP, CompressedGradStep
+
+    cfg = dataclasses.replace(GPT2Config.gpt2_125m(), n_layer=2)
+    step, state, batch = _gpt2_step(
+        _mesh(topo, dp=4), DDP(), cfg,
+        step_cls=CompressedGradStep, wire="fp8_e4m3",
+    )
+    residuals = jax.eval_shape(step.init_residuals, state.params)
+    state = state.replace(model_state={"grad_residual": jax.tree.map(
+        lambda s, p: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=p.sharding),
+        residuals, state.params,
+    )})
+    _, text = _lower(step, state, batch)
+    assert "f8e4m3fn" in text
+
+
+@pytest.mark.slow
+def test_ring_attention_step_on_sp4(topo):
+    from pytorch_distributedtraining_tpu.models import GPT2Config
+    from pytorch_distributedtraining_tpu.observe import hlo
+    from pytorch_distributedtraining_tpu.ops import make_ring_attn_fn
+    from pytorch_distributedtraining_tpu.parallel import DDP
+
+    mesh = _mesh(topo, sp=4)
+    cfg = dataclasses.replace(GPT2Config.gpt2_125m(), n_layer=2)
+    step, state, batch = _gpt2_step(
+        mesh, DDP(), cfg, attn_fn=make_ring_attn_fn(mesh)
+    )
+    _, text = _lower(step, state, batch)
+    assert hlo.counts(text).get("collective-permute"), hlo.counts(text)
+
+
+@pytest.mark.slow
+def test_1f1b_pipeline_step_on_pp4(topo):
+    from pytorch_distributedtraining_tpu import optim
+    from pytorch_distributedtraining_tpu.models.gpt2 import Block, GPT2Config
+    from pytorch_distributedtraining_tpu.observe.hlo import pipeline_audit
+    from pytorch_distributedtraining_tpu.parallel import (
+        PipelineStep,
+        Policy,
+        pipeline_state_shardings,
+        stack_stage_params,
+    )
+
+    mesh = _mesh(topo, pp=4)
+    cfg, n_micro, t = GPT2Config.gpt2_125m(), 8, 1024
+    block = Block(cfg)
+    x0 = jnp.zeros((1, 8, cfg.n_embd))
+
+    def init_fn(rng):
+        return {"h": stack_stage_params([
+            block.init(jax.random.fold_in(rng, i), x0)["params"]
+            for i in range(4)
+        ])}, {}
+
+    tx = optim.adamw(lr=1e-3)
+    shapes, shardings = _abstract_state(init_fn, tx, mesh, Policy())
+    shardings = pipeline_state_shardings(shardings, shapes, mesh, "h")
+    step = PipelineStep(
+        lambda p, x: Block(cfg).apply({"params": p}, x), tx, mesh, Policy(),
+        n_micro=n_micro, schedule="1f1b", stages_key="h",
+        embed_fn=lambda other, mb, rng: mb,
+        head_fn=lambda other, y, mb, rng: jnp.mean(y.astype(jnp.float32) ** 2),
+        state_shardings=shardings,
+    )
+    batch = _on(
+        NamedSharding(mesh, P()), (n_micro, t, cfg.n_embd), jnp.bfloat16
+    )
+    _, text = _lower(step, _with_shardings(shapes, shardings), batch)
+    assert pipeline_audit(text, step.schedule, mesh=mesh).ok
+
+
+@pytest.mark.slow
+def test_serve_decode_and_spec_verify_int8_kv(topo, monkeypatch):
+    """The engine's decode program and its [n_slots, spec_k] verify program
+    at GPT-2 125M widths over int8 block-scaled pages, donation on (the
+    engine asks ``jax.default_backend()``, which says cpu here)."""
+    from pytorch_distributedtraining_tpu.models import GPT2, GPT2Config
+    from pytorch_distributedtraining_tpu.serve.engine import ServeEngine
+
+    monkeypatch.setattr(ServeEngine, "_donate", lambda self: (1,))
+    cfg = GPT2Config.gpt2_125m()
+    dev = topo.devices[0]
+    params = jax.eval_shape(
+        lambda r: GPT2(cfg).init(r, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0),
+    )
+    eng = ServeEngine(
+        cfg, params, n_slots=4, page_size=PAGE, max_len=256, spec_k=4,
+        kv_wire="int8_block", temperature=0.0,
+    )
+    put = lambda tree: jax.tree.map(  # noqa: E731
+        lambda s: _on(dev, s.shape, s.dtype), tree
+    )
+    slots = (
+        _on(dev, (4, eng.max_pages), jnp.int32), _on(dev, (4,), jnp.int32),
+    )
+    key = _on(dev, (2,), jnp.uint32)
+    eng._decode_fn.lower(
+        put(params), put(eng._pages), _on(dev, (4, 1), jnp.int32), *slots, key
+    ).compile()
+    eng._spec_fn.lower(
+        put(params), put(eng._pages), _on(dev, (4, 4), jnp.int32), *slots
+    ).compile()
+    eng._prefill_fns[32].lower(
+        put(params), put(eng._pages), _on(dev, (1, 32), jnp.int32),
+        _on(dev, (1, eng.max_pages), jnp.int32), _on(dev, (1,), jnp.int32),
+        _on(dev, (), jnp.int32), key,
+    ).compile()
+
+
+@pytest.mark.slow
+def test_swinir_s_train_step_batch18(topo):
+    """Full-width SwinIR-S (the constructor of drivers/stoke_ddp.py), batch
+    18 of 64x64, through TrainStep: float32 with XLA attention, and bf16
+    with the Pallas window kernel compiled into the model. The compiler's
+    memory plan decides how large a batch a cell can use; head_dim 10 and
+    channel 60 pad onto 128 lanes, so this small model is not small there
+    (float32 with the Pallas kernel is refused outright: 19.4 of 15.75 GB).
+    """
+    from pytorch_distributedtraining_tpu import optim
+    from pytorch_distributedtraining_tpu.losses import mse_loss
+    from pytorch_distributedtraining_tpu.models import SwinIR
+    from pytorch_distributedtraining_tpu.parallel import DDP, TrainStep
+    from pytorch_distributedtraining_tpu.precision import Policy as Precision
+
+    def model(attn_impl, dtype):
+        return SwinIR(
+            upscale=2, in_chans=3, img_size=64, window_size=8, img_range=1.0,
+            depths=[6, 6, 6, 6], embed_dim=60, num_heads=[6, 6, 6, 6],
+            mlp_ratio=2, upsampler="pixelshuffledirect",
+            resi_connection="1conv", attn_impl=attn_impl, dtype=dtype,
+        )
+
+    mesh = _mesh(topo, 1, dp=1)
+    tx = optim.adamw(lr=1e-3, clip_grad_norm=0.1)
+    shapes, shardings = _abstract_state(
+        lambda r: (
+            model("xla", jnp.float32).init(
+                r, jnp.zeros((1, 64, 64, 3))
+            )["params"], {},
+        ),
+        tx, mesh, DDP(),
+    )
+    data = NamedSharding(mesh, P())
+    batch = (
+        _on(data, (18, 64, 64, 3), jnp.float32),
+        _on(data, (18, 128, 128, 3), jnp.float32),
+    )
+    plans = {}
+    for precision, impl, dtype in (
+        ("fp32", "xla", jnp.float32), ("bf16", "pallas", jnp.bfloat16),
+    ):
+        net = model(impl, dtype)
+        step = TrainStep(
+            lambda p, b, rng, ms, net=net: (
+                mse_loss(net.apply({"params": p}, b[0]), b[1]), {},
+            ),
+            tx, mesh, DDP(), state_shardings=shardings,
+            precision=Precision.from_name(precision),
+        )
+        compiled, text = _lower(step, _with_shardings(shapes, shardings), batch)
+        assert ("tpu_custom_call" in text) == (impl == "pallas")
+        plans[f"{precision}/{impl}"] = (
+            compiled.memory_analysis().temp_size_in_bytes
+        )
+    print("SwinIR-S batch 18 of 64x64, planned temporaries (bytes):", plans)
+    # half of the chip's 15.75 GB or more: batch 18 is not "mostly empty"
+    assert all(8e9 < v < 15.75e9 for v in plans.values()), plans

@@ -121,7 +121,7 @@ def test_quantize_roundtrip_unbiased_over_steps():
 
 
 def test_compressed_zero2_scatter_matches_exact_sgd(devices8):
-    """VERDICT r3 weak #6: the ZeRO-2 composition — int8 psum_scatter to
+    """The ZeRO-2 composition — int8 psum_scatter to
     the owning shard — must take the same SGD step as exact DDP, with the
     opt state actually sharded (reduce-to-owner, not all-reduce)."""
     import optax

@@ -1,4 +1,4 @@
-"""Torch-SwinIR checkpoint naming → framework params (VERDICT r1 missing #2).
+"""Torch-SwinIR checkpoint naming → framework params.
 
 Builds a state_dict in the official torch-SwinIR naming
 (`layers.N.residual_group.blocks.M.*`, the family the reference loads at

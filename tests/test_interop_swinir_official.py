@@ -1,4 +1,4 @@
-"""Official SwinIR-S checkpoint fixture at FULL size (VERDICT r3 missing #2).
+"""Official SwinIR-S checkpoint fixture at FULL size.
 
 The reference's actual artifact is
 ``002_lightweightSR_DIV2K_s64w8_SwinIR-S_x2.pth`` loaded at

@@ -17,10 +17,9 @@ CHILD = """
 import os
 import jax
 
-from pytorch_distributedtraining_tpu.runtime.cache import cache_dir
+from pytorch_distributedtraining_tpu.runtime.cache import enable_compile_cache
 
-jax.config.update("jax_compilation_cache_dir", cache_dir("test_compile"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+enable_compile_cache()
 
 from pytorch_distributedtraining_tpu.runtime import dist
 
@@ -57,6 +56,31 @@ def test_launch_cli_two_ranks(tmp_path):
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert os.path.exists(marker + "0") and os.path.exists(marker + "1")
+
+
+def test_ranks_refused_on_a_host_with_chips(monkeypatch, capsys):
+    """A chip belongs to one process: several local ranks that would all
+    open the host's TPU are refused with a message, not left to hang."""
+    import pytest
+
+    from pytorch_distributedtraining_tpu.runtime import launch
+
+    monkeypatch.setattr(launch, "local_tpu_chips", lambda: 4)
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert launch.shared_chip_refusal(1, False) is None  # one process: fine
+    assert launch.shared_chip_refusal(4, True) is None  # ranks held to CPU
+    assert "one process" in launch.shared_chip_refusal(4, False)
+    with pytest.raises(SystemExit) as exit_info:
+        launch.main(["--nproc_per_node=2", "child.py"])
+    assert exit_info.value.code == 2
+    assert "--one_cpu_device_per_rank" in capsys.readouterr().err
+    with pytest.raises(RuntimeError, match="chip"):
+        launch.spawn(print, nprocs=2, one_cpu_device=False)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")  # inherited: never the chip
+    assert launch.shared_chip_refusal(4, False) is None
+    monkeypatch.setattr(launch, "local_tpu_chips", lambda: 0)
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert launch.shared_chip_refusal(4, False) is None  # no chips here
 
 
 def test_launch_elastic_restart(tmp_path):
@@ -124,8 +148,8 @@ def test_elastic_restart_resumes_from_checkpoint(tmp_path):
         "import os, sys\n"
         "import numpy as np\n"
         "import jax\n"
-        "from pytorch_distributedtraining_tpu.runtime.cache import cache_dir\n"
-        "jax.config.update('jax_compilation_cache_dir', cache_dir('test_compile'))\n"
+        "from pytorch_distributedtraining_tpu.runtime.cache import enable_compile_cache\n"
+        "enable_compile_cache()\n"
         "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)\n"
         "from pytorch_distributedtraining_tpu.runtime import dist\n"
         "dist.initialize()\n"

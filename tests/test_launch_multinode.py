@@ -1,4 +1,4 @@
-"""Two-launcher multi-node simulation (VERDICT r3 missing #3).
+"""Two-launcher multi-node simulation.
 
 The reference's launch line is one `torch.distributed.launch` per node
 (`/root/reference/Stoke-DDP.py:1-2`); multi-node rendezvous is two
@@ -27,8 +27,8 @@ import os
 import numpy as np
 import jax
 
-from pytorch_distributedtraining_tpu.runtime.cache import cache_dir
-jax.config.update("jax_compilation_cache_dir", cache_dir("test_compile"))
+from pytorch_distributedtraining_tpu.runtime.cache import enable_compile_cache
+enable_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 from pytorch_distributedtraining_tpu.runtime import dist
@@ -93,8 +93,8 @@ FATE_CHILD = """
 import os
 import jax
 
-from pytorch_distributedtraining_tpu.runtime.cache import cache_dir
-jax.config.update("jax_compilation_cache_dir", cache_dir("test_compile"))
+from pytorch_distributedtraining_tpu.runtime.cache import enable_compile_cache
+enable_compile_cache()
 
 from pytorch_distributedtraining_tpu.runtime import dist
 

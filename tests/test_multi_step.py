@@ -1,6 +1,6 @@
 """MultiStep: K steps per dispatch == K sequential step() calls.
 
-The wrapper exists for dispatch-bound hosts/links (BASELINE.md round-4);
+The wrapper exists for dispatch-bound hosts/links;
 its contract is that rolling steps into one `lax.scan` program changes
 dispatch count only — math, rng folding, and state evolution identical.
 """

@@ -84,7 +84,7 @@ def test_offload_policy_falls_back_and_trains_on_cpu(devices8, caplog):
 
 
 def test_param_offload_falls_back_and_trains_on_cpu(devices8, caplog):
-    """DeepspeedOffloadParamConfig twin (VERDICT r3 missing #5): params in
+    """DeepspeedOffloadParamConfig twin: params in
     pinned host memory where supported; on the CPU backend the policy must
     fall back with a warning and training must still run."""
     mesh = make_mesh(MeshSpec(dp=8), devices=devices8)
@@ -179,7 +179,7 @@ def test_facade_wires_offload_knobs():
 
 def test_facade_warns_on_inert_offload_knobs(recwarn):
     """Surface-parity knobs with no TPU effect warn instead of silently
-    dropping (VERDICT r3 item 10): AIO config and non-cpu offload tiers."""
+    dropping: AIO config and non-cpu offload tiers."""
     import warnings
 
     from pytorch_distributedtraining_tpu.stoke.config import (

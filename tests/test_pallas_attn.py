@@ -57,8 +57,8 @@ def test_gradients_match(qkv):
 
 @pytest.mark.parametrize("causal", [True, False])
 def test_gradients_match_blocks(qkv, causal):
-    """Pallas dq/dk/dv kernels vs XLA AD across block shapes (bwd is now
-    in-kernel recompute, not an XLA fallback — VERDICT r1 weak #7)."""
+    """Pallas dq/dk/dv kernels vs XLA AD across block shapes (bwd is
+    in-kernel recompute, not an XLA fallback)."""
     q, k, v = qkv
 
     def loss_flash(q, k, v):

@@ -1,9 +1,9 @@
 """Fused Pallas window attention vs the XLA einsum path (interpret mode).
 
 The kernel must be a drop-in for `models/swinir.py:WindowAttention`
-(`attn_impl='pallas'`): same parameters, same outputs, same gradients —
-including the relative-position-bias gradient the backward kernel
-accumulates across the window grid.
+(`attn_impl='pallas_interpret'` on CPU): same parameters, same outputs,
+same gradients — including the relative-position-bias gradient the
+backward kernel accumulates across the window grid.
 """
 
 import numpy as np
@@ -77,7 +77,7 @@ def test_module_pallas_impl_matches_xla():
     mask = None  # module-level mask parity is covered by the SwinIR test
     mods = {
         impl: WindowAttention(12, 3, 4, attn_impl=impl)
-        for impl in ("xla", "pallas")
+        for impl in ("xla", "pallas_interpret")
     }
     params = mods["xla"].init(jax.random.key(0), x, mask)["params"]
 
@@ -86,7 +86,7 @@ def test_module_pallas_impl_matches_xla():
         return jnp.mean(out**2)
 
     lx, gx = jax.value_and_grad(lambda p: loss("xla", p))(params)
-    lp, gp = jax.value_and_grad(lambda p: loss("pallas", p))(params)
+    lp, gp = jax.value_and_grad(lambda p: loss("pallas_interpret", p))(params)
     np.testing.assert_allclose(float(lx), float(lp), rtol=1e-5)
     for (ka, a), (kb, b) in zip(
         sorted(jax.tree_util.tree_leaves_with_path(gx), key=lambda t: str(t[0])),
@@ -103,7 +103,7 @@ def test_swinir_attn_impl_parity_with_shift():
     x = jnp.asarray(r.random((2, 16, 16, 3)), jnp.float32)
     kw = dict(depths=[2], embed_dim=12, num_heads=[2], window_size=4)
     m_x = SwinIR(attn_impl="xla", **kw)
-    m_p = SwinIR(attn_impl="pallas", **kw)
+    m_p = SwinIR(attn_impl="pallas_interpret", **kw)
     params = m_x.init(jax.random.key(0), x)["params"]
 
     def loss(m, p):
@@ -172,13 +172,13 @@ def test_packed_matches_unpacked(with_mask):
 
 
 def test_swinir_attn_pack_parity():
-    """SwinIR(attn_impl='pallas', attn_pack=2) end to end vs xla impl,
+    """SwinIR(attn_impl='pallas_interpret', attn_pack=2) vs xla impl,
     including shifted layers (mask path)."""
     r = np.random.default_rng(8)
     x = jnp.asarray(r.random((2, 16, 16, 3)), jnp.float32)
     kw = dict(depths=[2], embed_dim=12, num_heads=[2], window_size=4)
     m_x = SwinIR(attn_impl="xla", **kw)
-    m_p = SwinIR(attn_impl="pallas", attn_pack=2, **kw)
+    m_p = SwinIR(attn_impl="pallas_interpret", attn_pack=2, **kw)
     params = m_x.init(jax.random.key(0), x)["params"]
     ox = m_x.apply({"params": params}, x)
     op = m_p.apply({"params": params}, x)

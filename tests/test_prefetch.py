@@ -445,15 +445,14 @@ def test_enable_compile_cache(tmp_path, monkeypatch):
         enable_compile_cache,
     )
 
+    # the variable names the directory; repo code then sets none itself
     target = tmp_path / "cc"
-    monkeypatch.setenv("GRAFT_COMPILE_CACHE", str(target))
+    target.mkdir()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(target))
     old = jax.config.jax_compilation_cache_dir
-    try:
-        path = enable_compile_cache("testlabel")
-        assert path == str(target) and target.is_dir()
-        assert jax.config.jax_compilation_cache_dir == str(target)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old)
+    path = enable_compile_cache()
+    assert path == str(target)
+    assert jax.config.jax_compilation_cache_dir == old
     assert cache_entry_count(path) == 0
     (target / "entry.bin").write_bytes(b"x")
     assert cache_entry_count(path) == 1
@@ -467,7 +466,11 @@ def test_enable_compile_cache_disabled(monkeypatch):
     )
 
     monkeypatch.setenv("GRAFT_COMPILE_CACHE", "0")
-    assert enable_compile_cache("testlabel") is None
+    try:
+        assert enable_compile_cache() is None
+        assert not jax.config.jax_enable_compilation_cache
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
 
 
 @pytest.mark.slow

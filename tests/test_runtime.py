@@ -123,24 +123,55 @@ def test_hybrid_mesh_rejects_dp_in_spec(devices8):
         make_hybrid_mesh(MeshSpec(dp=2, fsdp=4), dcn_dp=1, devices=devices8)
 
 
-def test_machine_keyed_cache_dir():
-    """VERDICT r3 weak #5: compile-cache dirs carry a host-CPU fingerprint
-    so foreign AOT artifacts miss instead of SIGILL-ing."""
+_CACHE_PROBE = """
+import json, os, sys
+import jax
+set_dirs = []
+_update = jax.config.update
+def spy(name, value):
+    if name == "jax_compilation_cache_dir":
+        set_dirs.append(value)
+    return _update(name, value)
+jax.config.update = spy
+from pytorch_distributedtraining_tpu.runtime.cache import enable_compile_cache
+path = enable_compile_cache()
+print(json.dumps({"path": path, "set_dirs": set_dirs,
+                  "config": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+@pytest.mark.parametrize("env_dir", ["set", "unset"])
+def test_compile_cache_placement(env_dir, tmp_path):
+    """One function places the cache: where JAX_COMPILATION_CACHE_DIR says
+    (and then repo code sets no directory itself), else one fixed
+    in-checkout path that every process of the checkout agrees on."""
+    import json
     import os
+    import subprocess
+    import sys
 
-    from pytorch_distributedtraining_tpu.runtime.cache import (
-        cache_dir,
-        machine_fingerprint,
+    from pytorch_distributedtraining_tpu.runtime import cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items() if k != cache.ENV_VAR}
+    env["PYTHONPATH"] = repo
+    if env_dir == "set":
+        env[cache.ENV_VAR] = str(tmp_path)
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE], env=env, cwd=str(tmp_path),
+        capture_output=True, text=True, timeout=120,
     )
-
-    fp = machine_fingerprint()
-    assert fp == machine_fingerprint()  # stable
-    assert len(fp) == 12 and all(c in "0123456789abcdef" for c in fp)
-    d = cache_dir("unit")
-    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        assert d == os.environ["JAX_COMPILATION_CACHE_DIR"]
+    assert out.returncode == 0, out.stderr[-2000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    if env_dir == "set":
+        assert got["path"] == got["config"] == str(tmp_path)
+        assert got["set_dirs"] == []
     else:
-        assert fp in d and "unit" in d
+        # the other process: this one, asked from a different cwd
+        assert got["path"] == got["config"] == cache.DEFAULT_DIR
+        assert cache.DEFAULT_DIR == os.path.join(repo, ".jax_cache")
+        for salt in (str(os.getuid()), str(os.getpid()), "/tmp"):
+            assert salt not in os.path.relpath(got["path"], repo)
 
 
 def test_hybrid_mesh_fallback_keeps_slices_on_dp(devices8):

@@ -267,7 +267,7 @@ def test_cli_source_exits_zero(capsys):
     assert cli.main(["--source"]) == 0
     out = capsys.readouterr().out
     assert "analyzing repo source (plane: source)" in out
-    assert '"stage": "source"' in out  # harvest-facing JSON summary line
+    assert '"stage": "source"' in out  # JSON summary line
 
 
 def test_cli_src_fixture_implies_source(capsys):

@@ -8,7 +8,7 @@ rank's local slice. In this framework that contract is met structurally —
 under global-view ``jit`` a dp-sharded batch is one logical array, so
 ``nn.BatchNorm``'s mean/var reductions are global and XLA inserts the
 collective (see ``models/resnet.py`` docstring). These tests *prove* it
-rather than argue it (VERDICT r1, "What's missing" #3).
+rather than argue it.
 """
 
 import jax

@@ -277,7 +277,7 @@ class TestGoodputLedger:
 
         f = model_train_flops(FakeSwin(), 8, (64, 64))
         per_img_gflops = f / 8 / 1e9
-        # BASELINE.md derives ~21 GFLOPs/image trained for SwinIR-S x2@64
+        # ~21 GFLOPs/image trained for SwinIR-S x2@64 (3x forward)
         assert 15.0 < per_img_gflops < 30.0
 
     def test_gpt2_flops_scale_with_batch(self):
