@@ -42,10 +42,15 @@ def _process_worker_init(dataset):
 
 
 def _process_worker_fetch(i):
+    """``(sample, seconds)``: the worker's own fetch time rides back with
+    the sample (summed per batch into the arguments of ``loader.collect``
+    and ``loader.collate``)."""
+    t0 = time.perf_counter()
     # chaos site: the plan crosses the spawn boundary via GRAFT_FAULT_PLAN
     # in the inherited env, so worker-crash drills work on real workers
     fault_point("loader.fetch", index=i)
-    return _WORKER_DATASET[i]
+    sample = _WORKER_DATASET[i]
+    return sample, time.perf_counter() - t0
 
 
 def stack_windows(batches, k: int):
@@ -190,6 +195,9 @@ class DataLoader:
         self.spec = spec
         self.device_prefetch = max(0, int(device_prefetch))
         self.auto_set_epoch = auto_set_epoch
+        # batches produced so far, counted by the one thread that produces
+        # them: the ``n`` argument of the loader's spans
+        self._batches = 0
         self._epoch = 0
         self._explicit_epoch = False  # set_epoch() ever called by the user
         self._iter_count = 0
@@ -308,14 +316,13 @@ class DataLoader:
             return self.device_iter(depth=self.device_prefetch)
         return self._make_iter(self._begin_epoch())
 
-    def device_iter(self, mesh=None, spec=None, depth: int = 2, probe=None):
+    def device_iter(self, mesh=None, spec=None, depth: int = 2):
         """Iterate device-staged batches: a :class:`~.prefetch
         .DevicePrefetcher` keeps up to ``depth`` sharded global batches
         placed on the mesh ahead of the consumer, so the H2D transfer
         overlaps the running step instead of serializing with it.
 
-        ``mesh``/``spec`` default to the loader's own; ``probe`` is an
-        optional ``TransferOverlapProbe`` receiving wait samples. On a
+        ``mesh``/``spec`` default to the loader's own. On a
         ``loader.stage`` fault (or a real staging failure) the iterator
         degrades to synchronous feeding — no hang, no dropped batch.
         """
@@ -329,7 +336,7 @@ class DataLoader:
             )
         pf = DevicePrefetcher(
             self._make_iter(self._begin_epoch(), to_device=False),
-            mesh, spec, depth=depth, probe=probe,
+            mesh, spec, depth=depth,
         )
         # the prefetcher's feeder pulls fetches ahead of the consumer, so
         # it is an epoch-race hazard exactly like a pooled feeder — even
@@ -379,8 +386,10 @@ class DataLoader:
         multiprocessing context was requested (the GIL escape hatch)."""
         if self._mp_context is None:
             def _thread_fetch(i):
+                t0 = time.perf_counter()
                 fault_point("loader.fetch", index=i)
-                return self.dataset[i]
+                sample = self.dataset[i]
+                return sample, time.perf_counter() - t0
 
             return (
                 ThreadPoolExecutor(max_workers=self.num_workers),
@@ -423,14 +432,10 @@ class DataLoader:
         # which stages them asynchronously instead
         if self.num_workers <= 0:
             for idxs in batches:
-                t0 = time.perf_counter()
-                item = self.collate_fn([self.dataset[i] for i in idxs])
-                if telemetry.enabled():
-                    # synchronous fetch+collate = unoverlapped input time
-                    telemetry.add_span(
-                        "input.fetch", "input", t0,
-                        time.perf_counter() - t0,
-                    )
+                # synchronous fetch+collate = unoverlapped input time
+                with telemetry.span("input.fetch", "input", n=self._batches):
+                    item = self.collate_fn([self.dataset[i] for i in idxs])
+                self._batches += 1
                 yield self._to_device(item) if to_device else item
             return
 
@@ -457,6 +462,25 @@ class DataLoader:
         # fully-drained feeder whose thread is merely not yet reaped
         # (is_alive() alone races with the consumer seeing _END)
 
+        def produce(futs):
+            """One batch on the feeder thread: ``loader.collect`` is
+            blocked in the workers' ``Future.result()``, ``loader.collate``
+            holds the GIL the dispatch thread also needs. The workers' own
+            fetch seconds are known once collected: they ride on the ring's
+            ``collect`` record and, in a profile (an annotation's arguments
+            are fixed when it opens), on the same batch's ``collate``."""
+            n = self._batches
+            with telemetry.span("loader.collect", "input", n=n) as collect:
+                fetched = [f.result() for f in futs]
+                worker_s = sum(seconds for _, seconds in fetched)
+                collect.set(worker_s=worker_s)
+            with telemetry.span(
+                "loader.collate", "input", n=n, worker_s=worker_s
+            ):
+                item = self.collate_fn([sample for sample, _ in fetched])
+            self._batches = n + 1
+            return item
+
         def feeder():
             try:
                 from collections import deque
@@ -468,12 +492,10 @@ class DataLoader:
                         return
                     pending.append([pool.submit(fetch, i) for i in idxs])
                     if len(pending) >= lookahead:
-                        futs = pending.popleft()
-                        if not put(self.collate_fn([f.result() for f in futs])):
+                        if not put(produce(pending.popleft())):
                             return
                 while pending:
-                    futs = pending.popleft()
-                    if not put(self.collate_fn([f.result() for f in futs])):
+                    if not put(produce(pending.popleft())):
                         return
                 drained.set()
                 put(_END)
@@ -487,14 +509,9 @@ class DataLoader:
         t.start()
         try:
             while True:
-                t0 = time.perf_counter()
-                item = q.get()
-                if telemetry.enabled():
-                    # consumer blocked on the feeder = input_wait bucket
-                    telemetry.add_span(
-                        "input.wait", "input", t0,
-                        time.perf_counter() - t0,
-                    )
+                # consumer blocked on the feeder = input_wait bucket
+                with telemetry.span("input.wait", "input", queued=q.qsize()):
+                    item = q.get()
                 if item is _END:
                     return
                 if isinstance(item, tuple) and len(item) == 2 and item[0] is _ERR:

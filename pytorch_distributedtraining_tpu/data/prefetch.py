@@ -33,6 +33,7 @@ import warnings
 
 import numpy as np
 
+from ..observe import trace as telemetry
 from ..resilience.faults import fault_point
 
 __all__ = ["DevicePrefetcher", "place_on_mesh"]
@@ -138,24 +139,20 @@ class DevicePrefetcher:
 
     Wraps an iterator of host (or already-placed) pytree batches; see the
     module docstring for the overlap/donation/degrade contracts. Exposes
-    the wait accounting the overlap-fraction probe consumes:
+    its own wait accounting:
 
     - ``wait_s``  — cumulative consumer time blocked on the next batch
       (unhidden transfer + host pipeline time),
     - ``staged`` / ``yielded`` / ``degraded`` — staging telemetry,
     - :meth:`overlap_fraction` — ``1 - wait_s/elapsed`` over a timed loop.
-
-    An optional ``probe`` (:class:`~..observe.profiling
-    .TransferOverlapProbe`) receives every wait sample.
     """
 
-    def __init__(self, source, mesh, spec, depth: int = 2, probe=None):
+    def __init__(self, source, mesh, spec, depth: int = 2):
         if mesh is None or spec is None:
             raise ValueError("DevicePrefetcher needs both mesh and spec")
         self.mesh = mesh
         self.spec = spec
         self.depth = max(1, int(depth))
-        self.probe = probe
         self.wait_s = 0.0
         self.yielded = 0
         self._stats = _StageStats()
@@ -188,35 +185,29 @@ class DevicePrefetcher:
 
     def __next__(self):
         t0 = time.perf_counter()
-        while True:
-            try:
-                kind, payload = self._q.get(timeout=0.5)
-                break
-            except queue.Empty:
-                if not self._thread.is_alive():
-                    # feeder hard-killed without a terminal item (action
-                    # "exit"/"kill" fires os-level): surface, don't spin
-                    self._drained.set()
-                    raise StopIteration
-        if kind == "end":
-            raise StopIteration
-        if kind == "err":
-            raise payload
-        if kind == "host":  # degraded path: place synchronously, no drop
-            payload = place_on_mesh(payload, self.mesh, self.spec)
-        dt = time.perf_counter() - t0
-        self.wait_s += dt
-        if self.probe is not None:
-            self.probe.note_wait(dt)
-        from ..observe import trace as telemetry
-
-        if telemetry.enabled():
-            # the wait IS the unhidden input time (goodput input_wait
-            # bucket) — recorded consumer-side so it never double-bills
-            # the feeder thread's overlapped staging
-            telemetry.add_span(
-                "input.wait", "input", t0, dt, {"n": self.yielded}
-            )
+        # the wait IS the unhidden input time (goodput input_wait bucket)
+        # — a consumer-side span, so it never double-bills the feeder
+        # thread's overlapped staging
+        with telemetry.span(
+            "input.wait", "input", n=self.yielded, queued=self._q.qsize()
+        ):
+            while True:
+                try:
+                    kind, payload = self._q.get(timeout=0.5)
+                    break
+                except queue.Empty:
+                    if not self._thread.is_alive():
+                        # feeder hard-killed without a terminal item (action
+                        # "exit"/"kill" fires os-level): surface, don't spin
+                        self._drained.set()
+                        raise StopIteration
+            if kind == "end":
+                raise StopIteration
+            if kind == "err":
+                raise payload
+            if kind == "host":  # degraded path: place synchronously, no drop
+                payload = place_on_mesh(payload, self.mesh, self.spec)
+        self.wait_s += time.perf_counter() - t0
         self.yielded += 1
         return payload
 

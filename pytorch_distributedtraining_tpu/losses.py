@@ -74,10 +74,13 @@ class FeatLoss:
         self.pixel_weight = pixel_weight
 
     def __call__(self, outputs, targets):
-        fo = _feature_pyramid(outputs, self.filters)
-        ft = _feature_pyramid(targets, self.filters)
-        feat = sum(jnp.mean(jnp.abs(a - b)) for a, b in zip(fo, ft))
-        return feat / len(fo) + self.pixel_weight * l1_loss(outputs, targets)
+        with jax.named_scope("feat_loss"):  # the loss network, by name
+            fo = _feature_pyramid(outputs, self.filters)
+            ft = _feature_pyramid(targets, self.filters)
+            feat = sum(jnp.mean(jnp.abs(a - b)) for a, b in zip(fo, ft))
+            return feat / len(fo) + self.pixel_weight * l1_loss(
+                outputs, targets
+            )
 
 
 class VGGFeatLoss:

@@ -138,6 +138,7 @@ def default_attention(q, k, v, *, causal: bool = True):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+
 class Block(nn.Module):
     """Pre-LN transformer block: LN → attn → +res, LN → MLP → +res.
 
@@ -255,17 +256,21 @@ class Block(nn.Module):
         qkv = dense(3 * d, "c_attn")(y)
         q, k, v = jnp.split(qkv, 3, axis=-1)
         reshape = lambda a: a.reshape(*a.shape[:2], h, d // h)  # noqa: E731
-        if self.decode and self.paged is not None:
-            y = self._paged_attention(
-                reshape(q), reshape(k), reshape(v), page_table, lengths
-            )
-        elif self.decode:
-            y = self._cached_attention(
-                reshape(q), reshape(k), reshape(v),
-                jnp.zeros((), jnp.int32) if start_index is None else start_index,
-            )
-        else:
-            y = self.attn_fn(reshape(q), reshape(k), reshape(v), causal=True)
+        # the attention core (score, mask, softmax, value product; not the
+        # projections, which Flax names) under one scope, whatever
+        # ``attn_fn``: metadata only, so a profile gives attention's share
+        with jax.named_scope("attention"):
+            q, k, v = reshape(q), reshape(k), reshape(v)
+            if self.decode and self.paged is not None:
+                y = self._paged_attention(q, k, v, page_table, lengths)
+            elif self.decode:
+                y = self._cached_attention(
+                    q, k, v,
+                    jnp.zeros((), jnp.int32)
+                    if start_index is None else start_index,
+                )
+            else:
+                y = self.attn_fn(q, k, v, causal=True)
         # named-remat tag (parallel/remat.py "names"/"offload" policies):
         # save the softmax·V product, recompute the cheap projections
         y = checkpoint_name(y, "attn_out")
@@ -350,7 +355,8 @@ class GPT2(nn.Module):
                     "cache", "position", lambda: jnp.zeros((), jnp.int32)
                 )
             pe = wpe[:t]
-        x = wte[tokens].astype(cfg.dtype) + pe.astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = wte[tokens].astype(cfg.dtype) + pe.astype(cfg.dtype)
         x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
 
         if cfg.scan_layers and not self.decode:
@@ -378,20 +384,22 @@ class GPT2(nn.Module):
                 )(x, deterministic, start_index, page_table, lengths)
 
         x = nn.LayerNorm(epsilon=1e-5, dtype=cfg.dtype, name="ln_f")(x)
-        if cfg.tie_word_embeddings:
-            logits = x @ wte.T.astype(cfg.dtype)
-        else:
-            logits = nn.Dense(
-                cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                name="lm_head",
-            )(x)
-        return logits.astype(jnp.float32)
+        with jax.named_scope("head"):  # the (tied) output projection
+            if cfg.tie_word_embeddings:
+                logits = x @ wte.T.astype(cfg.dtype)
+            else:
+                logits = nn.Dense(
+                    cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                    name="lm_head",
+                )(x)
+            return logits.astype(jnp.float32)
 
 
 def cross_entropy_loss(logits, targets, ignore_index: int = -100):
     """Token-level CE with ignore mask; logits [B,T,V], targets [B,T]."""
-    mask = (targets != ignore_index).astype(jnp.float32)
-    safe = jnp.where(targets == ignore_index, 0, targets)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
-    return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    with jax.named_scope("loss"):
+        mask = (targets != ignore_index).astype(jnp.float32)
+        safe = jnp.where(targets == ignore_index, 0, targets)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
+        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)

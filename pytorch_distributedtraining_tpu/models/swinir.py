@@ -40,18 +40,27 @@ from .scan_utils import remat_block, stack_trees, unstack_tree
 def window_partition(x: jnp.ndarray, ws: int) -> jnp.ndarray:
     """[B, H, W, C] -> [B*nW, ws*ws, C]."""
     b, h, w, c = x.shape
-    x = x.reshape(b, h // ws, ws, w // ws, ws, c)
-    x = x.transpose(0, 1, 3, 2, 4, 5)
-    return x.reshape(-1, ws * ws, c)
+    with jax.named_scope("window_layout"):  # data movement, by name
+        x = x.reshape(b, h // ws, ws, w // ws, ws, c)
+        x = x.transpose(0, 1, 3, 2, 4, 5)
+        return x.reshape(-1, ws * ws, c)
 
 
 def window_reverse(wins: jnp.ndarray, ws: int, h: int, w: int) -> jnp.ndarray:
     """[B*nW, ws*ws, C] -> [B, H, W, C]."""
     c = wins.shape[-1]
     b = wins.shape[0] // ((h // ws) * (w // ws))
-    x = wins.reshape(b, h // ws, w // ws, ws, ws, c)
-    x = x.transpose(0, 1, 3, 2, 4, 5)
-    return x.reshape(b, h, w, c)
+    with jax.named_scope("window_layout"):
+        x = wins.reshape(b, h // ws, w // ws, ws, ws, c)
+        x = x.transpose(0, 1, 3, 2, 4, 5)
+        return x.reshape(b, h, w, c)
+
+
+def _roll(x: jnp.ndarray, shift: int) -> jnp.ndarray:
+    """The cyclic shift of a shifted-window layer, under the same scope as
+    the window partition: all of it is layout, none of it arithmetic."""
+    with jax.named_scope("window_layout"):
+        return jnp.roll(x, (shift, shift), axis=(1, 2))
 
 
 def _relative_position_index(ws: int) -> np.ndarray:
@@ -165,18 +174,28 @@ class WindowAttention(nn.Module):
                 "attn_impl must be one of 'xla'/'pallas'/'pallas_interpret'/"
                 f"'paired'/'blockdiag', got {self.attn_impl!r}"
             )
-        bn, n, c = x.shape  # [B*nW, ws^2, C]
-        h = self.num_heads
-        head_dim = c // h
+        c = x.shape[-1]  # x: [B*nW, ws^2, C]
         qkv = nn.Dense(3 * c, use_bias=True, dtype=self.dtype, name="qkv")(x)
-        qkv = qkv.reshape(bn, n, 3, h, head_dim).transpose(2, 0, 3, 1, 4)
-        q, k, v = qkv[0], qkv[1], qkv[2]  # [bn, h, n, d]
-
         table = self.param(
             "relative_position_bias_table",
             nn.initializers.truncated_normal(0.02),
-            ((2 * self.window_size - 1) ** 2, h),
+            ((2 * self.window_size - 1) ** 2, self.num_heads),
         )
+        # score, bias, mask, softmax and value product under one scope,
+        # whatever the implementation; the qkv / proj projections keep
+        # their Flax names (metadata only: no instruction changes)
+        with jax.named_scope("attention"):
+            out = self._core(qkv, table, mask)
+        return nn.Dense(c, dtype=self.dtype, name="proj")(out)
+
+    def _core(self, qkv, table, mask):
+        """Attention between the projections: [bn, n, 3c] -> [bn, n, c]."""
+        bn, n, _ = qkv.shape
+        h = self.num_heads
+        c = qkv.shape[-1] // 3
+        head_dim = c // h
+        qkv = qkv.reshape(bn, n, 3, h, head_dim).transpose(2, 0, 3, 1, 4)
+        q, k, v = qkv[0], qkv[1], qkv[2]  # [bn, h, n, d]
         idx = _relative_position_index(self.window_size)
         bias = table[idx.reshape(-1)].reshape(n, n, h).transpose(2, 0, 1)
 
@@ -215,8 +234,7 @@ class WindowAttention(nn.Module):
                 self.attn_impl == "pallas_interpret",
             )  # [bn, h, n, d], softmax in f32 in-kernel
             out = out.transpose(0, 2, 1, 3).reshape(bn, n, c)
-            out = checkpoint_name(out, "attn_out")
-            return nn.Dense(c, dtype=self.dtype, name="proj")(out)
+            return checkpoint_name(out, "attn_out")
 
         scale = head_dim**-0.5
         attn = (q * scale) @ k.transpose(0, 1, 3, 2)  # [bn, h, n, n]
@@ -235,8 +253,7 @@ class WindowAttention(nn.Module):
         out = (attn @ v).transpose(0, 2, 1, 3).reshape(bn, n, c)
         # named-remat tag (parallel/remat.py "names"/"offload"): save the
         # softmax·V product, recompute the cheap projections
-        out = checkpoint_name(out, "attn_out")
-        return nn.Dense(c, dtype=self.dtype, name="proj")(out)
+        return checkpoint_name(out, "attn_out")
 
     def _paired(self, qkv, bias, mask, p: int):
         """Two windows per attention: [p*n, p*n] scores with an additive
@@ -285,8 +302,7 @@ class WindowAttention(nn.Module):
         out = out.reshape(bn // p, h, p, n, d).transpose(
             0, 2, 3, 1, 4
         ).reshape(bn, n, c)
-        out = checkpoint_name(out, "attn_out")
-        return nn.Dense(c, dtype=self.dtype, name="proj")(out)
+        return checkpoint_name(out, "attn_out")
 
     def _blockdiag(self, q, k, v, bias, mask):
         """QK^T / AV as single block-diagonal-packed gemms per window:
@@ -321,8 +337,7 @@ class WindowAttention(nn.Module):
         )(v)  # [bn, h*n, h*d]
         p2 = attn.transpose(0, 2, 1, 3).reshape(bn, n, h * n)
         out = p2 @ vblk  # heads already concatenated
-        out = checkpoint_name(out, "attn_out")
-        return nn.Dense(c, dtype=self.dtype, name="proj")(out)
+        return checkpoint_name(out, "attn_out")
 
 
 class SwinLayer(nn.Module):
@@ -346,7 +361,7 @@ class SwinLayer(nn.Module):
         shortcut = x
         y = nn.LayerNorm(dtype=self.norm_dtype, name="norm1")(x)
         if self.shift > 0:
-            y = jnp.roll(y, (-self.shift, -self.shift), axis=(1, 2))
+            y = _roll(y, -self.shift)
             mask = jnp.asarray(_shift_attn_mask(hgt, wid, ws, self.shift))
         else:
             mask = None
@@ -359,7 +374,7 @@ class SwinLayer(nn.Module):
         )(wins, mask)
         y = window_reverse(wins, ws, hgt, wid)
         if self.shift > 0:
-            y = jnp.roll(y, (self.shift, self.shift), axis=(1, 2))
+            y = _roll(y, self.shift)
         x = shortcut + y.astype(shortcut.dtype)
 
         y = nn.LayerNorm(dtype=self.norm_dtype, name="norm2")(x).astype(self.dtype)
@@ -590,7 +605,8 @@ class SwinIR(nn.Module):
                 self.in_chans * r * r, (3, 3), padding="SAME",
                 dtype=self.dtype, name="conv_up",
             )(feat)
-            out = pixel_shuffle(out, r)
+            with jax.named_scope("upsample"):
+                out = pixel_shuffle(out, r)
         else:
             # classical SwinIR-M: widen to num_feat=64, staged x2 shuffles
             # (or one x3), then a final conv — the official module tree

@@ -1,9 +1,9 @@
-"""Observability: metrics sinks (W&B-compatible), profiling, step timing.
+"""Observability: metrics sinks (W&B-compatible), profiling, span telemetry.
 
 Twin of the reference's L7 layer (`/root/reference/Stoke-DDP.py`): W&B login
 /init-with-retry/log/finish (`:43,316-325,47-58,339`), rank-aware prints,
 plus the tracing the reference lacks (SURVEY §5) — `jax.profiler` hooks and
-per-step timing.
+the program's own spans.
 """
 
 # PEP 562 lazy exports: the serve fleet's control plane (serve/router.py,
@@ -80,15 +80,12 @@ _LAZY = {
     "WandbSink": ("sink", None),
     "make_sink": ("sink", None),
     "profiling": (None, None),
-    "StepTimer": ("profiling", None),
-    "TransferOverlapProbe": ("profiling", None),
     "profiler_trace": ("profiling", "trace"),
     "Tracer": ("trace", None),
     "export_chrome_trace": ("trace", None),
     "flush_flight_record": ("trace", None),
     "instant": ("trace", None),
     "span": ("trace", None),
-    "traced": ("trace", None),
 }
 
 
@@ -120,13 +117,10 @@ __all__ = [
     "NullSink",
     "WandbSink",
     "make_sink",
-    "StepTimer",
-    "TransferOverlapProbe",
     "trace",
     "profiler_trace",
     "Tracer",
     "span",
-    "traced",
     "instant",
     "export_chrome_trace",
     "flush_flight_record",

@@ -108,10 +108,11 @@ class OnDemandProfiler:
         on_capture=None,
     ):
         if trace_dir is None:
-            trace_dir = os.path.join(
-                os.environ.get("GRAFT_RUN_DIR", "/tmp/graft-captures"),
-                "captures",
-            )
+            # the run's scratch directory: $GRAFT_RUN_DIR, else under the
+            # system's temporary directory (follows TMPDIR)
+            from .trace import run_dir
+
+            trace_dir = os.path.join(run_dir(), "captures")
         self.trace_dir = trace_dir
         self.cooldown_s = float(cooldown_s)
         self.max_captures = int(max_captures)

@@ -13,7 +13,7 @@ launcher import nothing heavier):
 - :class:`GoodputLedger` — buckets a window of span records into
   ``productive / compile / input_wait / checkpoint / collective /
   outage / other``. Per-bucket interval *union* (not naive sums), so a
-  ``StepTimer`` span folded over a ``TrainStep`` dispatch span cannot
+  caller's own step span around a ``TrainStep`` dispatch span cannot
   double-count; only top-level (depth-0) spans participate — and only
   on the busiest thread. That single-tid rule is ALSO the async-
   checkpoint accounting contract (``checkpoint_sharded``): the

@@ -1,21 +1,25 @@
-"""Profiling and step timing — capability the reference lacks (SURVEY §5:
+"""Profiling — capability the reference lacks (SURVEY §5:
 "Tracing/profiling: none").
 
 - :func:`trace`: context manager around ``jax.profiler`` writing a
-  TensorBoard-loadable trace (XLA op-level, HBM, ICI traffic on TPU).
-- :class:`StepTimer`: cheap wall-clock per-step stats with warmup handling
-  (first steps include compilation).
-- :class:`TransferOverlapProbe`: host-side transfer-vs-compute overlap
-  fraction — how much of the wall clock the consumer spent blocked waiting
-  for staged input versus running the step.
+  TensorBoard-loadable trace (XLA op-level, HBM, ICI traffic on TPU). The
+  program's own spans (``observe.trace``) are in every such profile as
+  ``graft/<name>`` annotations, with no knob.
+- :func:`remember_program` / :func:`program_texts`: the compiled HLO text
+  of the programs the process ran, for whoever reads a device trace. A
+  TPU trace names each executed op by its HLO instruction and carries no
+  ``op_name``; the text maps the instruction to the scopes the program
+  gave it (``jax.named_scope``, Flax module paths, ``jvp`` / ``transpose``
+  / ``checkpoint``).
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
+import os
+import tempfile
 import warnings
-from dataclasses import dataclass, field
+import weakref
 
 # the profiler is a process-global singleton in jax: a second
 # start_trace raises. This module owns the arbitration so the manual
@@ -80,7 +84,11 @@ def stop_profiler_trace() -> None:
 
 
 @contextlib.contextmanager
-def trace(logdir: str = "/tmp/jax-trace"):
+def trace(logdir: str | None = None):
+    """Profile the block into ``logdir`` (default ``jax-trace`` under the
+    system's temporary directory, which follows ``TMPDIR``)."""
+    if logdir is None:
+        logdir = os.path.join(tempfile.gettempdir(), "jax-trace")
     started = start_profiler_trace(logdir)
     try:
         yield logdir
@@ -89,114 +97,75 @@ def trace(logdir: str = "/tmp/jax-trace"):
             stop_profiler_trace()
 
 
-@dataclass
-class StepTimer:
-    """Track step wall-times; ``summary()`` gives p50/p90/p99/mean/tails
-    excluding warmup (compile) steps.
+# -- the compiled text of the programs that ran --------------------------
+#
+# A step class or the facade calls ``remember_program`` at the FIRST call
+# of each jitted program it owns: one ``tree.map`` of the arguments to
+# their abstract signature, once; nothing is lowered or compiled then.
+# ``program_texts`` lowers and compiles from the signatures on demand —
+# after a measured window, through the persistent compilation cache.
 
-    When telemetry is enabled (``observe.trace``), every timed step is
-    also folded into the span ring buffer as a ``train.step`` span —
-    the timer and the goodput ledger read the same measurements, so the
-    two timing paths cannot disagree.
-    """
+# jitted function -> (args, kwargs, mesh); an entry goes with its program
+_PROGRAMS: weakref.WeakKeyDictionary = weakref.WeakKeyDictionary()
 
-    warmup: int = 2
-    times: list = field(default_factory=list)
-    span_name: str = "train.step"
-    _t0: float | None = None
 
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
+def _signature(x):
+    """An array as its ``ShapeDtypeStruct`` (with the sharding it was
+    committed to, which a jit without ``in_shardings`` lowers by);
+    anything else (a static flag, ``None``) as itself."""
+    import jax
 
-    def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
-        self.times.append(dt)
-        from . import trace as _trace
+    if not isinstance(x, jax.Array):
+        return x
+    return jax.ShapeDtypeStruct(
+        x.shape, x.dtype, weak_type=x.aval.weak_type,
+        sharding=x.sharding if x.committed else None,
+    )
 
-        if _trace.enabled():
-            # warmup steps are compile-bucket by construction
-            n = len(self.times)
-            _trace.add_span(
-                self.span_name,
-                "compile" if n <= self.warmup else "step",
-                self._t0, dt, {"n": n},
+
+def _ambient_mesh():
+    """The mesh of an enclosing ``with mesh:``, or None. It is part of a
+    jit's tracing context, and the caller's to choose: lowered under
+    another one, a program is traced anew (19 s for the SwinIR step on the
+    chip, PR 24) instead of found. jax has no public reader for it; where
+    the private one has moved, say so: the texts then come slowly."""
+    try:
+        from jax._src.mesh import thread_resources
+
+        mesh = thread_resources.env.physical_mesh
+    except (ImportError, AttributeError) as e:
+        warnings.warn(
+            f"remember_program: the ambient mesh cannot be read ({e}); "
+            "program_texts may trace a program anew", RuntimeWarning,
+        )
+        return None
+    return None if mesh.empty else mesh
+
+
+def remember_program(jitted, args, kwargs=None) -> None:
+    """Keep the abstract signature ``jitted`` is first called with, and
+    the mesh context it is called in."""
+    import jax
+
+    _PROGRAMS[jitted] = (
+        jax.tree.map(_signature, args),
+        jax.tree.map(_signature, dict(kwargs or {})), _ambient_mesh(),
+    )
+
+
+def program_texts() -> list:
+    """Compiled HLO text (``compiled.as_text()``, instruction metadata
+    included) of every remembered program whose owner is still alive. A
+    program that no longer lowers from its signature is left out."""
+    texts = []
+    for jitted, (args, kwargs, mesh) in list(_PROGRAMS.items()):
+        try:
+            with mesh or contextlib.nullcontext():
+                texts.append(
+                    jitted.lower(*args, **kwargs).compile().as_text()
+                )
+        except Exception as e:  # noqa: BLE001 — a reader's aid, never fatal
+            warnings.warn(
+                f"program_texts: {type(e).__name__}: {e}", RuntimeWarning
             )
-        self._t0 = None
-
-    def summary(self) -> dict:
-        steady = self.times[self.warmup :] or self.times
-        if not steady:
-            return {}
-        s = sorted(steady)
-        n = len(s)
-        return {
-            "steps": n,
-            "mean_s": sum(s) / n,
-            "p50_s": s[n // 2],
-            "p90_s": s[min(n - 1, int(0.9 * n))],
-            "p99_s": s[min(n - 1, int(0.99 * n))],
-            "min_s": s[0],
-            "max_s": s[-1],
-        }
-
-    def throughput(self, items_per_step: int) -> float:
-        m = self.summary()
-        return items_per_step / m["mean_s"] if m else 0.0
-
-
-@dataclass
-class TransferOverlapProbe:
-    """Measure how well input staging overlaps with compute.
-
-    The consumer marks time spent blocked on the input pipeline
-    (``waiting()`` / ``note_wait``) and time spent in the step itself
-    (``computing()`` / ``note_busy``). ``fraction()`` is the share of
-    accounted wall clock NOT lost to input waits — 1.0 means transfers were
-    fully hidden behind compute, 0.0 means the step was input-bound.
-
-    ``DevicePrefetcher`` accepts one as its ``probe`` and feeds
-    ``note_wait`` from its queue-get stalls, so a hot loop only needs to
-    wrap the step call in ``computing()``.
-    """
-
-    wait_s: float = 0.0
-    busy_s: float = 0.0
-    waits: int = 0
-
-    def note_wait(self, dt: float) -> None:
-        self.wait_s += max(0.0, dt)
-        self.waits += 1
-
-    def note_busy(self, dt: float) -> None:
-        self.busy_s += max(0.0, dt)
-
-    @contextlib.contextmanager
-    def waiting(self):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.note_wait(time.perf_counter() - t0)
-
-    @contextlib.contextmanager
-    def computing(self):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.note_busy(time.perf_counter() - t0)
-
-    def fraction(self) -> float | None:
-        total = self.wait_s + self.busy_s
-        if total <= 0.0:
-            return None
-        return max(0.0, min(1.0, 1.0 - self.wait_s / total))
-
-    def summary(self) -> dict:
-        return {
-            "wait_s": self.wait_s,
-            "busy_s": self.busy_s,
-            "waits": self.waits,
-            "overlap_fraction": self.fraction(),
-        }
+    return texts

@@ -1,14 +1,22 @@
 """Structured span telemetry: the shared event model under every timer.
 
-The repo's observability grew as point tools — ``StepTimer``,
-``TransferOverlapProbe``, HLO audits, JSONL sinks — none of which share an
-event vocabulary, so a bench record can say *how fast* a run was but not
-*where the time went*. This module is the substrate they now all feed:
+The repo's observability grew as point tools — step timers, HLO audits,
+JSONL sinks — none of which share an event vocabulary, so a bench record
+can say *how fast* a run was but not *where the time went*. This module
+is the substrate they now all feed:
 
-- :func:`span` — a context manager (and :func:`traced` decorator) that
-  records a named, categorized duration into a thread-safe bounded ring
-  buffer. Nesting is tracked per-thread (``depth``), so ledgers can
-  account top-level time without double counting children.
+- :func:`span` — a context manager that names a duration of the program.
+  Every span is a ``jax.profiler.TraceAnnotation("graft/<name>")``,
+  always, with no knob: whenever anyone takes a profile (the benchmark's
+  ``--trace 1``, ``OnDemandProfiler``, an operator's
+  ``jax.profiler.trace``) the program's spans are in it, on the
+  profiler's clock, nested per thread, with their attributes (the
+  optimizer-step number ``step=n`` on dispatch and facade spans) as
+  arguments. With no profiler session an annotation is a flag test.
+  With telemetry ON the span is also recorded, categorized, into a
+  thread-safe bounded ring buffer. Nesting is tracked per-thread
+  (``depth``), so ledgers can account top-level time without double
+  counting children.
 - :func:`instant` — zero-duration events (fault injections, recompiles,
   preemption signals) on the same timeline.
 - :func:`export_chrome_trace` — the buffer as Chrome trace-event JSON
@@ -22,30 +30,44 @@ event vocabulary, so a bench record can say *how fast* a run was but not
 
 Stdlib-only by contract: the bench parent and the launcher (both jax-free)
 may import this, and package import must not touch a backend
-(``tests/test_import_hygiene.py``). Disabled-path cost is one attribute
-load + one ``is None`` branch per call site — cheap enough to leave the
-instrumentation in production code paths (bench.py's
-``telemetry_overhead`` guard enforces <1% of step time when *enabled*).
+(``tests/test_import_hygiene.py``). ``TraceAnnotation`` is found through
+``sys.modules`` at first use; while jax is not loaded a span is the null
+span. Telemetry-off cost is one annotation object per span (under half a
+microsecond) — cheap enough to leave the instrumentation in production
+code paths (bench.py's ``telemetry_overhead`` guard enforces <1% of step
+time when the ring is *enabled*).
+
+The span names of the program (``graft/<name>`` in a profile):
+``TrainStep|MultiStep|EvalStep|PipelineStep|CompressedGradStep|
+HierGradStep.dispatch`` (``.compile+dispatch`` the first time);
+``facade.model|loss|backward|step|detach_and_sync_loss|fused_step`` with
+``facade.backward.grad``, ``facade.step.flush_micros|materialize_lazies|
+lr|apply|fused``, ``facade.note_loss``, ``facade.shard_batch``,
+``facade.forward``, ``facade.loss.compute``, ``facade.loss_fetch.flush``;
+``input.fetch``, ``input.wait``, ``loader.collect``, ``loader.collate``;
+``checkpoint.write|snapshot|wait``, ``preempt.agreement``;
+``serve.prefill|decode|spec_verify|tile.dispatch``.
 
 Env knobs (mirrored by ``TPUConfig.telemetry`` / ``TPUConfig.trace_dir``
 through the stoke facade, and by both drivers' ``--trace``):
 
-- ``GRAFT_TELEMETRY`` = 1/0 — enable span collection + crash handler.
+- ``GRAFT_TELEMETRY`` = 1/0 — enable the ring buffer (span collection,
+  Chrome export) + crash handler. Annotations need no knob.
 - ``GRAFT_TRACE`` = a directory — implies telemetry, and names where
   the Chrome trace JSON is exported.
 - ``GRAFT_RUN_DIR`` — run-scoped scratch directory (default
-  ``/tmp/graft-runs/<pid>``) shared by metric sinks, flight-recorder
-  files and per-rank step logs.
+  ``<tempfile.gettempdir()>/graft-runs/<pid>``, so it follows ``TMPDIR``)
+  shared by metric sinks, flight-recorder files and per-rank step logs.
 """
 
 from __future__ import annotations
 
 import collections
-import functools
 import json
 import os
 import socket
 import sys
+import tempfile
 import threading
 import time
 import traceback
@@ -53,7 +75,6 @@ import traceback
 __all__ = [
     "Tracer",
     "span",
-    "traced",
     "instant",
     "add_span",
     "dispatch_span",
@@ -96,10 +117,15 @@ def run_dir() -> str:
 
     ``GRAFT_RUN_DIR`` names it explicitly (the launcher exports one shared
     dir to every rank so rank-0 aggregation and the restart gate see all
-    processes); the default is per-process under /tmp so library defaults
-    never litter the repo checkout (the committed ``metrics.jsonl`` bug).
+    processes); the default is per-process, ``graft-runs/<pid>`` under the
+    system's temporary directory (``tempfile.gettempdir()``, which follows
+    ``TMPDIR``), so library defaults never litter the repo checkout (the
+    committed ``metrics.jsonl`` bug) and a harness with a ``TMPDIR`` of
+    its own keeps them.
     """
-    path = os.environ.get("GRAFT_RUN_DIR") or f"/tmp/graft-runs/{os.getpid()}"
+    path = os.environ.get("GRAFT_RUN_DIR") or os.path.join(
+        tempfile.gettempdir(), "graft-runs", str(os.getpid())
+    )
     os.makedirs(path, exist_ok=True)
     return path
 
@@ -174,8 +200,9 @@ class Tracer:
         self, name: str, cat: str, t0: float, dur: float,
         attrs: dict | None = None, depth: int | None = None,
     ) -> None:
-        """Record an externally-timed span (StepTimer folds in here, so
-        the timer and the ledger can never disagree about a step)."""
+        """Record an externally-timed span into the ring (it cannot be
+        an annotation after the fact: the program's own sites use
+        ``with span(...)``)."""
         if not self.enabled:
             return
         self._append({
@@ -195,10 +222,9 @@ class Tracer:
         })
 
     def span(self, name: str, cat: str = "other", **attrs):
-        """Context manager recording one duration span."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _LiveSpan(self, name, cat, attrs)
+        """Context manager recording one duration span (an annotation
+        only while this tracer is off)."""
+        return _open(self, name, cat, attrs)
 
     # -- inspection ----------------------------------------------------
 
@@ -296,7 +322,7 @@ class Tracer:
 
 
 class _NullSpanType:
-    """Disabled fast path: one shared no-op context manager."""
+    """A span while jax is not loaded: one shared no-op context manager."""
 
     __slots__ = ()
 
@@ -312,9 +338,51 @@ class _NullSpanType:
 
 _NULL_SPAN = _NullSpanType()
 
+ANNOTATION_PREFIX = "graft/"  # the program's spans in a profile
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _annotation_type():
+    """``jax.profiler.TraceAnnotation`` with a span's ``set``, found
+    through ``sys.modules`` (this module imports no jax) and resolved
+    once; None while jax is not loaded (the launcher, the bench parent)."""
+    global _ANNOTATION
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+
+    class _Annotation(profiler.TraceAnnotation):
+        __slots__ = ()
+
+        def set(self, **attrs):  # arguments are fixed at construction
+            return self
+
+    _ANNOTATION = _Annotation
+    return _Annotation
+
+
+def _annotate(name: str, attrs: dict):
+    """The span as a trace annotation only: ``graft/<name>`` with
+    ``attrs`` as its arguments. A flag test while no profile is taken."""
+    cls = _ANNOTATION or _annotation_type()
+    if cls is None:
+        return _NULL_SPAN
+    return cls(ANNOTATION_PREFIX + name, **attrs)
+
+
+def _open(tracer: "Tracer", name: str, cat: str, attrs: dict):
+    """A span: an annotation only while ``tracer`` is off, a ring record
+    (with its annotation inside) while it is on."""
+    if not tracer.enabled:
+        return _annotate(name, attrs)
+    return _LiveSpan(tracer, name, cat, attrs)
+
 
 class _LiveSpan:
-    __slots__ = ("tracer", "name", "cat", "attrs", "t0", "_depth")
+    """A span on both clocks: the ring's (``perf_counter``) and, through
+    its annotation, the profiler's."""
+
+    __slots__ = ("tracer", "name", "cat", "attrs", "t0", "_depth", "_ann")
 
     def __init__(self, tracer: Tracer, name: str, cat: str, attrs: dict):
         self.tracer = tracer
@@ -332,11 +400,14 @@ class _LiveSpan:
         stack = self.tracer._stack()
         self._depth = len(stack)
         stack.append(self)
+        self._ann = _annotate(self.name, self.attrs)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self.t0
+        self._ann.__exit__(exc_type, exc, tb)
         stack = self.tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -407,28 +478,9 @@ def configure_from_env(env: dict | None = None) -> bool:
 
 def span(name: str, cat: str = "other", **attrs):
     """``with span("step.dispatch", "step", n=i): ...`` on the default
-    tracer. Disabled cost: one branch + one allocation-free return."""
-    if not _TRACER.enabled:
-        return _NULL_SPAN
-    return _LiveSpan(_TRACER, name, cat, attrs)
-
-
-def traced(name: str | None = None, cat: str = "other"):
-    """Decorator twin of :func:`span`."""
-
-    def deco(fn):
-        label = name or f"{fn.__module__.rsplit('.', 1)[-1]}.{fn.__qualname__}"
-
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            if not _TRACER.enabled:
-                return fn(*args, **kwargs)
-            with _LiveSpan(_TRACER, label, cat, {}):
-                return fn(*args, **kwargs)
-
-        return wrapper
-
-    return deco
+    tracer: always a trace annotation ``graft/<name>`` with ``attrs`` as
+    its arguments, and a ring record when telemetry is on."""
+    return _open(_TRACER, name, cat, attrs)
 
 
 def instant(name: str, cat: str = "other", **attrs) -> None:
@@ -442,17 +494,21 @@ def dispatch_span(owner, kind: str):
     The owner's FIRST dispatch traces+compiles (or deserializes the
     cache artifact), so it lands in the ``compile`` bucket; steady-state
     dispatches are ``step``/productive. State lives on the owner object
-    (``_telemetry_warm``), not the tracer, so two steps in one process
-    each get their own compile span.
+    (``_telemetry_dispatches``, the count of its dispatches so far), not
+    the tracer, so two steps in one process each get their own compile
+    span. That count is the span's ``step`` argument: the identifier a
+    step's spans share, a Python integer kept on the host (never
+    ``state.step`` or any device value).
     """
-    if not _TRACER.enabled:
-        return _NULL_SPAN
-    if not getattr(owner, "_telemetry_warm", False):
-        owner._telemetry_warm = True
-        return _LiveSpan(
-            _TRACER, f"{kind}.compile+dispatch", "compile", {"kind": kind}
-        )
-    return _LiveSpan(_TRACER, f"{kind}.dispatch", "step", {"kind": kind})
+    n = getattr(owner, "_telemetry_dispatches", 0)
+    owner._telemetry_dispatches = n + 1
+    return _dispatch(kind, bool(n), {"kind": kind, "step": n})
+
+
+def _dispatch(kind: str, warm: bool, attrs: dict):
+    if warm:
+        return _open(_TRACER, f"{kind}.dispatch", "step", attrs)
+    return _open(_TRACER, f"{kind}.compile+dispatch", "compile", attrs)
 
 
 def bucket_dispatch_span(owner, kind: str, bucket):
@@ -465,19 +521,13 @@ def bucket_dispatch_span(owner, kind: str, bucket):
     ``step``/productive. The bucket rides on the span attrs so the SLO
     bench can attribute p99 excursions to a cold bucket.
     """
-    if not _TRACER.enabled:
-        return _NULL_SPAN
     warm = getattr(owner, "_telemetry_warm_buckets", None)
     if warm is None:
         warm = owner._telemetry_warm_buckets = set()
     key = (kind, bucket)
-    attrs = {"kind": kind, "bucket": bucket}
-    if key not in warm:
-        warm.add(key)
-        return _LiveSpan(
-            _TRACER, f"{kind}.compile+dispatch", "compile", attrs
-        )
-    return _LiveSpan(_TRACER, f"{kind}.dispatch", "step", attrs)
+    was_warm = key in warm
+    warm.add(key)
+    return _dispatch(kind, was_warm, {"kind": kind, "bucket": bucket})
 
 
 def note_recompile(owner, jitted, kind: str) -> None:

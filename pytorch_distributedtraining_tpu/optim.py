@@ -192,14 +192,15 @@ def clip_by_global_norm_recorded(
 
     def update(updates, state, params=None):
         del params, state
-        gnorm = optax.global_norm(updates)
-        trigger = gnorm > max_norm
-        scale = jnp.where(
-            trigger, max_norm / jnp.maximum(gnorm, 1e-38), 1.0
-        ).astype(jnp.float32)
-        updates = jax.tree.map(
-            lambda u: (u * scale).astype(u.dtype), updates
-        )
+        with jax.named_scope("clip"):
+            gnorm = optax.global_norm(updates)
+            trigger = gnorm > max_norm
+            scale = jnp.where(
+                trigger, max_norm / jnp.maximum(gnorm, 1e-38), 1.0
+            ).astype(jnp.float32)
+            updates = jax.tree.map(
+                lambda u: (u * scale).astype(u.dtype), updates
+            )
         return updates, RecordedClipState(
             gnorm=gnorm.astype(jnp.float32), clipped=trigger
         )
@@ -247,14 +248,27 @@ def adamw(
     if clip_grad_value is not None:
         chain.append(optax.clip(clip_grad_value))
     chain.append(
-        optax.adamw(
+        _scoped("adamw", optax.adamw(
             learning_rate=lr, b1=betas[0], b2=betas[1], eps=eps,
             weight_decay=weight_decay,
-        )
+        ))
     )
     if ema_decay is not None:
         chain.append(params_ema(ema_decay))
     return optax.chain(*chain)
+
+
+def _scoped(name: str, tx: optax.GradientTransformation):
+    """``tx`` with its update under ``jax.named_scope(name)``: the steps
+    put the whole update under "optimizer", so AdamW's arithmetic reads
+    ``optimizer/adamw`` in a compiled program's ``op_name`` (metadata
+    only: no instruction changes)."""
+
+    def update(updates, state, params=None):
+        with jax.named_scope(name):
+            return tx.update(updates, state, params)
+
+    return optax.GradientTransformation(tx.init, update)
 
 
 def sgd(
@@ -377,18 +391,23 @@ class FusedAdamW:
         update when False — the GradScaler overflow-skip, one ``where``
         on flat buffers instead of one per leaf.
         """
+        with jax.named_scope("adamw"):
+            return self._apply(gflat, opt_state, params, lr_factor, gate)
+
+    def _apply(self, gflat, opt_state, params, lr_factor, gate):
         pflat, unravel = ravel_pytree(params)
         pad = opt_state.mu.size - pflat.size
         p32 = jnp.pad(pflat.astype(jnp.float32), (0, pad))
         g = jnp.pad(gflat, (0, pad))
-        gnorm = jnp.sqrt(jnp.sum(g * g))  # pre-clip, the metric's contract
-        if self.clip_grad_norm is not None:
-            c = jnp.float32(self.clip_grad_norm)
-            # optax.clip_by_global_norm formula: rescale only above the cap
-            g = g * jnp.where(gnorm < c, 1.0, c / gnorm)
-        if self.clip_grad_value is not None:  # chain order: norm clip first
-            v = self.clip_grad_value
-            g = jnp.clip(g, -v, v)
+        with jax.named_scope("clip"):
+            gnorm = jnp.sqrt(jnp.sum(g * g))  # pre-clip, the metric's contract
+            if self.clip_grad_norm is not None:
+                c = jnp.float32(self.clip_grad_norm)
+                # optax.clip_by_global_norm formula: rescale only above the cap
+                g = g * jnp.where(gnorm < c, 1.0, c / gnorm)
+            if self.clip_grad_value is not None:  # chain order: norm clip first
+                v = self.clip_grad_value
+                g = jnp.clip(g, -v, v)
         count = opt_state.count + 1
         mu = self.b1 * opt_state.mu + (1.0 - self.b1) * g
         nu = self.b2 * opt_state.nu + (1.0 - self.b2) * (g * g)

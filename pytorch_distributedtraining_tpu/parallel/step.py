@@ -35,6 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..observe import trace as telemetry
 from ..observe.numerics import NumericsProbe
+from ..observe.profiling import remember_program
 from ..optim import FusedAdamW, clip_stats, refresh_params_ema
 from ..precision import DynamicLossScaler, Policy as PrecisionPolicy
 from ..runtime.mesh import batch_spec, stacked_batch_spec
@@ -255,16 +256,20 @@ class TrainStep:
                     state.params, state.model_state, mb,
                     jax.random.fold_in(rng, i), state.scaler
                 )
-                acc = jax.tree.map(
-                    lambda a, g: a + g.astype(jnp.float32), acc, grads
-                )
+                with jax.named_scope("grad_accum"):
+                    acc = jax.tree.map(
+                        lambda a, g: a + g.astype(jnp.float32), acc, grads
+                    )
                 return acc, (loss, aux)
 
             gsum, (losses, auxs) = jax.lax.scan(
                 body, zero, (micro, jnp.arange(self.grad_accum_steps))
             )
             # mean over microbatches (the ref divides in backward, :79,251)
-            grads = jax.tree.map(lambda g: g / self.grad_accum_steps, gsum)
+            with jax.named_scope("grad_accum"):
+                grads = jax.tree.map(
+                    lambda g: g / self.grad_accum_steps, gsum
+                )
             loss = jnp.mean(losses)
             aux = {
                 k: (
@@ -288,80 +293,91 @@ class TrainStep:
         finite = jnp.bool_(True)
         gnorm_fused = None
         updates = None  # tree path sets it; the probe's update-ratio feed
-        if self.fused is not None:
-            # flat path: ravel once, scaler/clip/Adam as full-width vector
-            # ops, unravel once (see optim.FusedAdamW.apply_tree)
-            if self.detect_anomaly:
-                # NaN survives the (power-of-two) scale, so the tree-path
-                # check below reads identically on still-scaled grads
-                self._check_finite(
-                    grads, loss, nan_only=self.loss_scaler is not None
+        # everything from the finished gradients to the new state is the
+        # "optimizer" of a profile (metadata only: no instruction changes);
+        # optim.py nests "clip" and "adamw" inside it
+        with jax.named_scope("optimizer"):
+            if self.fused is not None:
+                # flat path: ravel once, scaler/clip/Adam as full-width vector
+                # ops, unravel once (see optim.FusedAdamW.apply_tree)
+                if self.detect_anomaly:
+                    # NaN survives the (power-of-two) scale, so the tree-path
+                    # check below reads identically on still-scaled grads
+                    self._check_finite(
+                        grads, loss, nan_only=self.loss_scaler is not None
+                    )
+                scaler_state = (
+                    state.scaler if self.loss_scaler is not None else None
                 )
-            scaler_state = (
-                state.scaler if self.loss_scaler is not None else None
-            )
-            new_params, new_opt, new_scaler, gnorm_fused = (
-                self.fused.apply_tree(
-                    grads,
-                    state.opt_state,
-                    state.params,
-                    lr_factor,
-                    scaler=self.loss_scaler,
-                    scaler_state=scaler_state,
+                new_params, new_opt, new_scaler, gnorm_fused = (
+                    self.fused.apply_tree(
+                        grads,
+                        state.opt_state,
+                        state.params,
+                        lr_factor,
+                        scaler=self.loss_scaler,
+                        scaler_state=scaler_state,
+                    )
                 )
-            )
-        else:
-            # fp16: unscale to f32 before clip/update (torch unscale_ parity)
-            if self.loss_scaler is not None and state.scaler is not None:
-                grads = self.loss_scaler.unscale_grads(grads, state.scaler)
-                finite = DynamicLossScaler.grads_finite(grads)
-                new_scaler = self.loss_scaler.update(state.scaler, finite)
             else:
-                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+                # fp16: unscale to f32 before clip/update (torch unscale_
+                # parity)
+                if self.loss_scaler is not None and state.scaler is not None:
+                    grads = self.loss_scaler.unscale_grads(grads, state.scaler)
+                    finite = DynamicLossScaler.grads_finite(grads)
+                    new_scaler = self.loss_scaler.update(state.scaler, finite)
+                else:
+                    grads = jax.tree.map(
+                        lambda g: g.astype(jnp.float32), grads
+                    )
 
-            if self.detect_anomaly:
-                # after unscale; with a loss scaler active only NaN is
-                # anomalous (inf overflows are the scaler's own
-                # backoff-and-skip path — torch's set_detect_anomaly
-                # likewise flags NaN only)
-                self._check_finite(
-                    grads, loss, nan_only=self.loss_scaler is not None
+                if self.detect_anomaly:
+                    # after unscale; with a loss scaler active only NaN is
+                    # anomalous (inf overflows are the scaler's own
+                    # backoff-and-skip path — torch's set_detect_anomaly
+                    # likewise flags NaN only)
+                    self._check_finite(
+                        grads, loss, nan_only=self.loss_scaler is not None
+                    )
+
+                # ZeRO-2/3: force reduce-scatter layout on grads (named, so
+                # a profile can tell this traffic from parameter gathers)
+                gspecs = self.policy.grads_specs(state.params, self.mesh)
+                if gspecs is not None:
+                    with jax.named_scope("grad_sync"):
+                        grads = constrain(grads, gspecs, self.mesh)
+
+                updates, new_opt = self.tx.update(
+                    grads, state.opt_state, state.params
+                )
+                # the plateau scheduler's factor
+                updates = jax.tree.map(lambda u: u * lr_factor, updates)
+                if self.update_wire_dtype is not None:
+                    # narrow the fan-out wire (see ctor comment); the add below
+                    # upcasts back to the param dtype
+                    updates = jax.tree.map(
+                        lambda u: u.astype(self.update_wire_dtype), updates
+                    )
+                new_params = optax.apply_updates(state.params, updates)
+                # params-EMA correction: the chain element saw pre-lr_factor
+                # updates; recompute from the TRUE new params
+                # (optim.params_ema)
+                new_opt = refresh_params_ema(
+                    state.opt_state, new_opt, new_params
                 )
 
-            # ZeRO-2/3: force reduce-scatter layout on grads
-            gspecs = self.policy.grads_specs(state.params, self.mesh)
-            if gspecs is not None:
-                grads = constrain(grads, gspecs, self.mesh)
-
-            updates, new_opt = self.tx.update(
-                grads, state.opt_state, state.params
-            )
-            updates = jax.tree.map(lambda u: u * lr_factor, updates)  # plateau
-            if self.update_wire_dtype is not None:
-                # narrow the fan-out wire (see ctor comment); the add below
-                # upcasts back to the param dtype
-                updates = jax.tree.map(
-                    lambda u: u.astype(self.update_wire_dtype), updates
-                )
-            new_params = optax.apply_updates(state.params, updates)
-            # params-EMA correction: the chain element saw pre-lr_factor
-            # updates; recompute from the TRUE new params (optim.params_ema)
-            new_opt = refresh_params_ema(
-                state.opt_state, new_opt, new_params
-            )
-
-            if self.loss_scaler is not None:
-                # skip the whole update on overflow (GradScaler semantics)
-                new_params = jax.tree.map(
-                    lambda n, o: jnp.where(finite, n, o),
-                    new_params,
-                    state.params,
-                )
-                new_opt = jax.tree.map(
-                    lambda n, o: jnp.where(finite, n, o),
-                    new_opt,
-                    state.opt_state,
-                )
+                if self.loss_scaler is not None:
+                    # skip the whole update on overflow (GradScaler semantics)
+                    new_params = jax.tree.map(
+                        lambda n, o: jnp.where(finite, n, o),
+                        new_params,
+                        state.params,
+                    )
+                    new_opt = jax.tree.map(
+                        lambda n, o: jnp.where(finite, n, o),
+                        new_opt,
+                        state.opt_state,
+                    )
 
         new_model_state = aux.get("model_state", state.model_state)
         metrics = {"loss": loss.astype(jnp.float32)}
@@ -375,23 +391,23 @@ class TrainStep:
             else (recorded_clip.gnorm if recorded_clip is not None else None)
         )
         if self.extra_metrics:
-            metrics["grad_norm"] = (
-                gnorm_known
-                if gnorm_known is not None
-                else optax.global_norm(grads)
-            )
+            if gnorm_known is None:
+                with jax.named_scope("metrics"):  # a norm only to return it
+                    gnorm_known = optax.global_norm(grads)
+            metrics["grad_norm"] = gnorm_known
             if recorded_clip is not None:
                 metrics["grad_clipped"] = recorded_clip.clipped
             if new_scaler is not None:
                 metrics["loss_scale"] = new_scaler.scale
         if self.numerics is not None:
-            metrics["numerics"] = self.numerics.aux(
-                grads,
-                params=state.params,
-                updates=updates,
-                model_state=new_model_state,
-                grad_norm=gnorm_known,
-            )
+            with jax.named_scope("metrics"):
+                metrics["numerics"] = self.numerics.aux(
+                    grads,
+                    params=state.params,
+                    updates=updates,
+                    model_state=new_model_state,
+                    grad_norm=gnorm_known,
+                )
         for k, v in aux.items():
             if k != "model_state":
                 metrics[k] = v
@@ -535,8 +551,11 @@ class TrainStep:
         # async dispatch: the span covers trace/compile + enqueue, not
         # device execution (which overlaps the host's next iteration —
         # the final block_until_ready's sync span absorbs the remainder)
+        lr_factor = jnp.float32(lr_factor)
+        if not hasattr(self, "_telemetry_dispatches"):  # the first call
+            remember_program(self._jitted, (state, batch, lr_factor))
         with telemetry.dispatch_span(self, "TrainStep"):
-            out = self._jitted(state, batch, jnp.float32(lr_factor))
+            out = self._jitted(state, batch, lr_factor)
         telemetry.note_recompile(self, self._jitted, "TrainStep")
         return out
 

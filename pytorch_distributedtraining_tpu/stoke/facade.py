@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from .. import checkpoint as ckpt
 from .. import optim as optim_mod
 from ..data import DataLoader as _DataLoader
+from ..observe import trace as _telemetry
+from ..observe.profiling import remember_program
 from ..ops import sync_scalar_device
 from ..parallel import (
     CompressedGradStep,
@@ -274,7 +276,8 @@ def _ema_update(ema, val):
     """0.98-decay loss monitor folded on device (`Stoke-DDP.py:76` EMA);
     keeping it as a compiled scalar op lets the facade track the loss
     without a per-step host sync."""
-    return 0.98 * ema + 0.02 * jnp.asarray(val, jnp.float32)
+    with jax.named_scope("metrics"):
+        return 0.98 * ema + 0.02 * jnp.asarray(val, jnp.float32)
 
 
 class _AsyncScalarFetcher:
@@ -345,7 +348,7 @@ class _AsyncScalarFetcher:
         """Block until submitted fetches landed (or worker death/timeout);
         return the freshest value."""
         deadline = time.monotonic() + timeout
-        with self._cond:
+        with _telemetry.span("facade.loss_fetch.flush", "step"), self._cond:
             while self._pending is not None or self._busy:
                 alive = self._thread is not None and self._thread.is_alive()
                 remaining = deadline - time.monotonic()
@@ -472,10 +475,12 @@ class _LazyOutput(_LazyBase):
 
     def materialize(self):
         if self._value is None:
-            self._value, _ = self._facade._jit_fwd(
-                self._params, self._model_state, self._inputs, self._rng(),
-                train=True,
-            )
+            facade = self._facade
+            with facade._span("facade.forward"):
+                self._value, _ = facade._run(
+                    "_jit_fwd", self._params, self._model_state,
+                    self._inputs, self._rng(), train=True,
+                )
         return self._value
 
     @property
@@ -602,8 +607,6 @@ class Stoke:
         # + crash flight recorder; export_trace() writes the Chrome trace
         self.telemetry, self.trace_dir = _telemetry_from_env(self.tpu_config)
         if self.telemetry:
-            from ..observe import trace as _telemetry
-
             _telemetry.enable()
         # numerics observability plane (env > TPUConfig): fused on-device
         # probes on the step + the host-side divergence watchdog; the
@@ -950,6 +953,13 @@ class Stoke:
         # (inputs, targets, lazy_loss | None, lazy_output | None) per micro
         self._pending_micro = []
         self._accepts_train = self._model_accepts("train")
+        # always on, plain adds the facade owns: dispatches of each compiled
+        # program (by the attribute that holds it; ``_run`` keeps a
+        # program's signature at its first), and the optimizer steps taken
+        # — a Python integer kept on the host, the ``step`` argument of
+        # every facade span (never ``state.step``: that is a device value)
+        self.programs = {}
+        self._opt_steps = 0
 
         if sample_input is not None:
             self.init(sample_input)
@@ -1061,13 +1071,20 @@ class Stoke:
             return precision.cast_to_output(out), new_state
 
         self._jit_fwd = jax.jit(fwd, static_argnames=("train",))
-        self._jit_loss = jax.jit(lambda o, t: loss_callable(o, t))
+
+        def loss_only(o, t):
+            with jax.named_scope("loss"):
+                return loss_callable(o, t)
+
+        self._jit_loss = jax.jit(loss_only)
+        self._jit_ema = _ema_update
 
         def fwd_loss(p, model_state, x, y, rng):
             out, new_state = self._apply_model(
                 precision.cast_to_compute(p), model_state, x, True, rng
             )
-            loss = loss_callable(out, y)
+            with jax.named_scope("loss"):
+                loss = loss_callable(out, y)
             return loss, precision.cast_to_output(out), new_state
 
         # the eager .backward() path honors Policy.remat too (the fused
@@ -1100,8 +1117,11 @@ class Stoke:
         accum = self.grad_accum_steps
 
         def acc(buf, grads):
-            g32 = jax.tree.map(lambda g: g.astype(jnp.float32) / accum, grads)
-            return g32 if buf is None else jax.tree.map(jnp.add, buf, g32)
+            with jax.named_scope("grad_accum"):
+                g32 = jax.tree.map(
+                    lambda g: g.astype(jnp.float32) / accum, grads
+                )
+                return g32 if buf is None else jax.tree.map(jnp.add, buf, g32)
 
         self._jit_acc_first = jax.jit(lambda g: acc(None, g))
         self._jit_acc = jax.jit(acc)
@@ -1116,6 +1136,12 @@ class Stoke:
         fused_tx = tx if isinstance(tx, optim_mod.FusedAdamW) else None
 
         def apply_updates(params, opt_state, scaler_state, grads, lr):
+            # the update program is the "optimizer" of a profile, as in
+            # TrainStep (metadata only); optim.py nests "clip" and "adamw"
+            with jax.named_scope("optimizer"):
+                return update(params, opt_state, scaler_state, grads, lr)
+
+        def update(params, opt_state, scaler_state, grads, lr):
             params = stream_to_device(params, param_shardings)
             opt_state = stream_to_device(opt_state, opt_shardings)
             if fused_tx is not None:
@@ -1138,7 +1164,8 @@ class Stoke:
                 new_scaler = scaler.update(scaler_state, finite)
             gspecs = policy.grads_specs(params, mesh)
             if gspecs is not None:
-                grads = constrain(grads, gspecs, mesh)
+                with jax.named_scope("grad_sync"):
+                    grads = constrain(grads, gspecs, mesh)
             updates, new_opt = tx.update(grads, opt_state, params)
             updates = jax.tree.map(lambda u: u * lr, updates)
             if wire_dtype is not None:
@@ -1241,6 +1268,35 @@ class Stoke:
             ),
             donate_argnums=(0, 1),
         )
+        # the programs themselves, by the attribute that holds each: a
+        # subclass may wrap the attributes (a counter), the signatures
+        # kept for a trace reader (``_run``) are the programs'
+        self._jits = {
+            name: getattr(self, name) for name in (
+                "_jit_fwd", "_jit_loss", "_jit_ema", "_jit_loss_grad",
+                "_jit_acc_first", "_jit_acc", "_jit_apply",
+                "_jit_eager_step",
+            )
+        }
+
+    def _span(self, name: str):
+        """A span of the facade (``observe.trace``): a trace annotation
+        always, a ring record with telemetry on; its ``step`` argument is
+        the number of optimizer steps taken so far. The first optimizer
+        step traces and compiles the programs, so its spans are billed to
+        the ledger's ``compile`` bucket, as a step's first dispatch is."""
+        n = self._opt_steps
+        return _telemetry.span(name, "step" if n else "compile", step=n)
+
+    def _run(self, name: str, *args, **kwargs):
+        """Call the compiled program held by attribute ``name``, counted;
+        at its first call its abstract signature is kept
+        (``observe.profiling.remember_program``)."""
+        n = self.programs.get(name, 0)
+        if not n:
+            remember_program(self._jits[name], args, kwargs)
+        self.programs[name] = n + 1
+        return getattr(self, name)(*args, **kwargs)
 
     # -- eager-parity runtime surface --------------------------------------
 
@@ -1254,31 +1310,38 @@ class Stoke:
         fwd+bwd program (no double forward)."""
         if self._state is None:
             self.init(inputs)
-        inputs = self._shard_batch(inputs)
-        self._last_inputs = inputs
-        if self._training:
-            lazy = _LazyOutput(
-                self, inputs, self._state.params, self._state.model_state,
-                (self._state.rng, self._state.step),
-            )
-            self._lazy_output = lazy
-            self._pending_lazies.append(weakref.ref(lazy))
-            return lazy
-        return self._run_forward(inputs, train=False)
+        with self._span("facade.model"):
+            inputs = self._shard_batch(inputs)
+            self._last_inputs = inputs
+            if self._training:
+                lazy = _LazyOutput(
+                    self, inputs, self._state.params,
+                    self._state.model_state,
+                    (self._state.rng, self._state.step),
+                )
+                self._lazy_output = lazy
+                self._pending_lazies.append(weakref.ref(lazy))
+                return lazy
+            return self._run_forward(inputs, train=False)
 
     def _run_forward(self, inputs, train: bool):
-        rng = jax.random.fold_in(self._state.rng, self._state.step)
-        out, _ = self._jit_fwd(
-            self._state.params, self._state.model_state, inputs, rng,
-            train=train,
-        )
+        with self._span("facade.forward"):
+            rng = jax.random.fold_in(self._state.rng, self._state.step)
+            out, _ = self._run(
+                "_jit_fwd", self._state.params, self._state.model_state,
+                inputs, rng, train=train,
+            )
         return out
+
+    def _compute_loss(self, outputs, targets):
+        with self._span("facade.loss.compute"):
+            loss = self._run("_jit_loss", outputs, targets)
+        self._note_loss(loss)
+        return loss
 
     def _materialize_loss(self, output, targets):
         """Fallback for direct use of a deferred loss before backward()."""
-        loss = self._jit_loss(output.materialize(), targets)
-        self._note_loss(loss)
-        return loss
+        return self._compute_loss(output.materialize(), targets)
 
     def _materialize_lazy_loss(self, lazy):
         """Early use of a deferred loss.
@@ -1298,17 +1361,16 @@ class Stoke:
         """Loss computation (`Stoke-DDP.py:74,118`). Deferred when the
         outputs are themselves deferred — ``.backward()`` then resolves it
         from the fused grad program at zero extra cost."""
-        targets = self._shard_batch(targets)
-        self._last_targets = targets
-        if isinstance(outputs, _LazyOutput) and outputs._value is None:
-            lazy = _LazyLoss(self, outputs, targets)
-            self._lazy_loss = lazy
-            return lazy
-        if isinstance(outputs, _LazyOutput):
-            outputs = outputs.materialize()
-        loss = self._jit_loss(outputs, targets)
-        self._note_loss(loss)
-        return loss
+        with self._span("facade.loss"):
+            targets = self._shard_batch(targets)
+            self._last_targets = targets
+            if isinstance(outputs, _LazyOutput) and outputs._value is None:
+                lazy = _LazyLoss(self, outputs, targets)
+                self._lazy_loss = lazy
+                return lazy
+            if isinstance(outputs, _LazyOutput):
+                outputs = outputs.materialize()
+            return self._compute_loss(outputs, targets)
 
     def backward(self, loss=None):
         """Backward (`Stoke-DDP.py:79`).
@@ -1330,44 +1392,49 @@ class Stoke:
             raise RuntimeError(
                 "backward() needs a preceding model(inputs) and loss(outputs, targets)"
             )
-        lazy_loss = loss if isinstance(loss, _LazyLoss) else self._lazy_loss
-        lazy_out = self._lazy_output
-        self._lazy_loss = None
-        self._lazy_output = None
-        if self.fuse_eager_step:
-            self._pending_micro.append(
-                (self._last_inputs, self._last_targets, lazy_loss, lazy_out)
+        with self._span("facade.backward"):
+            lazy_loss = (
+                loss if isinstance(loss, _LazyLoss) else self._lazy_loss
+            )
+            lazy_out = self._lazy_output
+            self._lazy_loss = None
+            self._lazy_output = None
+            if self.fuse_eager_step:
+                self._pending_micro.append((
+                    self._last_inputs, self._last_targets, lazy_loss,
+                    lazy_out,
+                ))
+                self._backward_count += 1
+                # split-path parity: a caller that brought its own concrete
+                # loss gets it back, not None
+                return lazy_loss if lazy_loss is not None else loss
+            val = self._backward_now(
+                self._last_inputs, self._last_targets, lazy_loss, lazy_out
             )
             self._backward_count += 1
-            # split-path parity: a caller that brought its own concrete
-            # loss gets it back, not None
-            return lazy_loss if lazy_loss is not None else loss
-        val = self._backward_now(
-            self._last_inputs, self._last_targets, lazy_loss, lazy_out
-        )
-        self._backward_count += 1
-        return val
+            return val
 
     def _backward_now(self, x, y, lazy_loss=None, lazy_out=None):
         """Split-path backward on one micro (does NOT bump the counter)."""
-        rng = jax.random.fold_in(self._state.rng, self._state.step)
-        loss_val, out, new_model_state, grads = self._jit_loss_grad(
-            self._state.params,
-            self._state.model_state,
-            x,
-            y,
-            rng,
-            self._state.scaler,
-        )
-        self._state = self._state.replace(model_state=new_model_state)
-        if self.grad_accum_steps == 1 and self._grad_acc is None:
-            self._grad_acc = grads  # scale 1/1 and f32 cast are no-ops
-        else:
-            self._grad_acc = (
-                self._jit_acc_first(grads)
-                if self._grad_acc is None
-                else self._jit_acc(self._grad_acc, grads)
+        # the grad program and the accumulate: dispatch, blocked or not
+        with self._span("facade.backward.grad"):
+            rng = jax.random.fold_in(self._state.rng, self._state.step)
+            loss_val, out, new_model_state, grads = self._run(
+                "_jit_loss_grad",
+                self._state.params,
+                self._state.model_state,
+                x,
+                y,
+                rng,
+                self._state.scaler,
             )
+            self._state = self._state.replace(model_state=new_model_state)
+            if self.grad_accum_steps == 1 and self._grad_acc is None:
+                self._grad_acc = grads  # scale 1/1 and f32 cast are no-ops
+            elif self._grad_acc is None:
+                self._grad_acc = self._run("_jit_acc_first", grads)
+            else:
+                self._grad_acc = self._run("_jit_acc", self._grad_acc, grads)
         self._note_loss(loss_val)
         # resolve the deferred loss/output handles from the fused program's
         # own results, so `detach_and_sync_loss(loss)` and any later use of
@@ -1397,40 +1464,54 @@ class Stoke:
     def step(self):
         """Optimizer step (`Stoke-DDP.py:82`): fires every
         ``grad_accum_steps``-th call (Stoke accumulation semantics)."""
-        if self._backward_count == 0:
-            return
-        if self._backward_count % self.grad_accum_steps != 0:
-            return
-        if (
-            self._pending_micro
-            and self._grad_acc is None
-            and len(self._pending_micro) == self.grad_accum_steps
-        ):
-            return self._step_fused()
-        self._flush_pending_micros()
+        with self._span("facade.step"):
+            if self._backward_count == 0:
+                return
+            if self._backward_count % self.grad_accum_steps != 0:
+                return
+            if (
+                self._pending_micro
+                and self._grad_acc is None
+                and len(self._pending_micro) == self.grad_accum_steps
+            ):
+                return self._step_fused()
+            self._step_split()
+
+    def _step_split(self):
+        """The update as its own program, after the grad programs. Each
+        part that can leave the device waiting is a span of its own, and
+        every dispatch is inside exactly one innermost span."""
+        with self._span("facade.step.flush_micros"):
+            self._flush_pending_micros()
         # any still-deferred handles hold references to the CURRENT params,
         # whose buffers _jit_apply is about to donate — materialize them now
         # so late use reproduces the pre-step forward instead of crashing
-        for ref in self._pending_lazies:
-            lazy = ref()
-            if lazy is not None:
-                lazy.materialize()
-        self._pending_lazies = []
-        new_params, new_opt, new_scaler = self._jit_apply(
-            self._state.params,
-            self._state.opt_state,
-            self._state.scaler,
-            self._grad_acc,
-            jnp.float32(self._opt_handle.lr),
-        )
-        self._state = self._state.replace(
-            params=new_params,
-            opt_state=new_opt,
-            scaler=new_scaler,
-            step=self._state.step + 1,
-        )
+        with self._span("facade.step.materialize_lazies"):
+            for ref in self._pending_lazies:
+                lazy = ref()
+                if lazy is not None:
+                    lazy.materialize()
+            self._pending_lazies = []
+        with self._span("facade.step.lr"):  # a host float onto the device
+            lr = jnp.float32(self._opt_handle.lr)
+        with self._span("facade.step.apply"):
+            new_params, new_opt, new_scaler = self._run(
+                "_jit_apply",
+                self._state.params,
+                self._state.opt_state,
+                self._state.scaler,
+                self._grad_acc,
+                lr,
+            )
+            self._state = self._state.replace(
+                params=new_params,
+                opt_state=new_opt,
+                scaler=new_scaler,
+                step=self._state.step + 1,
+            )
         self._grad_acc = None
         self._backward_count = 0
+        self._opt_steps += 1
 
     def _step_fused(self):
         """The deferred accum window as one compiled program."""
@@ -1452,22 +1533,25 @@ class Stoke:
         self._pending_lazies = []
         micros = tuple((x, y) for x, y, _, _ in window)
         has_ema = self._ema_dev is not None
-        ema_in = self._ema_dev if has_ema else jnp.float32(0.0)
-        (
-            losses, outs, new_ms, new_params, new_opt, new_scaler,
-            new_ema, last_l32,
-        ) = self._jit_eager_step(
-            self._state.params,
-            self._state.opt_state,
-            self._state.scaler,
-            self._state.model_state,
-            micros,
-            self._state.rng,
-            self._state.step,
-            jnp.float32(self._opt_handle.lr),
-            ema_in,
-            jnp.bool_(has_ema),
-        )
+        with self._span("facade.step.fused"):  # the window's one dispatch
+            ema_in = self._ema_dev if has_ema else jnp.float32(0.0)
+            (
+                losses, outs, new_ms, new_params, new_opt, new_scaler,
+                new_ema, last_l32,
+            ) = self._run(
+                "_jit_eager_step",
+                self._state.params,
+                self._state.opt_state,
+                self._state.scaler,
+                self._state.model_state,
+                micros,
+                self._state.rng,
+                self._state.step,
+                jnp.float32(self._opt_handle.lr),
+                ema_in,
+                jnp.bool_(has_ema),
+            )
+            next_step = self._state.step + 1
         # EMA/last-loss bookkeeping came back from the program itself —
         # no per-micro _note_loss dispatches on the fused path (last_l32
         # is the final micro's scalar mean, matching _note_loss's
@@ -1493,10 +1577,11 @@ class Stoke:
             opt_state=new_opt,
             scaler=new_scaler,
             model_state=new_ms,
-            step=self._state.step + 1,
+            step=next_step,
         )
         self._grad_acc = None
         self._backward_count = 0
+        self._opt_steps += 1
 
     def zero_grad(self):
         """Drop accumulated grads (raw-loop parity, `Fairscale-DDP.py:97`).
@@ -1515,9 +1600,10 @@ class Stoke:
         *tensor* — so `sum_loss += ...` accumulation stays on device and
         the hot loop never blocks the host; ``float()`` it at log points.
         """
-        if isinstance(loss, (_LazyLoss, _LazyOutput)):
-            loss = loss.materialize()
-        return sync_scalar_device(loss)
+        with self._span("facade.detach_and_sync_loss"):
+            if isinstance(loss, (_LazyLoss, _LazyOutput)):
+                loss = loss.materialize()
+            return sync_scalar_device(loss)
 
     # -- fused fast path ---------------------------------------------------
 
@@ -1692,17 +1778,19 @@ class Stoke:
                 self._build_fused(),
                 (self._shard_batch(inputs), self._shard_batch(targets)),
             )
-        self._state, metrics = self._fused(
-            self._state,
-            (self._shard_batch(inputs), self._shard_batch(targets)),
-            lr_factor=self._opt_handle.lr,
-        )
-        self._note_loss(metrics["loss"])
-        self._observe_numerics(metrics)
-        if self.capture is not None:
-            self._last_batch = (inputs, targets)
-            self.capture.note_step()
-        return metrics
+        with self._span("facade.fused_step"):
+            batch = (self._shard_batch(inputs), self._shard_batch(targets))
+            # the step's own dispatch span is the innermost one here
+            self._state, metrics = self._fused(
+                self._state, batch, lr_factor=self._opt_handle.lr
+            )
+            self._opt_steps += 1
+            self._note_loss(metrics["loss"])
+            self._observe_numerics(metrics)
+            if self.capture is not None:
+                self._last_batch = (inputs, targets)
+                self.capture.note_step()
+            return metrics
 
     def _compiled_hlo_text(self) -> str | None:
         """Compiled HLO of the fused step (a cache hit after the first
@@ -1846,10 +1934,13 @@ class Stoke:
         from jax.sharding import NamedSharding
 
         sharding = NamedSharding(self.mesh, batch_spec(self.mesh))
-        return jax.tree.map(
-            lambda a: jax.make_array_from_process_local_data(sharding, np.asarray(a)),
-            x,
-        )
+        with self._span("facade.shard_batch"):  # host data onto the mesh
+            return jax.tree.map(
+                lambda a: jax.make_array_from_process_local_data(
+                    sharding, np.asarray(a)
+                ),
+                x,
+            )
 
     # -- checkpoint --------------------------------------------------------
 
@@ -2090,8 +2181,6 @@ class Stoke:
         at construction ($GRAFT_TRACE / TPUConfig.trace_dir) > the shared
         run dir. Returns the written path, or None when telemetry was
         never enabled (nothing to export ≠ an error)."""
-        from ..observe import trace as _telemetry
-
         if not _telemetry.enabled() and not _telemetry.records():
             return None
         if path is None:
@@ -2316,11 +2405,12 @@ class Stoke:
         if loss.ndim != 0:  # per-sample/per-shard losses: monitor the mean
             loss = jnp.mean(loss)
         self._last_loss_dev = loss
-        self._ema_dev = (
-            jnp.asarray(loss, jnp.float32)
-            if self._ema_dev is None
-            else _ema_update(self._ema_dev, loss)
-        )
+        with self._span("facade.note_loss"):  # the monitor's own dispatch
+            self._ema_dev = (
+                jnp.asarray(loss, jnp.float32)
+                if self._ema_dev is None
+                else self._run("_jit_ema", self._ema_dev, loss)
+            )
         if self.verbose:
             # keep the async display value ~one link-RTT fresh even when
             # print_ema_loss is called rarely (staleness otherwise spans

@@ -1,4 +1,4 @@
-"""Metrics sinks, wandb shim, step timer."""
+"""Metrics sinks, wandb shim."""
 
 import json
 import os
@@ -7,7 +7,6 @@ import numpy as np
 
 from pytorch_distributedtraining_tpu.observe import (
     JSONLSink,
-    StepTimer,
     make_sink,
     wandb,
 )
@@ -45,17 +44,3 @@ def test_wandb_shim_reference_pattern(tmp_path, monkeypatch):
     wandb.finish()
     assert os.path.exists(tmp_path / "metrics.jsonl")
 
-
-def test_step_timer_summary():
-    t = StepTimer(warmup=1)
-    import time
-
-    for _ in range(4):
-        with t:
-            time.sleep(0.01)
-    s = t.summary()
-    assert s["steps"] == 3
-    assert 0.005 < s["p50_s"] < 0.1
-    assert s["p99_s"] >= s["p50_s"]
-    assert s["max_s"] >= s["p99_s"]
-    assert t.throughput(10) > 0

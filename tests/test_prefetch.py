@@ -1,4 +1,4 @@
-"""Device prefetch, overlap probe/audit, and compile-cache knob tests.
+"""Device prefetch, overlap audit, and compile-cache knob tests.
 
 CPU-runnable coverage for the overlap subsystem: DevicePrefetcher
 ordering/depth/degradation, the loader.stage fault site, the
@@ -24,7 +24,6 @@ from pytorch_distributedtraining_tpu.data import (
     stack_windows,
 )
 from pytorch_distributedtraining_tpu.observe import (
-    TransferOverlapProbe,
     collectives_schedulable,
     overlap_audit,
 )
@@ -274,40 +273,7 @@ def test_place_on_mesh_pads_ragged_tail(mesh8):
     np.testing.assert_array_equal(arr[5], xs[-1])  # repeat-last padding
 
 
-# -- overlap probe -----------------------------------------------------------
-
-
-def test_overlap_probe_fraction_math():
-    p = TransferOverlapProbe()
-    assert p.fraction() is None  # nothing accounted yet
-    p.note_busy(0.9)
-    p.note_wait(0.1)
-    assert p.fraction() == pytest.approx(0.9)
-    assert p.waits == 1
-    s = p.summary()
-    assert s["overlap_fraction"] == pytest.approx(0.9)
-    assert s["wait_s"] == pytest.approx(0.1)
-
-
-def test_overlap_probe_context_managers():
-    p = TransferOverlapProbe()
-    with p.computing():
-        time.sleep(0.02)
-    with p.waiting():
-        time.sleep(0.01)
-    assert p.busy_s > 0 and p.wait_s > 0 and p.waits == 1
-    assert 0.0 <= p.fraction() <= 1.0
-
-
-def test_prefetcher_feeds_probe(mesh8):
-    xs, ys = _pairs(n=16)
-    probe = TransferOverlapProbe()
-    dl = DataLoader(TensorDataset(xs, ys), batch_size=8, mesh=mesh8,
-                    spec=batch_spec(mesh8))
-    for b in dl.device_iter(depth=1, probe=probe):
-        probe.note_busy(0.05)  # simulated step
-    assert probe.waits == 2  # one wait sample per yielded batch
-    assert probe.fraction() is not None
+# -- overlap fraction ----------------------------------------------------------
 
 
 def test_prefetcher_overlap_fraction_bounds(mesh8):

@@ -95,15 +95,6 @@ class TestSpans:
         trace.instant("ghost.event")
         assert trace.records() == []
 
-    def test_traced_decorator(self, live_tracer):
-        @trace.traced(cat="input")
-        def fetch():
-            return 42
-
-        assert fetch() == 42
-        rec = trace.records()[-1]
-        assert rec["cat"] == "input" and "fetch" in rec["name"]
-
     def test_dispatch_span_warm_transition(self, live_tracer):
         class Owner:
             pass
