@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ..parallel.spec import pin_batch
 from ..precision import fp8_dot_general_cls
 from .generate import (
     kv_scale_block,
@@ -246,6 +247,10 @@ class Block(nn.Module):
                  page_table=None, lengths=None):
         cfg = self.cfg
         d, h = cfg.n_embd, cfg.n_head
+        # the residual stream enters and leaves every block in the layout
+        # the step's batch has (identity unless a step publishes one): in
+        # the scan body, so in the rematerialised computation too
+        x = pin_batch(x)
         dense = lambda feat, name: nn.Dense(  # noqa: E731
             feat, dtype=cfg.dtype, name=name,
             kernel_init=nn.initializers.normal(0.02),
@@ -284,7 +289,7 @@ class Block(nn.Module):
         y = nn.gelu(y, approximate=True)
         y = dense(d, "mlp_proj")(y)
         y = nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
-        out = x + y
+        out = pin_batch(x + y)
         if self.as_scan_body:
             return out, None
         return out
@@ -356,7 +361,7 @@ class GPT2(nn.Module):
                 )
             pe = wpe[:t]
         with jax.named_scope("embed"):
-            x = wte[tokens].astype(cfg.dtype) + pe.astype(cfg.dtype)
+            x = pin_batch(wte[tokens].astype(cfg.dtype) + pe.astype(cfg.dtype))
         x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
 
         if cfg.scan_layers and not self.decode:
@@ -392,7 +397,7 @@ class GPT2(nn.Module):
                     cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
                     name="lm_head",
                 )(x)
-            return logits.astype(jnp.float32)
+            return pin_batch(logits.astype(jnp.float32))
 
 
 def cross_entropy_loss(logits, targets, ignore_index: int = -100):

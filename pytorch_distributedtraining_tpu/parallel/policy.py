@@ -8,6 +8,12 @@ Each policy answers three questions about the train state
   3. are **grads** constrained to a sharded layout in-step (forcing XLA to
      emit reduce-scatter instead of all-reduce)?
 
+A policy lays out state only. Activations are the step's: ``TrainStep``
+publishes its batch layout while the loss is traced and the model pins its
+residual stream to it (``spec.batch_layout`` / ``spec.pin_batch``), because
+with sharded parameters and free activations GSPMD chose, on a 2x2, to keep
+the weights in place and gather the batch: hidden-sharded tensor parallelism.
+
 Aliases keep the reference's vocabulary: ``OSS`` == ZeRO-1
 (`/root/reference/Fairscale-DDP.py:86`), ``ShardedDDP`` == ZeRO-2
 (`Fairscale-DDP.py:89`), ``FSDP`` == ZeRO-3 (Stoke's ``fairscale_fsdp``
@@ -110,7 +116,9 @@ class ZeRO2(ZeRO1):
 class ZeRO3(ZeRO2):
     """+ param sharding — FSDP twin (Stoke ``fairscale_fsdp`` surface;
     BASELINE.json config 4). ``remat=True`` trades FLOPs for HBM like
-    FSDP's activation checkpointing."""
+    FSDP's activation checkpointing. The parameters are gathered for their
+    use only because the step pins activations to the batch layout
+    (``spec.pin_batch``); left free, GSPMD gathers the batch instead."""
 
     shard_params: bool = True
 

@@ -9,9 +9,15 @@ sizes (a known Fairscale OSS pain point).
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import math
+
 import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ..runtime.mesh import data_axes
 
 
 def shard_axis(mesh: Mesh) -> str | None:
@@ -126,3 +132,47 @@ def constrain(tree, tree_of_specs, mesh: Mesh):
         tree_of_specs,
         is_leaf=lambda x: isinstance(x, P),
     )
+
+
+# The mesh whose data axes the batch of the step being traced is split over.
+_BATCH_LAYOUT = contextvars.ContextVar("batch_layout", default=None)
+
+
+@contextlib.contextmanager
+def batch_layout(mesh: Mesh):
+    """Publish, while a step is traced, that its batch is split over
+    ``mesh``'s data axes: what :func:`pin_batch` holds activations to.
+
+    The step that owns the mesh says it (``TrainStep`` around its loss
+    function); a model never asks jax for an ambient mesh.
+    """
+    token = _BATCH_LAYOUT.set(mesh)
+    try:
+        yield
+    finally:
+        _BATCH_LAYOUT.reset(token)
+
+
+def pin_batch(x):
+    """Hold ``x`` ([batch, ...]) to the published batch layout (in-jit).
+
+    Only the leading dimension is constrained, to the mesh's data axes;
+    every other dimension stays the partitioner's, so the hidden and
+    sequence splits of "tp" / "sp" meshes survive. The identity when no
+    layout is published (serving, eval, a bare ``model.apply``), when the
+    data axes hold one device, or when they do not divide the batch.
+
+    Why it exists: with sharded parameters and free activations GSPMD may
+    keep the weights where they are and gather the batch instead (ZeRO-3
+    on a 2x2 became hidden-sharded tensor parallelism, every chip computing
+    every sequence's attention); pinned, the parameters move.
+    """
+    mesh = _BATCH_LAYOUT.get()
+    if mesh is None:
+        return x
+    axes = data_axes(mesh)
+    n = math.prod(mesh.shape[a] for a in axes)
+    if n <= 1 or x.shape[0] % n:
+        return x
+    spec = P(axes, *([P.UNCONSTRAINED] * (x.ndim - 1)))
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))

@@ -41,7 +41,7 @@ from ..precision import DynamicLossScaler, Policy as PrecisionPolicy
 from ..runtime.mesh import batch_spec, stacked_batch_spec
 from .policy import Policy
 from .remat import apply_remat
-from .spec import constrain, stream_to_device
+from .spec import batch_layout, constrain, stream_to_device
 from .state import TrainState
 
 
@@ -98,7 +98,6 @@ class TrainStep:
         update_wire_dtype=None,
         numerics: NumericsProbe | bool | None = None,
     ):
-        self.loss_fn = loss_fn
         self.tx = tx
         self.mesh = mesh
         self.policy = policy or Policy()
@@ -110,7 +109,17 @@ class TrainStep:
         # activations (attention outputs in the model zoo). Finer-grained
         # per-block remat lives in the models' own `remat` flags
         # (gpt2/vit/swinir); both compose (inner checkpoints nest).
-        self.loss_fn = apply_remat(loss_fn, self.policy.remat)
+        # The step owns the mesh, so the step says where activations live:
+        # while the loss is traced the batch's layout is published and the
+        # model's residual stream is pinned to it (spec.pin_batch), else
+        # GSPMD may gather the batch instead of the sharded parameters.
+        # Inside the checkpointed function: jax.checkpoint caches its
+        # trace on the function it wraps, and this one is the step's own.
+        def laid_out(params, batch, rng, model_state):
+            with batch_layout(mesh):
+                return loss_fn(params, batch, rng, model_state)
+
+        self.loss_fn = apply_remat(laid_out, self.policy.remat)
         self.grad_accum_steps = int(grad_accum_steps)
         self.precision = precision or PrecisionPolicy()
         self.loss_scaler = loss_scaler

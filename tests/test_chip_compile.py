@@ -19,6 +19,7 @@ hands the described devices and shapes to the jitted function itself.
 
 import dataclasses
 import os
+import re
 import subprocess
 import sys
 
@@ -289,7 +290,9 @@ def _with_shardings(shapes, shardings):
     )
 
 
-def _gpt2_step(mesh, policy, cfg, *, attn_fn=None, step_cls=None, **kw):
+def _gpt2_step(
+    mesh, policy, cfg, *, attn_fn=None, step_cls=None, batch=8, **kw
+):
     from pytorch_distributedtraining_tpu import optim
     from pytorch_distributedtraining_tpu.models import GPT2, cross_entropy_loss
     from pytorch_distributedtraining_tpu.parallel import TrainStep
@@ -322,7 +325,9 @@ def _gpt2_step(mesh, policy, cfg, *, attn_fn=None, step_cls=None, **kw):
         )
     else:
         step = step_cls(loss_fn, tx, mesh, policy, **kw)
-    tokens = _on(NamedSharding(mesh, batch_spec(mesh)), (8, 1024), jnp.int32)
+    tokens = _on(
+        NamedSharding(mesh, batch_spec(mesh)), (batch, 1024), jnp.int32
+    )
     return step, _with_shardings(shapes, shardings), (tokens, tokens)
 
 
@@ -367,8 +372,58 @@ def test_gpt2_125m_zero3_on_four_chips(topo):
     _, text = _lower(step, state, batch)
     # XLA:TPU spells the gradient reduce-scatter as all-reduce/all-to-all
     counts = hlo.counts(text)
-    assert counts.get("all-gather"), counts
     assert counts.get("all-reduce") or counts.get("reduce-scatter"), counts
+    # ZeRO-3 moves parameters (an activation's gather satisfied a bare
+    # count while GSPMD kept the weights in place and gathered the batch):
+    # whole kernels arrive, and no gather has the batch as its leading
+    # dimension; each chip scores its own 2 of the 8 sequences
+    gathered = _gathered_shapes(text)
+    assert {(768, 2304), (768, 3072)} <= gathered, gathered
+    assert not [s for s in gathered if s[0] == 8], gathered
+    assert _largest_scores(text) == (2, 12, 1024, 1024)
+
+
+def _gathered_shapes(text):
+    """Result dimensions (1s dropped) of the floating-point all-gathers."""
+    from pytorch_distributedtraining_tpu.observe import hlo
+
+    return {
+        tuple(int(d) for d in dims.split(",") if d not in ("", "1"))
+        for op in hlo.collective_inventory(text) if op.kind == "all-gather"
+        for dims in re.findall(
+            r"\bb?f\d+\[([0-9,]*)\]", op.line.split(" all-gather", 1)[0]
+        )
+    }
+
+
+def _largest_scores(text):
+    """The largest [B, H, T, T] tensor a device holds (XLA also slices its
+    own sequences apart to prefetch them: smaller leading dimensions)."""
+    return max(
+        tuple(int(d) for d in dims.split(","))
+        for dims in re.findall(r"\bb?f\d+\[(\d+,\d+,1024,1024)\]", text)
+    )
+
+
+@pytest.mark.slow
+def test_gpt2_xl_zero3_at_the_cells_sizes(topo):
+    """``gpt2-xl.zero3-4chip`` as the benchmark runs it (16 x 1,024, scan +
+    remat): each chip holds the scores of its own 4 sequences and all 25
+    heads, gathers one scanned layer's kernels at a time, and plans 4.5 GB
+    of temporaries (7.6 GB while the batch was gathered instead)."""
+    from pytorch_distributedtraining_tpu.models import GPT2Config
+    from pytorch_distributedtraining_tpu.parallel import ZeRO3
+
+    cfg = dataclasses.replace(
+        GPT2Config.gpt2_xl(), scan_layers=True, remat=True
+    )
+    step, state, batch = _gpt2_step(_mesh(topo, fsdp=4), ZeRO3(), cfg, batch=16)
+    compiled, text = _lower(step, state, batch)
+    assert compiled.memory_analysis().temp_size_in_bytes < 5.5e9
+    assert _largest_scores(text) == (4, 25, 1024, 1024)
+    gathered = _gathered_shapes(text)
+    assert {(1600, 4800), (1600, 6400), (1600, 1600)} <= gathered, gathered
+    assert not [s for s in gathered if s[0] == 16], gathered
 
 
 @pytest.mark.slow
