@@ -8,6 +8,10 @@ Reference models (both from missing local modules, SURVEY §2.4):
 
 BASELINE ladder (BASELINE.json): ResNet-18/50, GPT-2 125M, ViT-B/16.
 
+Beyond both: ``MoEMLP`` (a Switch layer over "ep", ``.moe``: imported from
+its module) and ``Glm4MoeLite`` (GLM-4.7-Flash: latent attention, a
+sigmoid-routed dropless expert layer that is told which experts it holds).
+
 All models are Flax linen modules in NHWC (images) / [B, T, D] (sequences) —
 the layouts XLA:TPU tiles best — with bf16-friendly parameterization.
 Imports are lazy so pulling one model doesn't build the whole zoo.
@@ -32,6 +36,8 @@ _LAZY = {
     "GPT2": ".gpt2",
     "GPT2Config": ".gpt2",
     "cross_entropy_loss": ".gpt2",
+    "Glm4MoeLite": ".glm4_moe_lite",
+    "Glm4MoeLiteConfig": ".glm4_moe_lite",
     "ViT": ".vit",
     "ViTConfig": ".vit",
     "ViTB16": ".vit",

@@ -30,8 +30,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _BIG_NEG = -1e30
+# What a kernel may ask of a TensorCore's VMEM (128 MiB on v5e/v6e, less a
+# margin for Mosaic's own scratch), and the scoped limit a kernel gets
+# without asking. K and V (forward, dq) or Q and dO (dk/dv) ride whole
+# [T, dh] in VMEM: past the default the limit is raised to what the blocks
+# need, and past the ceiling the shape is refused before Mosaic is.
+_VMEM_CEILING = 100 * 2**20
+_VMEM_DEFAULT = 16 * 2**20
 # Per-row stats (lse, delta) ride in [B, H, T, _STAT_LANES] instead of
 # [B, H, T]: Mosaic requires a block's last two dims divisible by (8, 128)
 # or equal to the array's — a (1, 1, bq) block of a rank-3 array violates
@@ -43,16 +51,18 @@ _STAT_LANES = 8
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, bq, bk, causal, scale):
     qi = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32) * scale  # [bq, dh]
+    # operands stay in the caller's dtype (bf16 feeds the MXU at full
+    # rate; an f32 matmul takes several passes), products accumulate in f32
+    q = q_ref[0, 0]  # [bq, dh]
     t = k_ref.shape[2]
     dh = q.shape[-1]
     nk = t // bk
 
     def body(j, carry):
         acc, m, l = carry
-        k = k_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
+        k = k_ref[0, 0, pl.ds(j * bk, bk), :]
+        v = v_ref[0, 0, pl.ds(j * bk, bk), :]
+        s = scale * jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [bq, bk]
@@ -65,7 +75,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, bq, bk, causal, scale):
         p = jnp.exp(s - m_new[:, None])
         l = l * corr + jnp.sum(p, axis=1)
         acc = acc * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         return acc, m_new, l
@@ -89,16 +99,16 @@ def _dq_kernel(
     *, bq, bk, causal, scale,
 ):
     qi = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32)  # [bq, dh]
-    do = do_ref[0, 0].astype(jnp.float32)
+    q = q_ref[0, 0]  # [bq, dh]
+    do = do_ref[0, 0]
     lse = lse_ref[0, 0, :, 0]  # [bq] (lane-broadcast stats, col 0)
     delta = delta_ref[0, 0, :, 0]  # [bq]
     t = k_ref.shape[2]
     nk = t // bk
 
     def body(j, dq):
-        k = k_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)
+        k = k_ref[0, 0, pl.ds(j * bk, bk), :]
+        v = v_ref[0, 0, pl.ds(j * bk, bk), :]
         s = scale * jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -114,7 +124,7 @@ def _dq_kernel(
         )  # [bq, bk]
         ds = p * (dp - delta[:, None])
         return dq + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
@@ -130,16 +140,16 @@ def _dkv_kernel(
     *, bq, bk, causal, scale,
 ):
     ki = pl.program_id(2)
-    k = k_ref[0, 0].astype(jnp.float32)  # [bk, dh]
-    v = v_ref[0, 0].astype(jnp.float32)
+    k = k_ref[0, 0]  # [bk, dh]
+    v = v_ref[0, 0]
     t = q_ref.shape[2]
     dh = k.shape[-1]
     nq = t // bq
 
     def body(i, carry):
         dk, dv = carry
-        q = q_ref[0, 0, pl.ds(i * bq, bq), :].astype(jnp.float32)
-        do = do_ref[0, 0, pl.ds(i * bq, bq), :].astype(jnp.float32)
+        q = q_ref[0, 0, pl.ds(i * bq, bq), :]
+        do = do_ref[0, 0, pl.ds(i * bq, bq), :]
         lse = lse_ref[0, 0, pl.ds(i * bq, bq), 0]
         delta = delta_ref[0, 0, pl.ds(i * bq, bq), 0]
         s = scale * jax.lax.dot_general(
@@ -152,7 +162,7 @@ def _dkv_kernel(
             s = jnp.where(kpos <= qpos, s, _BIG_NEG)
         p = jnp.exp(s - lse[:, None])  # [bq, bk]
         dv = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [bk, dh]
         dp = jax.lax.dot_general(
@@ -161,7 +171,7 @@ def _dkv_kernel(
         )  # [bq, bk]
         ds = p * (dp - delta[:, None])
         dk = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [bk, dh]
         return dk, dv
@@ -180,6 +190,34 @@ def _dkv_kernel(
 def _check_blocks(t, bq, bk):
     if t % bq or t % bk:
         raise ValueError(f"seq len {t} must divide block sizes ({bq},{bk})")
+
+
+def _vmem_params(what, t, dh, bq, bk, dtype, *, whole, tiles, stat_rows):
+    """Compiler parameters for a kernel that holds ``whole`` [T, dh]
+    blocks, ``tiles`` [max(bq, bk), dh] blocks and per-row statistics of
+    ``stat_rows`` rows in VMEM, each double-buffered by the pipeline (a
+    statistics block's 8 lanes pad to a 128-lane f32 tile). Raises where
+    the blocks cannot fit a TensorCore's VMEM: the sequence has to be
+    streamed then, which this kernel does not do."""
+    item = jnp.dtype(dtype).itemsize
+    need = 2 * (
+        whole * t * dh * item
+        + tiles * max(bq, bk) * dh * item
+        + stat_rows * 128 * 4
+    ) + 8 * bq * bk * 4  # the [bq, bk] f32 score-sized temporaries
+    if need > _VMEM_CEILING:
+        raise ValueError(
+            f"flash_attention {what}: T={t}, dh={dh}, {jnp.dtype(dtype).name} "
+            f"needs about {need / 2**20:.0f} MiB of VMEM for its whole-"
+            f"sequence blocks, over the {_VMEM_CEILING / 2**20:.0f} MiB a "
+            "kernel may use: shorten the sequence per call (ring_attention "
+            "splits it over chips)"
+        )
+    if need <= _VMEM_DEFAULT // 2:
+        return None
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(_VMEM_CEILING, max(_VMEM_DEFAULT, 2 * need))
+    )
 
 
 def _flash_forward(q, k, v, *, causal, bq, bk, interpret):
@@ -212,6 +250,9 @@ def _flash_forward(q, k, v, *, causal, bq, bk, interpret):
             jax.ShapeDtypeStruct(qt.shape, q.dtype),
             jax.ShapeDtypeStruct((b, h, t, _STAT_LANES), jnp.float32),
         ],
+        compiler_params=_vmem_params(
+            "forward", t, dh, bq, bk, q.dtype, whole=2, tiles=2, stat_rows=bq
+        ),
         interpret=interpret,
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse
@@ -252,6 +293,9 @@ def _flash_backward(q, k, v, out, lse, do, *, causal, bq, bk, interpret):
         in_specs=[tile_q, full_seq, full_seq, tile_q, row_q, row_q],
         out_specs=tile_q,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        compiler_params=_vmem_params(
+            "dq", t, dh, bq, bk, q.dtype, whole=2, tiles=3, stat_rows=2 * bq
+        ),
         interpret=interpret,
     )(qt, kt, vt, dot_, lse, delta)
 
@@ -266,6 +310,9 @@ def _flash_backward(q, k, v, out, lse, do, *, causal, bq, bk, interpret):
             jax.ShapeDtypeStruct(kt.shape, k.dtype),
             jax.ShapeDtypeStruct(vt.shape, v.dtype),
         ],
+        compiler_params=_vmem_params(
+            "dk/dv", t, dh, bq, bk, q.dtype, whole=2, tiles=4, stat_rows=2 * t
+        ),
         interpret=interpret,
     )(qt, kt, vt, dot_, lse, delta)
     return (

@@ -71,6 +71,10 @@ def _compile(fn, *args):
 
 WINDOW = (18 * 64, 6, 64, 10)  # SwinIR-S, batch 18 of 64x64: [B*nW, h, n, d]
 FLASH = (8, 1024, 12, 64)  # GPT-2 125M: [B, T, H, Dh]
+# GLM-4.7-Flash's cell: MLA's head of 192 + 64, blocks of 512; K and V ride
+# whole in VMEM (2 MiB each), over the default scoped limit in the backward
+FLASH_MLA, FLASH_MLA_BLOCK = (2, 4096, 20, 256), 512
+GROUPED = (2 * 4096 * 4, 8, 2048, 1536)  # buffer rows, experts held, D, F
 GPT2_HEADS, GPT2_HEAD_DIM, PAGE = 12, 64, 16
 
 
@@ -95,13 +99,13 @@ def _window(dev, *, mask, grad):
     )
 
 
-def _flash(dev, *, dtype, grad):
+def _flash(dev, *, dtype, grad, shape=FLASH, block=128):
     from pytorch_distributedtraining_tpu.ops.pallas_attn import flash_attention
 
-    qkv = _on(dev, FLASH, dtype)
+    qkv = _on(dev, shape, dtype)
 
     def fwd(q, k, v):
-        return flash_attention(q, k, v, True, 128, 128, False)
+        return flash_attention(q, k, v, True, block, block, False)
 
     if not grad:
         return fwd, (qkv, qkv, qkv)
@@ -110,6 +114,28 @@ def _flash(dev, *, dtype, grad):
             lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)), argnums=(0, 1, 2)
         ),
         (qkv, qkv, qkv),
+    )
+
+
+def _grouped(dev, *, grad):
+    """The expert layer's gate matmul over the worst-case buffer."""
+    from pytorch_distributedtraining_tpu.ops.grouped_matmul import (
+        grouped_matmul,
+    )
+
+    rows, groups, d, f = GROUPED
+    args = (
+        _on(dev, (rows, d), jnp.bfloat16), _on(dev, (groups, d, f), jnp.bfloat16),
+        _on(dev, (groups,), jnp.int32),
+    )
+    if not grad:
+        return grouped_matmul, args
+    return (
+        jax.grad(
+            lambda x, w, n: jnp.sum(grouped_matmul(x, w, n).astype(jnp.float32)),
+            argnums=(0, 1),
+        ),
+        args,
     )
 
 
@@ -194,6 +220,16 @@ KERNEL_CASES = {
         lambda d: _flash(d, dtype=jnp.bfloat16, grad=True), True,
     ),
     "flash_bwd_f32": (lambda d: _flash(d, dtype=jnp.float32, grad=True), True),
+    "flash_fwd_bf16_mla_head_256": (
+        lambda d: _flash(d, dtype=jnp.bfloat16, grad=False, shape=FLASH_MLA,
+                         block=FLASH_MLA_BLOCK), True,
+    ),
+    "flash_bwd_bf16_mla_head_256": (
+        lambda d: _flash(d, dtype=jnp.bfloat16, grad=True, shape=FLASH_MLA,
+                         block=FLASH_MLA_BLOCK), True,
+    ),
+    "grouped_matmul_fwd": (lambda d: _grouped(d, grad=False), True),
+    "grouped_matmul_bwd": (lambda d: _grouped(d, grad=True), True),
     "paged_decode_attention_int8": (_paged_decode, False),
     "kv_quantize_dequantize": (_kv_quant_pair, False),
     "fp8_dot_fwd_bwd": (_fp8_dot, False),
