@@ -279,7 +279,7 @@ def _emit_fallback(reason: str, outage: dict | None = None) -> None:
 
 
 _ARM_ENVS = (  # envs that change WHICH arm is being measured
-    "GRAFT_BENCH_OPT", "GRAFT_BENCH_ATTN", "GRAFT_BENCH_ATTN_PACK",
+    "GRAFT_BENCH_OPT", "GRAFT_BENCH_ATTN",
     "GRAFT_BENCH_NORM", "GRAFT_BENCH_SOFTMAX", "GRAFT_BENCH_LOOP",
     "GRAFT_BENCH_SCAN_K", "GRAFT_BENCH_FEED", "GRAFT_BENCH_PREFETCH",
     "GRAFT_REMAT", "GRAFT_SCAN_LAYERS", "GRAFT_WIRE", "GRAFT_FP8",
@@ -1275,16 +1275,15 @@ def _bench() -> None:
             # every retry attempt on the same unreadable file
             raise SystemExit(f"bench_knobs.json unreadable: {e}")
         unknown = set(knobs) - {
-            "attn", "attn_pack", "norm", "softmax", "opt", "loop", "scan_k",
-            "feed", "remat", "scan_layers", "pp", "pp_schedule", "pp_micro",
-            "wire",
+            "attn", "norm", "softmax", "opt", "loop", "scan_k", "feed",
+            "remat", "scan_layers", "pp", "pp_schedule", "pp_micro", "wire",
         }
         if unknown:
             # a typoed key would otherwise silently no-op the default flip
             raise SystemExit(
                 f"bench_knobs.json unknown keys {sorted(unknown)}; valid: "
-                "attn, attn_pack, norm, softmax, opt, loop, scan_k, feed, "
-                "remat, scan_layers, pp, pp_schedule, pp_micro, wire"
+                "attn, norm, softmax, opt, loop, scan_k, feed, remat, "
+                "scan_layers, pp, pp_schedule, pp_micro, wire"
             )
 
     resolved = {}  # effective value + where it came from, for the log line
@@ -1300,14 +1299,6 @@ def _bench() -> None:
         resolved[file_key] = (default, "default")
         return default
 
-    pack_raw = knob("GRAFT_BENCH_ATTN_PACK", "attn_pack", "1")
-    try:
-        attn_pack = int(pack_raw)
-    except ValueError:
-        raise SystemExit(
-            f"attn_pack must be an int, got {pack_raw!r} "
-            f"(from {resolved['attn_pack'][1]})"
-        )
     # remat policy + scan-over-layers (ISSUE 3). remat applies per Swin
     # layer/pair inside the model (the fine-grained form — Policy.remat
     # would blanket the whole loss fn); scan compiles one W-MSA/SW-MSA
@@ -1324,8 +1315,7 @@ def _bench() -> None:
     scan_layers = scan_layers_raw.strip().lower() in ("1", "true", "on", "yes")
     model = SwinIR(
         dtype=jnp.bfloat16,  # reference config, bf16 MXU path
-        attn_impl=knob("GRAFT_BENCH_ATTN", "attn", "xla"),
-        attn_pack=attn_pack,
+        attn_impl=knob("GRAFT_BENCH_ATTN", "attn", "auto"),
         norm_dtype=(
             jnp.bfloat16
             if knob("GRAFT_BENCH_NORM", "norm", "f32") == "bf16"
@@ -1405,7 +1395,7 @@ def _bench() -> None:
         resolved["opt"] = ("chain", "wire-override")
 
     # timing-loop knobs parse HERE, before any compile time is spent —
-    # same never-benchmark-a-mislabeled-arm convention as attn_pack/opt
+    # same never-benchmark-a-mislabeled-arm convention as opt
     def int_env(name: str, default: str) -> int:
         raw = os.environ.get(name, default)
         try:

@@ -533,11 +533,9 @@ def main():
 
     with_attention(PairedWindowAttn, "paired_windows")
 
-    # fused Pallas window attention: probs never round-trip HBM
-    # (ops/pallas_window_attn.py)
-    ablate({"attn_impl": "pallas"}, "pallas_window_attn")
-    # + window pairing inside the kernel path: full 128-row MXU tiles
-    ablate({"attn_impl": "pallas", "attn_pack": 2}, "pallas_packed")
+    # the per-head einsums the default path's fused kernel
+    # (ops/pallas_window_attn.py) replaces on a TPU
+    ablate({"attn_impl": "xla"}, "einsum_window_attn")
 
     # bf16 softmax accumulation (no f32 round-trip on the [bn,h,n,n] probs)
     ablate({"softmax_dtype": jnp.bfloat16}, "bf16_softmax")

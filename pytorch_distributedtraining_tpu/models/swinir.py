@@ -14,8 +14,10 @@ pixel-shuffle upsampler.
 
 TPU-first layout decisions:
 - NHWC end-to-end; window partition is reshape/transpose (free for XLA);
-- attention is one batched ``[B·nW, heads, 64, 64]`` matmul pair — 64-token
-  windows tile the MXU;
+- attention between the projections is one fused kernel on a TPU
+  (``ops/pallas_window_attn.py``: ``qkv`` in the projection's own layout,
+  heads split by lane masks, scores in VMEM only) and batched per-head
+  einsums everywhere else;
 - the shifted-window mask is precomputed host-side per static (H, W) and
   closed over as a constant (no dynamic shapes under jit);
 - all matmuls run in the module ``dtype`` (bf16 under the bf16 policy),
@@ -24,6 +26,7 @@ TPU-first layout decisions:
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Sequence
 
@@ -32,9 +35,12 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import PartitionSpec as P
 
 from .sr_espcn import pixel_shuffle
 from .scan_utils import remat_block, stack_trees, unstack_tree
+from ..parallel.spec import published_batch_mesh
+from ..runtime.mesh import data_axes
 
 
 def window_partition(x: jnp.ndarray, ws: int) -> jnp.ndarray:
@@ -140,19 +146,83 @@ SWINIR_EXPORT_KEY_MAP = [
 ]
 
 
+def _einsum_core(qkv, bias, mask, dtype, softmax_dtype):
+    """Window attention as per-head einsums over ``[bn, heads, n, d]``
+    arrays: ``qkv [bn, n, 3c]`` -> ``[bn, n, c]``. The reference every
+    other implementation is held to, and what runs wherever the fused
+    kernel does not."""
+    bn, n, c3 = qkv.shape
+    h, c = bias.shape[0], c3 // 3
+    head_dim = c // h
+    qkv = qkv.reshape(bn, n, 3, h, head_dim).transpose(2, 0, 3, 1, 4)
+    q, k, v = qkv[0], qkv[1], qkv[2]  # [bn, h, n, d]
+    attn = (q * head_dim**-0.5) @ k.transpose(0, 1, 3, 2)  # [bn, h, n, n]
+    attn = attn + bias[None].astype(attn.dtype)
+
+    if mask is not None:  # [nW, n, n] additive
+        nw = mask.shape[0]
+        attn = attn.reshape(bn // nw, nw, h, n, n) + mask[None, :, None].astype(
+            attn.dtype
+        )
+        attn = attn.reshape(bn, h, n, n)
+
+    attn = jax.nn.softmax(attn.astype(softmax_dtype), axis=-1).astype(dtype)
+    return (attn @ v).transpose(0, 2, 1, 3).reshape(bn, n, c)
+
+
+@partial(jax.jit, static_argnames=("dtype", "softmax_dtype", "mesh"))
+def _kernel_or_einsum_core(qkv, bias, mask, dtype, softmax_dtype, mesh=None):
+    """The fused kernel where the program is lowered for a TPU, the einsums
+    on any other platform: one traced program serves both, and only the
+    branch of the platform it is lowered for is compiled. Jitted so that a
+    model's layers, which call it with the same shapes, share one trace of
+    both branches (24 traces of each cost the cells more set-up than their
+    bound allows).
+
+    The partitioner cannot split a Mosaic kernel and refuses a program that
+    spans devices with one in it. Given the step's ``mesh``, each device
+    runs the core over its own windows (``shard_map`` over the data axes,
+    bias and mask whole on every device, ``d bias`` summed across them)."""
+    from ..ops.pallas_window_attn import window_attention_qkv
+
+    def core(qkv, bias, mask):
+        return jax.lax.platform_dependent(
+            qkv, bias, mask, tpu=window_attention_qkv,
+            default=partial(
+                _einsum_core, dtype=dtype, softmax_dtype=softmax_dtype
+            ),
+        )
+
+    if mesh is None:
+        return core(qkv, bias, mask)
+    windows = P(data_axes(mesh))
+    return jax.shard_map(
+        core, mesh=mesh, in_specs=(windows, P(), P()), out_specs=windows,
+        check_vma=False,  # a pallas_call says nothing of varying axes
+    )(qkv, bias, mask)
+
+
 class WindowAttention(nn.Module):
     dim: int
     num_heads: int
     window_size: int
     dtype: jnp.dtype = jnp.float32
     softmax_dtype: jnp.dtype = jnp.float32  # attention prob accumulation
-    # How the [bn, h, n, n] attention is computed — same parameters, same
-    # math for every choice (checkpoints are interchangeable):
-    #   'xla'       per-head einsums (baseline)
-    #   'pallas'    fused VMEM-resident kernel (ops/pallas_window_attn.py):
-    #               probabilities never round-trip HBM. Compiles for the
-    #               TPU or raises; 'pallas_interpret' runs the same kernel
-    #               interpreted (CPU tests)
+    # How the attention between the projections is computed — same
+    # parameters, same math for every choice (checkpoints are
+    # interchangeable):
+    #   'auto'      the fused kernel (ops/pallas_window_attn.py: qkv in the
+    #               projection's own layout, scores in VMEM only) where the
+    #               program is lowered for a TPU and the shapes meet the
+    #               kernel's contract; the einsums everywhere else. On a
+    #               mesh the kernel runs under shard_map over the mesh the
+    #               step publishes (spec.batch_layout), each device over
+    #               its own windows; with several devices visible and none
+    #               published, the einsums
+    #   'xla'       per-head einsums, the named reference
+    #   'pallas'    the kernel or an error: compiles for the TPU or raises;
+    #               'pallas_interpret' runs the same kernel interpreted
+    #               (CPU tests)
     #   'paired'    two windows packed into one [2n, 2n] attention with a
     #               cross-window kill mask: score/AV matmuls fill full
     #               128-row MXU tiles at ws=8 instead of two half-empty
@@ -160,19 +230,17 @@ class WindowAttention(nn.Module):
     #   'blockdiag' QK^T/AV as block-diagonal-packed gemms: contraction 60
     #               instead of head_dim 10 (6x MXU K-utilization) at the
     #               cost of materializing packed operands
-    attn_impl: str = "xla"
-    # pallas impl only: fuse this many windows per attention tile (2 packs
-    # SwinIR's 64-token windows into full 128-row MXU tiles)
-    attn_pack: int = 1
+    attn_impl: str = "auto"
 
     @nn.compact
     def __call__(self, x, mask=None):
         if self.attn_impl not in (
-            "xla", "pallas", "pallas_interpret", "paired", "blockdiag"
+            "auto", "xla", "pallas", "pallas_interpret", "paired", "blockdiag"
         ):
             raise ValueError(
-                "attn_impl must be one of 'xla'/'pallas'/'pallas_interpret'/"
-                f"'paired'/'blockdiag', got {self.attn_impl!r}"
+                "attn_impl must be one of 'auto'/'xla'/'pallas'/"
+                "'pallas_interpret'/'paired'/'blockdiag', got "
+                f"{self.attn_impl!r}"
             )
         c = x.shape[-1]  # x: [B*nW, ws^2, C]
         qkv = nn.Dense(3 * c, use_bias=True, dtype=self.dtype, name="qkv")(x)
@@ -193,67 +261,112 @@ class WindowAttention(nn.Module):
         bn, n, _ = qkv.shape
         h = self.num_heads
         c = qkv.shape[-1] // 3
-        head_dim = c // h
-        qkv = qkv.reshape(bn, n, 3, h, head_dim).transpose(2, 0, 3, 1, 4)
-        q, k, v = qkv[0], qkv[1], qkv[2]  # [bn, h, n, d]
         idx = _relative_position_index(self.window_size)
         bias = table[idx.reshape(-1)].reshape(n, n, h).transpose(2, 0, 1)
+        if mask is not None:
+            mask = jnp.asarray(mask)
 
-        if self.attn_impl == "paired":
-            p = 2
-            if bn % p == 0 and (mask is None or mask.shape[0] % p == 0):
-                return self._paired(qkv, bias, mask, p)
-            # odd window counts are legal SwinIR inputs — fall back rather
-            # than failing mid-forward (mirrors the pallas pack fallback)
-        if self.attn_impl == "blockdiag":
-            return self._blockdiag(q, k, v, bias, mask)
+        if self.attn_impl in ("paired", "blockdiag"):
+            heads = qkv.reshape(bn, n, 3, h, c // h).transpose(2, 0, 3, 1, 4)
+            if self.attn_impl == "blockdiag":
+                return self._blockdiag(heads[0], heads[1], heads[2], bias, mask)
+            if bn % 2 == 0 and (mask is None or mask.shape[0] % 2 == 0):
+                return self._paired(heads, bias, mask, 2)
+            # odd window counts are legal SwinIR inputs — fall through to
+            # the einsums rather than failing mid-forward
 
-        if self.attn_impl in ("pallas", "pallas_interpret"):
-            if self.softmax_dtype != jnp.float32:
-                # the kernel always accumulates softmax in f32; refusing a
-                # bf16 request keeps ablation arms honestly labeled
-                raise ValueError(
-                    "attn_impl='pallas' computes softmax in f32 in-kernel; "
-                    f"softmax_dtype={self.softmax_dtype} is not honored — "
-                    "use the 'xla' impl for bf16-softmax experiments"
-                )
-            from ..ops import pallas_window_attn as pwa
-
-            # pack only when the window counts divide (odd per-image window
-            # counts are legal SwinIR inputs — fall back to pack=1 there
-            # rather than failing mid-forward)
-            pk = max(1, self.attn_pack)
-            if bn % pk or (mask is not None and mask.shape[0] % pk):
-                pk = 1
-            out = pwa.window_attention_packed(
-                q, k, v,
-                bias.astype(jnp.float32),
-                None if mask is None else jnp.asarray(mask),
-                pk,
-                max(1, 16 // pk),
-                self.attn_impl == "pallas_interpret",
-            )  # [bn, h, n, d], softmax in f32 in-kernel
-            out = out.transpose(0, 2, 1, 3).reshape(bn, n, c)
-            return checkpoint_name(out, "attn_out")
-
-        scale = head_dim**-0.5
-        attn = (q * scale) @ k.transpose(0, 1, 3, 2)  # [bn, h, n, n]
-        attn = attn + bias[None].astype(attn.dtype)
-
-        if mask is not None:  # [nW, n, n] additive
-            nw = mask.shape[0]
-            attn = attn.reshape(bn // nw, nw, h, n, n) + mask[None, :, None].astype(
-                attn.dtype
+        if self.attn_impl in ("auto", "pallas", "pallas_interpret"):
+            out = self._fused(qkv, bias, mask)
+        else:
+            out = _einsum_core(
+                qkv, bias, mask, self.dtype, self.softmax_dtype
             )
-            attn = attn.reshape(bn, h, n, n)
-
-        attn = jax.nn.softmax(
-            attn.astype(self.softmax_dtype), axis=-1
-        ).astype(self.dtype)
-        out = (attn @ v).transpose(0, 2, 1, 3).reshape(bn, n, c)
         # named-remat tag (parallel/remat.py "names"/"offload"): save the
         # softmax·V product, recompute the cheap projections
         return checkpoint_name(out, "attn_out")
+
+    def _fused(self, qkv, bias, mask):
+        """The kernel where it applies. 'auto' decides from what it can
+        see: the shapes now, the platform when the program is lowered
+        (``_kernel_or_einsum_core``). 'pallas' / 'pallas_interpret' take
+        the kernel or raise."""
+        from ..observe import trace
+        from ..ops import pallas_window_attn as pwa
+
+        bn, n, c3 = qkv.shape
+        why = None
+        if self.softmax_dtype != jnp.float32:
+            why = (
+                "the kernel's softmax is float32; softmax_dtype="
+                f"{jnp.dtype(self.softmax_dtype).name} asks for less"
+            )
+        why = why or pwa.kernel_contract(
+            bn, n, c3 // 3, self.num_heads,
+            None if mask is None else mask.shape[0], qkv.dtype,
+        )
+        bias = bias.astype(jnp.float32)
+        if self.attn_impl != "auto":
+            if why is not None:
+                # refusing keeps ablation arms honestly labeled
+                raise ValueError(f"attn_impl={self.attn_impl!r}: {why}")
+            path, why = "kernel", f"attn_impl={self.attn_impl!r}"
+            out = pwa.window_attention_qkv(
+                qkv, bias, mask, self.attn_impl == "pallas_interpret"
+            )
+        else:
+            mesh = None
+            if why is None:
+                mesh, why = self._kernel_mesh(qkv, mask)
+            if why is not None:
+                path = "einsum"
+                out = _einsum_core(
+                    qkv, bias, mask, self.dtype, self.softmax_dtype
+                )
+            else:
+                # decided when the program is lowered: the kernel for a
+                # TPU, the einsums for any other platform
+                path = "by_platform"
+                why = "shapes meet the kernel's contract" + (
+                    "" if mesh is None else
+                    f"; each of the mesh's {mesh.size} devices its own windows"
+                )
+                out = _kernel_or_einsum_core(
+                    qkv, bias, mask, self.dtype, self.softmax_dtype, mesh
+                )
+        trace.instant(
+            "window_attention.path", path=path, reason=why,
+            bn=bn, n=n, c=c3 // 3, heads=self.num_heads,
+        )
+        return out
+
+    def _kernel_mesh(self, qkv, mask):
+        """Where 'auto' may place the kernel: ``(mesh, None)``, with the
+        mesh to split the windows over or None for a program on one
+        device, or ``(None, why not)``. A program that spans devices takes
+        the kernel only where the step has published its mesh
+        (``spec.batch_layout``); one device visible cannot be spanned."""
+        from ..ops.pallas_window_attn import kernel_contract
+
+        mesh = published_batch_mesh()
+        if mesh is None:
+            if jax.device_count() == 1:
+                return None, None
+            return None, (
+                f"{jax.device_count()} devices and no step has published "
+                "the batch's layout: the program may span them, and the "
+                "partitioner cannot split a kernel"
+            )
+        if mesh.size == 1:
+            return None, None
+        bn, n, c3 = qkv.shape
+        shards = math.prod(mesh.shape[a] for a in data_axes(mesh))
+        if bn % shards:
+            return None, f"{bn} windows do not split over {shards} devices"
+        why = kernel_contract(
+            bn // shards, n, c3 // 3, self.num_heads,
+            None if mask is None else mask.shape[0], qkv.dtype,
+        )
+        return (mesh, None) if why is None else (None, f"a device's share: {why}")
 
     def _paired(self, qkv, bias, mask, p: int):
         """Two windows per attention: [p*n, p*n] scores with an additive
@@ -351,8 +464,7 @@ class SwinLayer(nn.Module):
     dtype: jnp.dtype = jnp.float32
     norm_dtype: jnp.dtype = jnp.float32  # LN compute/storage dtype
     softmax_dtype: jnp.dtype = jnp.float32
-    attn_impl: str = "xla"
-    attn_pack: int = 1
+    attn_impl: str = "auto"
 
     @nn.compact
     def __call__(self, x):  # [B, H, W, C]
@@ -369,7 +481,6 @@ class SwinLayer(nn.Module):
         wins = WindowAttention(
             self.dim, self.num_heads, ws, dtype=self.dtype,
             softmax_dtype=self.softmax_dtype, attn_impl=self.attn_impl,
-            attn_pack=self.attn_pack,
             name="attn",
         )(wins, mask)
         y = window_reverse(wins, ws, hgt, wid)
@@ -402,15 +513,14 @@ class SwinLayerPair(nn.Module):
     dtype: jnp.dtype = jnp.float32
     norm_dtype: jnp.dtype = jnp.float32
     softmax_dtype: jnp.dtype = jnp.float32
-    attn_impl: str = "xla"
-    attn_pack: int = 1
+    attn_impl: str = "auto"
 
     @nn.compact
     def __call__(self, x):
         kw = dict(
             mlp_ratio=self.mlp_ratio, dtype=self.dtype,
             norm_dtype=self.norm_dtype, softmax_dtype=self.softmax_dtype,
-            attn_impl=self.attn_impl, attn_pack=self.attn_pack,
+            attn_impl=self.attn_impl,
         )
         x = SwinLayer(
             self.dim, self.num_heads, self.window_size, shift=0,
@@ -434,8 +544,7 @@ class RSTB(nn.Module):
     dtype: jnp.dtype = jnp.float32
     norm_dtype: jnp.dtype = jnp.float32
     softmax_dtype: jnp.dtype = jnp.float32
-    attn_impl: str = "xla"
-    attn_pack: int = 1
+    attn_impl: str = "auto"
     # Activation remat per layer/pair: bool (True == "full") or a named
     # policy from parallel/remat.py
     remat: bool | str = False
@@ -449,7 +558,7 @@ class RSTB(nn.Module):
         kw = dict(
             mlp_ratio=self.mlp_ratio, dtype=self.dtype,
             norm_dtype=self.norm_dtype, softmax_dtype=self.softmax_dtype,
-            attn_impl=self.attn_impl, attn_pack=self.attn_pack,
+            attn_impl=self.attn_impl,
         )
         if self.scan_layers and self.depth >= 2 and self.depth % 2 == 0:
             # one traced/compiled pair for all depth//2 iterations; remat
@@ -503,10 +612,9 @@ class SwinIR(nn.Module):
     # see benchmarks/profile_swinir.py) at ~1e-2 output tolerance.
     norm_dtype: jnp.dtype = jnp.float32
     softmax_dtype: jnp.dtype = jnp.float32  # attention softmax accumulation
-    # 'xla' | 'pallas' | 'pallas_interpret' | 'paired' | 'blockdiag' — see
-    # WindowAttention.attn_impl for what each computes
-    attn_impl: str = "xla"
-    attn_pack: int = 1  # pallas impl: windows fused per attention tile
+    # 'auto' | 'xla' | 'pallas' | 'pallas_interpret' | 'paired' | 'blockdiag'
+    # — see WindowAttention.attn_impl for what each computes
+    attn_impl: str = "auto"
     # Activation remat per Swin layer/pair: bool (True == "full") or a
     # named policy from parallel/remat.py ("dots"/"names"/"offload")
     remat: bool | str = False
@@ -552,8 +660,7 @@ class SwinIR(nn.Module):
                 self.embed_dim, depth, heads, ws, self.mlp_ratio,
                 dtype=self.dtype, norm_dtype=self.norm_dtype,
                 softmax_dtype=self.softmax_dtype, attn_impl=self.attn_impl,
-                attn_pack=self.attn_pack, remat=self.remat,
-                scan_layers=self.scan_layers,
+                remat=self.remat, scan_layers=self.scan_layers,
                 name=f"rstb_{i}",
             )(y)
         y = nn.LayerNorm(dtype=self.norm_dtype, name="norm")(y).astype(self.dtype)

@@ -143,14 +143,22 @@ def batch_layout(mesh: Mesh):
     """Publish, while a step is traced, that its batch is split over
     ``mesh``'s data axes: what :func:`pin_batch` holds activations to.
 
-    The step that owns the mesh says it (``TrainStep`` around its loss
-    function); a model never asks jax for an ambient mesh.
+    Whoever owns the mesh says it (``TrainStep`` around its loss function,
+    the stoke facade around every application of its model); a model never
+    asks jax for an ambient mesh.
     """
     token = _BATCH_LAYOUT.set(mesh)
     try:
         yield
     finally:
         _BATCH_LAYOUT.reset(token)
+
+
+def published_batch_mesh() -> Mesh | None:
+    """The mesh of the step being traced, or None where no step has said:
+    for code that has to place a computation itself (a kernel the
+    partitioner cannot split, wrapped in ``shard_map`` over the data axes)."""
+    return _BATCH_LAYOUT.get()
 
 
 def pin_batch(x):

@@ -49,7 +49,12 @@ from ..parallel import (
     wire_format,
 )
 from ..parallel.remat import apply_remat, resolve_remat
-from ..parallel.spec import constrain, shard_axis, stream_to_device
+from ..parallel.spec import (
+    batch_layout,
+    constrain,
+    shard_axis,
+    stream_to_device,
+)
 from ..precision import DynamicLossScaler, Policy as PrecisionPolicy
 from ..runtime import dist as _dist
 from ..runtime.mesh import (
@@ -1050,13 +1055,16 @@ class Stoke:
             kwargs["train"] = train
         mutable = [k for k in model_state] if (train and model_state) else False
         rngs = {"dropout": rng} if rng is not None else None
-        if mutable:
-            out, new_state = self._module.apply(
-                variables, x, rngs=rngs, mutable=mutable, **kwargs
-            )
-            return out, dict(new_state)
-        out = self._module.apply(variables, x, rngs=rngs, **kwargs)
-        return out, model_state
+        # the facade owns the mesh and places every batch on its data axes
+        # (_shard_batch), so it says so while the model is traced
+        with batch_layout(self.mesh):
+            if mutable:
+                out, new_state = self._module.apply(
+                    variables, x, rngs=rngs, mutable=mutable, **kwargs
+                )
+                return out, dict(new_state)
+            out = self._module.apply(variables, x, rngs=rngs, **kwargs)
+            return out, model_state
 
     def _build_jits(self):
         precision = self.precision

@@ -69,7 +69,10 @@ def _compile(fn, *args):
 
 # -- kernels at real widths (tier-1) -----------------------------------------
 
-WINDOW = (18 * 64, 6, 64, 10)  # SwinIR-S, batch 18 of 64x64: [B*nW, h, n, d]
+# SwinIR-S, batch 18 of 64x64: qkv [B*nW, n, 3c] as the projection writes it,
+# 6 heads of 10; classical SwinIR-M: embed 180, 6 heads of 30
+WINDOW = (18 * 64, 64, 180, 6)
+WINDOW_M = (18 * 64, 64, 540, 6)
 FLASH = (8, 1024, 12, 64)  # GPT-2 125M: [B, T, H, Dh]
 # GLM-4.7-Flash's cell: MLA's head of 192 + 64, blocks of 512; K and V ride
 # whole in VMEM (2 MiB each), over the default scoped limit in the backward
@@ -78,24 +81,26 @@ GROUPED = (2 * 4096 * 4, 8, 2048, 1536)  # buffer rows, experts held, D, F
 GPT2_HEADS, GPT2_HEAD_DIM, PAGE = 12, 64, 16
 
 
-def _window(dev, *, mask, grad):
+def _window(dev, *, mask, grad, dtype=jnp.float32, shape=None):
     from pytorch_distributedtraining_tpu.ops.pallas_window_attn import (
-        window_attention,
+        window_attention_qkv,
     )
 
-    bn, h, n, d = WINDOW
-    qkv = _on(dev, WINDOW, jnp.float32)
-    bias = _on(dev, (h, n, n), jnp.float32)
+    bn, n, c3, heads = shape or WINDOW
+    qkv = _on(dev, (bn, n, c3), dtype)
+    bias = _on(dev, (heads, n, n), jnp.float32)
     m = _on(dev, (64, n, n), jnp.float32) if mask else None
 
-    def fwd(q, k, v, bias, m):
-        return window_attention(q, k, v, bias, m, 16, False)
+    def fwd(qkv, bias, m):
+        return window_attention_qkv(qkv, bias, m, False)
 
     if not grad:
-        return fwd, (qkv, qkv, qkv, bias, m)
+        return fwd, (qkv, bias, m)
     return (
-        jax.grad(lambda *a: jnp.sum(fwd(*a)), argnums=(0, 1, 2, 3)),
-        (qkv, qkv, qkv, bias, m),
+        jax.grad(
+            lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)), argnums=(0, 1)
+        ),
+        (qkv, bias, m),
     )
 
 
@@ -213,6 +218,13 @@ KERNEL_CASES = {
     "window_fwd": (lambda d: _window(d, mask=False, grad=False), True),
     "window_fwd_shift_mask": (lambda d: _window(d, mask=True, grad=False), True),
     "window_bwd": (lambda d: _window(d, mask=True, grad=True), True),
+    "window_bwd_no_mask": (lambda d: _window(d, mask=False, grad=True), True),
+    "window_bwd_bf16": (
+        lambda d: _window(d, mask=True, grad=True, dtype=jnp.bfloat16), True,
+    ),
+    "window_bwd_head_30": (
+        lambda d: _window(d, mask=True, grad=True, shape=WINDOW_M), True,
+    ),
     "flash_fwd_bf16": (
         lambda d: _flash(d, dtype=jnp.bfloat16, grad=False), True,
     ),
@@ -588,61 +600,94 @@ def test_serve_decode_and_spec_verify_int8_kv(topo, monkeypatch):
     ).compile()
 
 
-@pytest.mark.slow
-def test_swinir_s_train_step_batch18(topo):
-    """Full-width SwinIR-S (the constructor of drivers/stoke_ddp.py), batch
-    18 of 64x64, through TrainStep: float32 with XLA attention, and bf16
-    with the Pallas window kernel compiled into the model. The compiler's
-    memory plan decides how large a batch a cell can use; head_dim 10 and
-    channel 60 pad onto 128 lanes, so this small model is not small there
-    (float32 with the Pallas kernel is refused outright: 19.4 of 15.75 GB).
-    """
+def _swinir_s_step(mesh, batch, *, dtype=jnp.float32, precision="fp32", **kw):
+    """Full-width SwinIR-S (the constructor of drivers/stoke_ddp.py, which
+    names no attention) on 64x64 inputs through TrainStep on ``mesh``."""
     from pytorch_distributedtraining_tpu import optim
     from pytorch_distributedtraining_tpu.losses import mse_loss
     from pytorch_distributedtraining_tpu.models import SwinIR
     from pytorch_distributedtraining_tpu.parallel import DDP, TrainStep
     from pytorch_distributedtraining_tpu.precision import Policy as Precision
+    from pytorch_distributedtraining_tpu.runtime.mesh import batch_spec
 
-    def model(attn_impl, dtype):
-        return SwinIR(
-            upscale=2, in_chans=3, img_size=64, window_size=8, img_range=1.0,
-            depths=[6, 6, 6, 6], embed_dim=60, num_heads=[6, 6, 6, 6],
-            mlp_ratio=2, upsampler="pixelshuffledirect",
-            resi_connection="1conv", attn_impl=attn_impl, dtype=dtype,
-        )
-
-    mesh = _mesh(topo, 1, dp=1)
+    net = SwinIR(
+        upscale=2, in_chans=3, img_size=64, window_size=8, img_range=1.0,
+        depths=[6, 6, 6, 6], embed_dim=60, num_heads=[6, 6, 6, 6],
+        mlp_ratio=2, upsampler="pixelshuffledirect",
+        resi_connection="1conv", dtype=dtype, **kw,
+    )
     tx = optim.adamw(lr=1e-3, clip_grad_norm=0.1)
     shapes, shardings = _abstract_state(
-        lambda r: (
-            model("xla", jnp.float32).init(
-                r, jnp.zeros((1, 64, 64, 3))
-            )["params"], {},
-        ),
+        lambda r: (net.init(r, jnp.zeros((1, 64, 64, 3)))["params"], {}),
         tx, mesh, DDP(),
     )
-    data = NamedSharding(mesh, P())
-    batch = (
-        _on(data, (18, 64, 64, 3), jnp.float32),
-        _on(data, (18, 128, 128, 3), jnp.float32),
+    step = TrainStep(
+        lambda p, b, rng, ms: (
+            mse_loss(net.apply({"params": p}, b[0]), b[1]), {},
+        ),
+        tx, mesh, DDP(), state_shardings=shardings,
+        precision=Precision.from_name(precision),
     )
+    data = NamedSharding(mesh, batch_spec(mesh))
+    return step, _with_shardings(shapes, shardings), (
+        _on(data, (batch, 64, 64, 3), jnp.float32),
+        _on(data, (batch, 128, 128, 3), jnp.float32),
+    )
+
+
+def _window_kernels(text):
+    """First result's shape of each Mosaic call: the forward's [bn, n, c],
+    the backward's d qkv [bn, n, 3c]."""
+    return [
+        tuple(
+            int(d) for d in
+            re.search(r"f32\[(\d+,\d+,\d+)\]", line).group(1).split(",")
+        )
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+
+
+@pytest.mark.slow
+def test_swinir_s_train_step_batch18(topo):
+    """SwinIR-S, batch 18 of 64x64, float32. The program is lowered for the
+    described v5e, so the default path takes the fused window kernel: 24
+    forward + 24 backward kernels, and fewer planned temporaries than the
+    einsums, whose [1152, 6, 64, 64] scores and head-of-10 layouts are
+    15.18 of the chip's 15.75 GB for the two microbatches of
+    `chipbench/workloads/swinir-s-x2.fused-step.json`. The compiler's
+    memory plan decides how large a batch a cell can use. bf16 compiles
+    too, and plans less again.
+    """
+    mesh = _mesh(topo, 1, dp=1)
     plans = {}
-    for precision, impl, dtype in (
-        ("fp32", "xla", jnp.float32), ("bf16", "pallas", jnp.bfloat16),
+    for label, kw in (
+        ("fp32/einsum", dict(attn_impl="xla")),
+        ("fp32/default", {}),
+        ("bf16/default", dict(dtype=jnp.bfloat16, precision="bf16")),
     ):
-        net = model(impl, dtype)
-        step = TrainStep(
-            lambda p, b, rng, ms, net=net: (
-                mse_loss(net.apply({"params": p}, b[0]), b[1]), {},
-            ),
-            tx, mesh, DDP(), state_shardings=shardings,
-            precision=Precision.from_name(precision),
-        )
-        compiled, text = _lower(step, _with_shardings(shapes, shardings), batch)
-        assert ("tpu_custom_call" in text) == (impl == "pallas")
-        plans[f"{precision}/{impl}"] = (
-            compiled.memory_analysis().temp_size_in_bytes
-        )
+        compiled, text = _lower(*_swinir_s_step(mesh, 18, **kw))
+        kernels = text.count('custom_call_target="tpu_custom_call"')
+        assert kernels == (0 if label == "fp32/einsum" else 48), (label, kernels)
+        plans[label] = compiled.memory_analysis().temp_size_in_bytes
     print("SwinIR-S batch 18 of 64x64, planned temporaries (bytes):", plans)
-    # half of the chip's 15.75 GB or more: batch 18 is not "mostly empty"
-    assert all(8e9 < v < 15.75e9 for v in plans.values()), plans
+    assert plans["fp32/default"] < plans["fp32/einsum"] < 15.75e9, plans
+    assert plans["bf16/default"] < plans["fp32/default"], plans
+
+
+@pytest.mark.slow
+def test_swinir_s_default_on_four_chips_keeps_each_chips_windows(topo):
+    """The stoke driver's program on a 2x2: batch 72 over dp=4. The
+    partitioner cannot split a Mosaic kernel (it refuses the program), so
+    the default path places it itself, over the mesh the step publishes:
+    48 kernels, each over one chip's 18 x 64 windows, and no activation is
+    gathered (the only collective is the gradients' all-reduce, which
+    carries the kernels' d bias too)."""
+    from pytorch_distributedtraining_tpu.observe import hlo
+
+    _, text = _lower(*_swinir_s_step(_mesh(topo, dp=4), 72))
+    shapes = _window_kernels(text)
+    assert len(shapes) == 48, shapes
+    assert set(shapes) == {(1152, 64, 60), (1152, 64, 180)}, set(shapes)
+    assert not _gathered_shapes(text), _gathered_shapes(text)
+    assert set(hlo.counts(text)) <= {"all-reduce"}, hlo.counts(text)

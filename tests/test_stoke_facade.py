@@ -585,3 +585,47 @@ def test_pipeline_step_requires_initialized_state(monkeypatch):
         s.pipeline_step(
             lambda p, x: x, lambda o, y, mb, rng: jnp.mean(y**2)
         )
+
+
+def test_facade_publishes_its_mesh_while_the_model_is_traced():
+    """The facade owns the mesh and places every batch on its data axes, so
+    it says so (``spec.batch_layout``) wherever it applies the model. The
+    driver's SwinIR names no attention: on the eight devices it can then
+    place its window kernel itself, each device over its own windows
+    (``by_platform``: the einsums on this CPU), in the training programs
+    and in the forward alike. Without a published mesh it would have to
+    take the einsums (the partitioner cannot split a Mosaic kernel)."""
+    from pytorch_distributedtraining_tpu.models import SwinIR
+    from pytorch_distributedtraining_tpu.observe import trace
+
+    stoke_model = _stoke(model=SwinIR(
+        upscale=2, window_size=8, depths=[2], embed_dim=12, num_heads=[2],
+        mlp_ratio=2,
+    ))
+    rng = np.random.default_rng(0)
+    hr = rng.random((16, 32, 32, 3)).astype(np.float32)
+    lr = hr.reshape(16, 16, 2, 16, 2, 3).mean(axis=(2, 4))
+    tracer = trace.get_tracer()
+    was = tracer.enabled
+    trace.enable(crash_handler=False)
+    trace.clear()
+    try:
+        stoke_model.model_access.train()
+        for _ in range(2):  # one accumulation window
+            loss = stoke_model.loss(stoke_model.model(lr), hr)
+            stoke_model.backward(loss=loss)
+            stoke_model.step()
+        stoke_model.model_access.eval()
+        np.asarray(stoke_model.model(lr))
+        said = [r["attrs"] for r in trace.records()
+                if r["name"] == "window_attention.path"]
+    finally:
+        trace.clear()
+        tracer.enabled = was
+    assert stoke_model.mesh.size == 8
+    # the parameters' init sees one image and no mesh: the einsums
+    assert {s["path"] for s in said if s["bn"] == 4} == {"einsum"}
+    batches = [s for s in said if s["bn"] == 16 * 4]
+    assert len(batches) >= 4 and len(batches) + 2 == len(said), said
+    assert {s["path"] for s in batches} == {"by_platform"}, batches
+    assert all("8 devices its own windows" in s["reason"] for s in batches)

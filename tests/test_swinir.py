@@ -1,5 +1,7 @@
 """SwinIR-S: shapes, param budget, window ops, shift masks, training."""
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -188,3 +190,74 @@ def test_attn_impl_rejects_unknown():
                attn_impl="winograd").init(
             jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 3))
         )
+
+
+@pytest.mark.parametrize("layout", ["none", "one_device", "dp4"])
+def test_default_path_on_cpu_is_the_einsum_path(layout):
+    """The default SwinIR takes no argument to choose its attention. Where
+    the shapes meet the kernel's contract and the program cannot span
+    devices unseen (a step has published its mesh, ``spec.batch_layout``),
+    the traced program holds both the fused kernel (for a TPU lowering) and
+    the einsums, and a CPU lowers the einsums: loss and every gradient
+    equal ``attn_impl='xla'`` to the bit, on unshifted and shifted layers.
+    On a mesh of four the core runs under ``shard_map``, each device over
+    its own windows (the partitioner cannot split a Mosaic kernel); the
+    bias's gradient is then summed in another order. With eight devices
+    visible and no layout published the einsums are all there is. The path
+    is said at each trace."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pytorch_distributedtraining_tpu.observe import trace
+    from pytorch_distributedtraining_tpu.parallel.spec import batch_layout
+    from pytorch_distributedtraining_tpu.runtime.mesh import MeshSpec, make_mesh
+
+    kw = dict(upscale=2, window_size=8, depths=[2], embed_dim=12,
+              num_heads=[2], mlp_ratio=2)
+    x = jnp.asarray(
+        np.random.default_rng(4).random((8, 16, 16, 3)), jnp.float32
+    )
+    auto, ref = SwinIR(**kw), SwinIR(**kw, attn_impl="xla")
+    assert auto.attn_impl == "auto"
+    params = ref.init(jax.random.PRNGKey(1), x)["params"]
+    n_dev = {"none": 0, "one_device": 1, "dp4": 4}[layout]
+    mesh = n_dev and make_mesh(MeshSpec(dp=n_dev), devices=jax.devices()[:n_dev])
+
+    def loss(model):
+        def fn(p, x):
+            with batch_layout(mesh) if mesh else contextlib.nullcontext():
+                return jnp.mean(model.apply({"params": p}, x) ** 2)
+        return fn
+
+    tracer = trace.get_tracer()
+    was = tracer.enabled
+    trace.enable(crash_handler=False)
+    trace.clear()
+    try:
+        jaxpr = str(jax.make_jaxpr(loss(auto))(params, x))
+        said = [r["attrs"] for r in trace.records()
+                if r["name"] == "window_attention.path"]
+    finally:
+        trace.clear()
+        tracer.enabled = was
+    assert (said[0]["bn"], said[0]["n"], said[0]["c"]) == (32, 64, 12)
+    if layout == "none":
+        assert jax.device_count() > 1  # conftest's eight
+        assert [s["path"] for s in said] == ["einsum", "einsum"]  # two layers
+        assert all("no step has published" in s["reason"] for s in said)
+        assert "pallas_call" not in jaxpr
+    else:
+        assert [s["path"] for s in said] == ["by_platform", "by_platform"]
+        assert "platform_index" in jaxpr and "pallas_call" in jaxpr
+        assert ("shard_map" in jaxpr) == (layout == "dp4")
+
+    if layout == "dp4":
+        x = jax.device_put(x, NamedSharding(mesh, P("dp")))
+    got = jax.jit(jax.value_and_grad(loss(auto)))(params, x)
+    want = jax.jit(jax.value_and_grad(loss(ref)))(params, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        if layout == "dp4":
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7
+            )
+        else:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
