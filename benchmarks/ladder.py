@@ -8,9 +8,8 @@
 
 Each run prints one JSON line: {config, metric, value, unit, mesh, steps}.
 ``--tiny`` shrinks models/batches for CPU smoke runs (used by tests);
-real-chip numbers come from running without it on TPU. ``bench.py`` at the
-repo root stays the driver's single headline number; this file is the
-tracking ladder.
+real-chip numbers come from running without it on TPU. The repository's
+benchmark is ``chipbench/``; this file is the tracking ladder.
 
 Usage:
     python benchmarks/ladder.py --config 4 [--tiny] [--steps 20]
@@ -31,7 +30,7 @@ import _bootstrap  # noqa: F401  (repo root on sys.path)
 
 
 def _timed_steps(step, state, batch, n_steps, warmup):
-    """Best-of-N windows (default 3, bench.py's methodology)."""
+    """Best-of-N windows (default 3)."""
     import jax
 
     windows = max(1, int(os.environ.get("GRAFT_LADDER_WINDOWS", "3")))

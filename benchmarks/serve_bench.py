@@ -26,8 +26,7 @@ p99 **tail attribution** (which phase owns the tail, and how much of it
 is bucket/batch padding vs genuine compute — asserted non-empty), and
 the SLO tracker's burn rate. The lifecycle bookkeeping's own cost is
 measured in-process and published as ``telemetry_overhead_fraction``,
-gated at the same 1% publication bar as bench.py's span probe (exit 9
-over it). The continuous arm's lifecycles are exported as a
+gated at a 1% publication bar (exit 9 over it). The continuous arm's lifecycles are exported as a
 ``graft-serve`` Chrome-trace lane for ``trace_summary.py``.
 
 Two decode fast-path arms ride the same trace (docs/SERVING.md):
@@ -178,7 +177,7 @@ def _token_agreement(a: dict, b: dict) -> float:
 
 def _ledger_overhead_fraction(eng, wall_s: float) -> float:
     """Measured cost of the lifecycle bookkeeping, as a fraction of the
-    arm's wall time — the serving twin of bench.py's span probe. A
+    arm's wall time. A
     scratch ledger absorbs 2000 interval closes to price one op, then
     the arm's actual op count (intervals recorded + per-tick gauge
     stores) converts it to seconds."""

@@ -10,7 +10,7 @@ and which collectives grew between a good run and a bad one:
 
 Each argument is either a profiler trace directory (parsed with
 ``observe.opcost``) or a bench-record JSON file carrying an ``opcost``
-block. bench.py and regress.py call :func:`attribute_records` at
+block. regress.py calls :func:`attribute_records` at
 verdict time, so a ``regression`` verdict in a bench record carries an
 ``attribution`` block naming the dominant class instead of just a
 number that got worse.
@@ -24,9 +24,9 @@ import os
 
 import _bootstrap  # noqa: F401  (repo root on sys.path)
 
-# NOTE: observe.opcost is imported lazily (inside _load) so that
-# bench.py's jax-free parent can import this module for
-# attribute_records — record-vs-record diffs are pure dict math.
+# NOTE: observe.opcost is imported lazily (inside _load) so that a
+# jax-free parent can import this module for attribute_records —
+# record-vs-record diffs are pure dict math.
 
 
 def _norm(obj: dict) -> dict | None:

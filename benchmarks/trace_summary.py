@@ -3,9 +3,8 @@
 One tool for both trace producers in this repo — they share the
 trace-event format, so they share the summarizer:
 
-- jax.profiler xplane dumps (the directory passed as
-  ``GRAFT_BENCH_TRACE``; bench.py writes a 3-step steady-state trace
-  there): aggregates `X` duration events per lane, preferring device
+- jax.profiler xplane dumps (a directory a profiler capture wrote):
+  aggregates `X` duration events per lane, preferring device
   lanes (TPU pids) over host lanes, so the MFU question — *which ops own
   the step time?* — is answerable without TensorBoard.
 - observe/trace.py telemetry exports (``telemetry-<pid>.trace.json``,
@@ -51,8 +50,7 @@ def load_events(trace_dir: str):
 
     The parser itself was hoisted into the package
     (``observe.opcost.load_trace_events``) so in-process consumers — the
-    on-demand capture's post-fire ingest, bench.py's opcost block —
-    share it; this wrapper keeps the CLI's exit behavior."""
+    on-demand capture's post-fire ingest — share it; this wrapper keeps the CLI's exit behavior."""
     try:
         return _opcost.load_trace_events(trace_dir)
     except FileNotFoundError as e:

@@ -389,8 +389,7 @@ def main(argv=None):
                         default=os.environ.get("GRAFT_PP_SCHEDULE", "1f1b"),
                         choices=["gpipe", "1f1b", "interleaved"],
                         help="pipeline schedule (env twin "
-                             "$GRAFT_PP_SCHEDULE); recorded for tooling "
-                             "parity with bench.py")
+                             "$GRAFT_PP_SCHEDULE)")
     parser.add_argument("--wire", type=str, default=None,
                         help="quantized gradient wire format: int8/"
                              "int8_block/fp8_e4m3/fp8_e5m2, optional "

@@ -7,7 +7,7 @@ where multi-controller SPMD's classic failure lives: rank-conditioned
 control flow gating a collective hangs the pod with no error anywhere.
 
 This module is the substrate: it parses every production source file in
-the repo (package, drivers, benchmarks, bench.py, ``__graft_entry__``;
+the repo (package, drivers, benchmarks, ``__graft_entry__``;
 tests and examples are excluded — they seed violations on purpose) and
 extracts the facts the rules in :mod:`.source_rules` evaluate:
 
@@ -22,8 +22,7 @@ extracts the facts the rules in :mod:`.source_rules` evaluate:
   cadence guard covers them.
 
 Stdlib-only by contract itself (``ast`` + ``os``): the ``--source`` CLI
-pass and the bench parent's source gate must not pay a jax import for a
-whole-repo lint.
+pass must not pay a jax import for a whole-repo lint.
 
 Acknowledged sites: a trailing ``# graftcheck: ok(rule-name)`` comment
 on the gate line or the call line records that a human audited the site
@@ -201,7 +200,7 @@ def repo_root() -> str:
 # production source only: tests seed violations on purpose, examples are
 # user-facing snippets, fixtures embed violating code as string literals
 _SCAN_DIRS = ("pytorch_distributedtraining_tpu", "drivers", "benchmarks")
-_SCAN_ROOT_FILES = ("bench.py", "__graft_entry__.py")
+_SCAN_ROOT_FILES = ("__graft_entry__.py",)
 
 
 def iter_source_files(root: str):

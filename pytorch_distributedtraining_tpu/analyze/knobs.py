@@ -55,7 +55,7 @@ class Knob:
 
 
 def _consumer(path: str) -> str:
-    """bench.py -> bench; pytorch_distributedtraining_tpu/stoke/... -> stoke."""
+    """drivers/x.py -> drivers; pytorch_distributedtraining_tpu/stoke/... -> stoke."""
     parts = path.split("/")
     if len(parts) == 1:
         name = parts[0]

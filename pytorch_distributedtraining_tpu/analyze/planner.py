@@ -458,8 +458,8 @@ def synth_batch(plan: Plan, batch: int):
 def build_step(plan: Plan, batch: int | None = None):
     """Materialize one candidate as a concrete (step, state, batch).
 
-    Shared by the planner's AOT probe, the batch-size tuner's pre-built
-    closure, and benchmarks/plan_bench.py's measured arms. Imports jax
+    Shared by the planner's AOT probe and the batch-size tuner's pre-built
+    closure. Imports jax
     lazily — enumeration and ranking stay host-side.
     """
     import jax
@@ -790,8 +790,8 @@ def search(
 def _load_calibration_doc(path: str) -> dict:
     """Stdlib twin of observe.opcost.load_calibration (that package
     import would pull jax; the planner stays host-side). Returns the
-    FULL doc — ``calibration`` ratios plus ``meta`` (which carries the
-    measured ``axis_bandwidth`` table bench.py persists)."""
+    FULL doc — ``calibration`` ratios plus ``meta`` (which may carry a
+    measured ``axis_bandwidth`` table)."""
     with open(path, encoding="utf-8") as fh:
         doc = json.load(fh)
     if not isinstance(doc, dict) or not isinstance(doc.get("calibration"), dict):

@@ -2,7 +2,7 @@
 
 ``analyze_step`` is the one entry point every integration uses — the
 CLI, the stoke facade's ``GRAFT_ANALYZE`` hook, both drivers'
-``--analyze`` flags, bench.py, and the ``__graft_entry__`` dryrun. It
+``--analyze`` flags and the ``__graft_entry__`` dryrun. It
 AOT-lowers the step (CPU-safe: ``compiled_text`` goes through
 ``lower().compile()`` without executing) and abstract-evaluates the
 jaxpr, then feeds both artifacts to every registered rule.

@@ -23,10 +23,7 @@ from .registry import rule
     "bench step timed without the unified telemetry layer enabled",
 )
 def bench_telemetry(ctx):
-    if not (
-        os.environ.get("_GRAFT_BENCH_CHILD")
-        or os.environ.get("GRAFT_BENCH")
-    ):
+    if not os.environ.get("GRAFT_BENCH"):
         return
     # sys.modules lookup, not an import: this module must stay importable
     # from jax-free tooling, and an un-imported tracer IS the finding
@@ -40,8 +37,7 @@ def bench_telemetry(ctx):
         "bench run is timing the step without telemetry: the published "
         "record will carry no goodput/MFU breakdown, so a slow window "
         "cannot be attributed (compile vs input-wait vs outage). Unset "
-        "GRAFT_TELEMETRY=0 (bench enables the tracer by default) or "
-        "accept an unattributable number",
+        "GRAFT_TELEMETRY=0 or accept an unattributable number",
         evidence=(
             "observe.trace "
             + ("loaded but disabled" if tr is not None else "never imported")
@@ -655,9 +651,8 @@ def plan_infeasible(ctx):
 )
 def bench_regression(ctx):
     # sys.modules, never imported: observe.fleet is stdlib-only but its
-    # package __init__ pulls jax — the sentry (benchmarks/regress.py or
-    # bench.py's publication hook) populates runtime_stats before this
-    # plane runs
+    # package __init__ pulls jax — the sentry (benchmarks/regress.py)
+    # populates runtime_stats before this plane runs
     fl = sys.modules.get("pytorch_distributedtraining_tpu.observe.fleet")
     stats = getattr(fl, "runtime_stats", None)
     if not stats:

@@ -38,8 +38,8 @@ Rule catalog (severities documented in docs/STATIC_ANALYSIS.md):
   at import time in library code: the value freezes at first import, so
   a launcher that sets the knob after importing (or a test that
   monkeypatches the environment) silently reads the stale value.
-  Script-style entry points (bench.py, benchmarks/) are exempt — their
-  import *is* their invocation.
+  Script-style entry points (benchmarks/) are exempt — their import *is*
+  their invocation.
 - ``knob-undocumented`` ERROR / ``knob-dead`` WARN /
   ``knob-twin-mismatch`` ERROR — the GRAFT_* registry
   (:mod:`.knobs`) vs docs/KNOBS.md and the TPUConfig twin declarations.
@@ -65,7 +65,7 @@ from .knobs import build_registry, config_twins, load_knobs_md
 from .registry import AnalysisContext, rule, run_rules
 
 # library scope for the WARN-class hygiene rules: importable code only.
-# bench.py / benchmarks/ / __graft_entry__.py are script entry points —
+# benchmarks/ and __graft_entry__.py are script entry points —
 # still scanned (their env reads feed the knob registry, their gated
 # collectives are real hazards) but exempt from import-time and
 # host-sync hygiene, whose hazard model is "someone imports this".
@@ -75,9 +75,9 @@ _LIBRARY_PREFIXES = ("pytorch_distributedtraining_tpu/", "drivers/")
 _FENCE_WINDOW_LINES = 4
 
 # modules contracted to import without jax present (stdlib + numpy).
-# The bench parent publishes FALLBACK records, the launcher supervises,
-# and the fleet/serve tooling routes — all on hosts where the jax wheel
-# may be broken mid-incident. Grow this list, never shrink it silently.
+# The launcher supervises and the fleet/serve tooling routes on hosts
+# where the jax wheel may be broken mid-incident. Grow this list, never
+# shrink it silently.
 STDLIB_ONLY_MODULES = (
     "pytorch_distributedtraining_tpu/runtime/membership.py",
     "pytorch_distributedtraining_tpu/runtime/recovery_drill.py",
@@ -95,7 +95,6 @@ STDLIB_ONLY_MODULES = (
     "pytorch_distributedtraining_tpu/analyze/knobs.py",
     "pytorch_distributedtraining_tpu/resilience/faults.py",
     "pytorch_distributedtraining_tpu/resilience/outage.py",
-    "pytorch_distributedtraining_tpu/resilience/capture.py",
     "pytorch_distributedtraining_tpu/parallel/reshard.py",
 )
 
@@ -219,8 +218,8 @@ def _stdlib_only_violation(ctx: AnalysisContext):
                     message=(
                         f"imports `{imp}` at module level but is "
                         "contracted stdlib-only: it must import on hosts "
-                        "with no (or a broken) jax wheel — the bench "
-                        "FALLBACK path, the launcher, fleet tooling"
+                        "with no (or a broken) jax wheel — the "
+                        "launcher, fleet tooling"
                     ),
                     evidence=(
                         "reach jax-side modules through "
@@ -637,9 +636,9 @@ def source_report(
 ):
     """Run every source-plane rule over the repo; returns a Report.
 
-    This is what ``python -m ...analyze --source``, bench.py's
-    ``source_findings`` block, and the ``__graft_entry__`` source phase
-    all call. Parse errors in production source surface as findings —
+    This is what ``python -m ...analyze --source`` and the
+    ``__graft_entry__`` source phase call. Parse errors in production
+    source surface as findings —
     a file the linter cannot read is a file nobody vetted.
     """
     if facts is None:

@@ -146,7 +146,7 @@ SWINIR_EXPORT_KEY_MAP = [
 ]
 
 
-def _einsum_core(qkv, bias, mask, dtype, softmax_dtype):
+def _einsum_core(qkv, bias, mask, dtype):
     """Window attention as per-head einsums over ``[bn, heads, n, d]``
     arrays: ``qkv [bn, n, 3c]`` -> ``[bn, n, c]``. The reference every
     other implementation is held to, and what runs wherever the fused
@@ -166,12 +166,12 @@ def _einsum_core(qkv, bias, mask, dtype, softmax_dtype):
         )
         attn = attn.reshape(bn, h, n, n)
 
-    attn = jax.nn.softmax(attn.astype(softmax_dtype), axis=-1).astype(dtype)
+    attn = jax.nn.softmax(attn.astype(jnp.float32), axis=-1).astype(dtype)
     return (attn @ v).transpose(0, 2, 1, 3).reshape(bn, n, c)
 
 
-@partial(jax.jit, static_argnames=("dtype", "softmax_dtype", "mesh"))
-def _kernel_or_einsum_core(qkv, bias, mask, dtype, softmax_dtype, mesh=None):
+@partial(jax.jit, static_argnames=("dtype", "mesh"))
+def _kernel_or_einsum_core(qkv, bias, mask, dtype, mesh=None):
     """The fused kernel where the program is lowered for a TPU, the einsums
     on any other platform: one traced program serves both, and only the
     branch of the platform it is lowered for is compiled. Jitted so that a
@@ -188,9 +188,7 @@ def _kernel_or_einsum_core(qkv, bias, mask, dtype, softmax_dtype, mesh=None):
     def core(qkv, bias, mask):
         return jax.lax.platform_dependent(
             qkv, bias, mask, tpu=window_attention_qkv,
-            default=partial(
-                _einsum_core, dtype=dtype, softmax_dtype=softmax_dtype
-            ),
+            default=partial(_einsum_core, dtype=dtype),
         )
 
     if mesh is None:
@@ -207,7 +205,6 @@ class WindowAttention(nn.Module):
     num_heads: int
     window_size: int
     dtype: jnp.dtype = jnp.float32
-    softmax_dtype: jnp.dtype = jnp.float32  # attention prob accumulation
     # How the attention between the projections is computed — same
     # parameters, same math for every choice (checkpoints are
     # interchangeable):
@@ -223,24 +220,14 @@ class WindowAttention(nn.Module):
     #   'pallas'    the kernel or an error: compiles for the TPU or raises;
     #               'pallas_interpret' runs the same kernel interpreted
     #               (CPU tests)
-    #   'paired'    two windows packed into one [2n, 2n] attention with a
-    #               cross-window kill mask: score/AV matmuls fill full
-    #               128-row MXU tiles at ws=8 instead of two half-empty
-    #               64-row passes
-    #   'blockdiag' QK^T/AV as block-diagonal-packed gemms: contraction 60
-    #               instead of head_dim 10 (6x MXU K-utilization) at the
-    #               cost of materializing packed operands
     attn_impl: str = "auto"
 
     @nn.compact
     def __call__(self, x, mask=None):
-        if self.attn_impl not in (
-            "auto", "xla", "pallas", "pallas_interpret", "paired", "blockdiag"
-        ):
+        if self.attn_impl not in ("auto", "xla", "pallas", "pallas_interpret"):
             raise ValueError(
                 "attn_impl must be one of 'auto'/'xla'/'pallas'/"
-                "'pallas_interpret'/'paired'/'blockdiag', got "
-                f"{self.attn_impl!r}"
+                f"'pallas_interpret', got {self.attn_impl!r}"
             )
         c = x.shape[-1]  # x: [B*nW, ws^2, C]
         qkv = nn.Dense(3 * c, use_bias=True, dtype=self.dtype, name="qkv")(x)
@@ -258,29 +245,16 @@ class WindowAttention(nn.Module):
 
     def _core(self, qkv, table, mask):
         """Attention between the projections: [bn, n, 3c] -> [bn, n, c]."""
-        bn, n, _ = qkv.shape
-        h = self.num_heads
-        c = qkv.shape[-1] // 3
+        n, h = qkv.shape[1], self.num_heads
         idx = _relative_position_index(self.window_size)
         bias = table[idx.reshape(-1)].reshape(n, n, h).transpose(2, 0, 1)
         if mask is not None:
             mask = jnp.asarray(mask)
 
-        if self.attn_impl in ("paired", "blockdiag"):
-            heads = qkv.reshape(bn, n, 3, h, c // h).transpose(2, 0, 3, 1, 4)
-            if self.attn_impl == "blockdiag":
-                return self._blockdiag(heads[0], heads[1], heads[2], bias, mask)
-            if bn % 2 == 0 and (mask is None or mask.shape[0] % 2 == 0):
-                return self._paired(heads, bias, mask, 2)
-            # odd window counts are legal SwinIR inputs — fall through to
-            # the einsums rather than failing mid-forward
-
-        if self.attn_impl in ("auto", "pallas", "pallas_interpret"):
-            out = self._fused(qkv, bias, mask)
+        if self.attn_impl == "xla":
+            out = _einsum_core(qkv, bias, mask, self.dtype)
         else:
-            out = _einsum_core(
-                qkv, bias, mask, self.dtype, self.softmax_dtype
-            )
+            out = self._fused(qkv, bias, mask)
         # named-remat tag (parallel/remat.py "names"/"offload"): save the
         # softmax·V product, recompute the cheap projections
         return checkpoint_name(out, "attn_out")
@@ -294,20 +268,14 @@ class WindowAttention(nn.Module):
         from ..ops import pallas_window_attn as pwa
 
         bn, n, c3 = qkv.shape
-        why = None
-        if self.softmax_dtype != jnp.float32:
-            why = (
-                "the kernel's softmax is float32; softmax_dtype="
-                f"{jnp.dtype(self.softmax_dtype).name} asks for less"
-            )
-        why = why or pwa.kernel_contract(
+        why = pwa.kernel_contract(
             bn, n, c3 // 3, self.num_heads,
             None if mask is None else mask.shape[0], qkv.dtype,
         )
         bias = bias.astype(jnp.float32)
         if self.attn_impl != "auto":
             if why is not None:
-                # refusing keeps ablation arms honestly labeled
+                # a caller who names the kernel gets it or an error
                 raise ValueError(f"attn_impl={self.attn_impl!r}: {why}")
             path, why = "kernel", f"attn_impl={self.attn_impl!r}"
             out = pwa.window_attention_qkv(
@@ -319,9 +287,7 @@ class WindowAttention(nn.Module):
                 mesh, why = self._kernel_mesh(qkv, mask)
             if why is not None:
                 path = "einsum"
-                out = _einsum_core(
-                    qkv, bias, mask, self.dtype, self.softmax_dtype
-                )
+                out = _einsum_core(qkv, bias, mask, self.dtype)
             else:
                 # decided when the program is lowered: the kernel for a
                 # TPU, the einsums for any other platform
@@ -331,7 +297,7 @@ class WindowAttention(nn.Module):
                     f"; each of the mesh's {mesh.size} devices its own windows"
                 )
                 out = _kernel_or_einsum_core(
-                    qkv, bias, mask, self.dtype, self.softmax_dtype, mesh
+                    qkv, bias, mask, self.dtype, mesh
                 )
         trace.instant(
             "window_attention.path", path=path, reason=why,
@@ -368,90 +334,6 @@ class WindowAttention(nn.Module):
         )
         return (mesh, None) if why is None else (None, f"a device's share: {why}")
 
-    def _paired(self, qkv, bias, mask, p: int):
-        """Two windows per attention: [p*n, p*n] scores with an additive
-        cross-window kill mask (-100 -> softmax ~0, the shift-mask trick),
-        so each score/AV matmul runs a full ``p*n``-row MXU tile.
-        Unshifted layers may pair across image boundaries — the kill mask
-        zeroes every cross-window probability, so pairing is image-blind.
-        """
-        q, k, v = qkv[0], qkv[1], qkv[2]  # [bn, h, n, d]
-        bn, h, n, d = q.shape
-        c = h * d
-
-        def pack(t):  # [bn, h, n, d] -> [bn/p, h, p*n, d]
-            return t.reshape(bn // p, p, h, n, d).transpose(
-                0, 2, 1, 3, 4
-            ).reshape(bn // p, h, p * n, d)
-
-        q, k, v = pack(q), pack(k), pack(v)
-        attn = (q * d**-0.5) @ k.transpose(0, 1, 3, 2)  # [bn/p, h, pn, pn]
-
-        eye = jnp.eye(p, dtype=bias.dtype)
-        bias_pair = jnp.einsum("ab,hnm->hanbm", eye, bias).reshape(
-            h, p * n, p * n
-        )
-        kill = (1.0 - jnp.eye(p, dtype=jnp.float32)) * -100.0
-        kill = jnp.repeat(jnp.repeat(kill, n, 0), n, 1)  # [pn, pn]
-        attn = attn + (bias_pair + kill.astype(bias.dtype)[None]).astype(
-            attn.dtype
-        )[None]
-
-        if mask is not None:  # [nW, n, n] per-window shift mask
-            nw = mask.shape[0]
-            m = jnp.asarray(mask).reshape(nw // p, p, n, n)
-            m_pair = jnp.einsum(
-                "ab,wanm->wanbm", eye.astype(m.dtype), m
-            ).reshape(nw // p, p * n, p * n)
-            attn = attn.reshape(
-                bn // nw, nw // p, h, p * n, p * n
-            ) + m_pair[None, :, None].astype(attn.dtype)
-            attn = attn.reshape(bn // p, h, p * n, p * n)
-
-        attn = jax.nn.softmax(
-            attn.astype(self.softmax_dtype), axis=-1
-        ).astype(self.dtype)
-        out = attn @ v  # [bn/p, h, p*n, d]
-        out = out.reshape(bn // p, h, p, n, d).transpose(
-            0, 2, 3, 1, 4
-        ).reshape(bn, n, c)
-        return checkpoint_name(out, "attn_out")
-
-    def _blockdiag(self, q, k, v, bias, mask):
-        """QK^T / AV as single block-diagonal-packed gemms per window:
-        contraction ``h*d`` (60) instead of ``d`` (10) — 6x MXU
-        K-utilization — at the cost of materializing packed operands."""
-        import jax.scipy.linalg as jsp
-
-        bn, h, n, d = q.shape
-        c = h * d
-
-        kT = k.transpose(0, 1, 3, 2)  # [bn, h, d, n]
-        kblk = jax.vmap(
-            lambda ks: jsp.block_diag(*[ks[i] for i in range(h)])
-        )(kT)  # [bn, h*d, h*n]
-        q2 = q.transpose(0, 2, 1, 3).reshape(bn, n, c)
-        s = (q2 * d**-0.5) @ kblk  # [bn, n, h*n]
-        attn = s.reshape(bn, n, h, n).transpose(0, 2, 1, 3)
-
-        attn = attn + bias[None].astype(attn.dtype)
-        if mask is not None:
-            nw = mask.shape[0]
-            attn = attn.reshape(bn // nw, nw, h, n, n) + mask[
-                None, :, None
-            ].astype(attn.dtype)
-            attn = attn.reshape(bn, h, n, n)
-        attn = jax.nn.softmax(
-            attn.astype(self.softmax_dtype), axis=-1
-        ).astype(self.dtype)
-
-        vblk = jax.vmap(
-            lambda vs: jsp.block_diag(*[vs[i] for i in range(h)])
-        )(v)  # [bn, h*n, h*d]
-        p2 = attn.transpose(0, 2, 1, 3).reshape(bn, n, h * n)
-        out = p2 @ vblk  # heads already concatenated
-        return checkpoint_name(out, "attn_out")
-
 
 class SwinLayer(nn.Module):
     """One STL: (shifted-)window attention + MLP, pre-norm residuals."""
@@ -462,8 +344,6 @@ class SwinLayer(nn.Module):
     shift: int
     mlp_ratio: float
     dtype: jnp.dtype = jnp.float32
-    norm_dtype: jnp.dtype = jnp.float32  # LN compute/storage dtype
-    softmax_dtype: jnp.dtype = jnp.float32
     attn_impl: str = "auto"
 
     @nn.compact
@@ -471,7 +351,7 @@ class SwinLayer(nn.Module):
         b, hgt, wid, c = x.shape
         ws = self.window_size
         shortcut = x
-        y = nn.LayerNorm(dtype=self.norm_dtype, name="norm1")(x)
+        y = nn.LayerNorm(dtype=jnp.float32, name="norm1")(x)
         if self.shift > 0:
             y = _roll(y, -self.shift)
             mask = jnp.asarray(_shift_attn_mask(hgt, wid, ws, self.shift))
@@ -480,15 +360,14 @@ class SwinLayer(nn.Module):
         wins = window_partition(y.astype(self.dtype), ws)
         wins = WindowAttention(
             self.dim, self.num_heads, ws, dtype=self.dtype,
-            softmax_dtype=self.softmax_dtype, attn_impl=self.attn_impl,
-            name="attn",
+            attn_impl=self.attn_impl, name="attn",
         )(wins, mask)
         y = window_reverse(wins, ws, hgt, wid)
         if self.shift > 0:
             y = _roll(y, self.shift)
         x = shortcut + y.astype(shortcut.dtype)
 
-        y = nn.LayerNorm(dtype=self.norm_dtype, name="norm2")(x).astype(self.dtype)
+        y = nn.LayerNorm(dtype=jnp.float32, name="norm2")(x).astype(self.dtype)
         hdim = int(self.dim * self.mlp_ratio)
         y = nn.Dense(hdim, dtype=self.dtype, name="fc1")(y)
         y = nn.gelu(y)
@@ -511,15 +390,12 @@ class SwinLayerPair(nn.Module):
     window_size: int
     mlp_ratio: float
     dtype: jnp.dtype = jnp.float32
-    norm_dtype: jnp.dtype = jnp.float32
-    softmax_dtype: jnp.dtype = jnp.float32
     attn_impl: str = "auto"
 
     @nn.compact
     def __call__(self, x):
         kw = dict(
             mlp_ratio=self.mlp_ratio, dtype=self.dtype,
-            norm_dtype=self.norm_dtype, softmax_dtype=self.softmax_dtype,
             attn_impl=self.attn_impl,
         )
         x = SwinLayer(
@@ -542,8 +418,6 @@ class RSTB(nn.Module):
     window_size: int
     mlp_ratio: float
     dtype: jnp.dtype = jnp.float32
-    norm_dtype: jnp.dtype = jnp.float32
-    softmax_dtype: jnp.dtype = jnp.float32
     attn_impl: str = "auto"
     # Activation remat per layer/pair: bool (True == "full") or a named
     # policy from parallel/remat.py
@@ -557,7 +431,6 @@ class RSTB(nn.Module):
         shortcut = x
         kw = dict(
             mlp_ratio=self.mlp_ratio, dtype=self.dtype,
-            norm_dtype=self.norm_dtype, softmax_dtype=self.softmax_dtype,
             attn_impl=self.attn_impl,
         )
         if self.scan_layers and self.depth >= 2 and self.depth % 2 == 0:
@@ -606,14 +479,8 @@ class SwinIR(nn.Module):
     upsampler: str = "pixelshuffledirect"
     resi_connection: str = "1conv"
     dtype: jnp.dtype = jnp.float32
-    # LayerNorm compute/storage dtype. f32 is the safe default; bf16 halves
-    # the HBM traffic of the 50 norm applications (24 SwinLayers x 2 +
-    # patch_norm + final norm; the step is bandwidth-bound at these shapes,
-    # see benchmarks/profile_swinir.py) at ~1e-2 output tolerance.
-    norm_dtype: jnp.dtype = jnp.float32
-    softmax_dtype: jnp.dtype = jnp.float32  # attention softmax accumulation
-    # 'auto' | 'xla' | 'pallas' | 'pallas_interpret' | 'paired' | 'blockdiag'
-    # — see WindowAttention.attn_impl for what each computes
+    # 'auto' | 'xla' | 'pallas' | 'pallas_interpret' — see
+    # WindowAttention.attn_impl for what each computes
     attn_impl: str = "auto"
     # Activation remat per Swin layer/pair: bool (True == "full") or a
     # named policy from parallel/remat.py ("dots"/"names"/"offload")
@@ -652,18 +519,17 @@ class SwinIR(nn.Module):
         # torch SwinIR's patch_embed norm (patch_norm=True default): a
         # channel LayerNorm between shallow conv and the RSTB body — kept so
         # reference checkpoints map onto an identical function
-        y = nn.LayerNorm(dtype=self.norm_dtype, name="patch_norm")(feat).astype(
+        y = nn.LayerNorm(dtype=jnp.float32, name="patch_norm")(feat).astype(
             self.dtype
         )
         for i, (depth, heads) in enumerate(zip(self.depths, self.num_heads)):
             y = RSTB(
                 self.embed_dim, depth, heads, ws, self.mlp_ratio,
-                dtype=self.dtype, norm_dtype=self.norm_dtype,
-                softmax_dtype=self.softmax_dtype, attn_impl=self.attn_impl,
+                dtype=self.dtype, attn_impl=self.attn_impl,
                 remat=self.remat, scan_layers=self.scan_layers,
                 name=f"rstb_{i}",
             )(y)
-        y = nn.LayerNorm(dtype=self.norm_dtype, name="norm")(y).astype(self.dtype)
+        y = nn.LayerNorm(dtype=jnp.float32, name="norm")(y).astype(self.dtype)
         y = nn.Conv(
             self.embed_dim, (3, 3), padding="SAME", dtype=self.dtype,
             name="conv_after_body",

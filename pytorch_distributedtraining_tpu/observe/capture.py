@@ -16,8 +16,7 @@ trips:
 
 Every source is read through ``sys.modules`` — never imported — so an
 armed profiler in a process that runs none of those planes polls four
-dict lookups and nothing else. That armed-but-idle cost is priced into
-bench.py's 1% telemetry-overhead gate, not assumed free.
+dict lookups and nothing else.
 
 Captures are bounded three ways: a cooldown between fires (each source
 fires at most once per cooldown window), a max-captures budget per

@@ -34,12 +34,12 @@ inseparable from fleet-wide monitoring):
   per metric family: WARN on drift, ERROR on regression, and outage /
   fallback / zero-value records are *excluded* from the trajectory and
   never count as regressions themselves. ``benchmarks/regress.py`` is
-  the CLI; ``bench.py`` runs it at publication; graftcheck's
-  ``bench-regression`` runtime rule reads :data:`runtime_stats`.
+  the CLI; graftcheck's ``bench-regression`` runtime rule reads
+  :data:`runtime_stats`.
 
 Stdlib-only by contract, like ``observe/trace.py`` and ``runtime/
-membership.py``: the launcher's controller loop and the bench parent
-drive this module, and nothing in it may touch jax.
+membership.py``: the launcher's controller loop drives this module, and
+nothing in it may touch jax.
 """
 
 from __future__ import annotations

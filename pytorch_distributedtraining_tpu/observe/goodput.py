@@ -180,10 +180,9 @@ class GoodputLedger:
 
 # -- analytic model FLOPs ----------------------------------------------
 #
-# Training cost as 3x forward (fwd + ~2x bwd), the standard estimate the
-# roofline guard in bench.py already uses (SwinIR-S x2 @64x64 ≈ 21
-# GFLOPs/image trained — swinir_train_flops computes the same quantity
-# from the config instead of hardcoding it).
+# Training cost as 3x forward (fwd + ~2x bwd), the standard estimate
+# (SwinIR-S x2 @64x64 ≈ 21 GFLOPs/image trained — swinir_train_flops
+# computes it from the config).
 
 _TRAIN_MULT = 3.0  # fwd + bwd ≈ 3x fwd matmul FLOPs
 
@@ -243,7 +242,7 @@ def swinir_train_flops(
 
     Window attention: the qk^T/att*v matmuls see ``window_size**2``-long
     sequences, so their cost is linear in tokens. Defaults are the
-    SwinIR-S flagship (bench.py) — at 64x64/x2 this lands in the same
+    SwinIR-S of the reference — at 64x64/x2 this lands in the same
     ~20-26 GFLOPs/image band as the ~21 GFLOPs/image hand derivation
     (which rounds the conv tail down).
     """

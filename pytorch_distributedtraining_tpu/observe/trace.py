@@ -34,8 +34,7 @@ may import this, and package import must not touch a backend
 ``sys.modules`` at first use; while jax is not loaded a span is the null
 span. Telemetry-off cost is one annotation object per span (under half a
 microsecond) — cheap enough to leave the instrumentation in production
-code paths (bench.py's ``telemetry_overhead`` guard enforces <1% of step
-time when the ring is *enabled*).
+code paths.
 
 The span names of the program (``graft/<name>`` in a profile):
 ``TrainStep|MultiStep|EvalStep|PipelineStep|CompressedGradStep|

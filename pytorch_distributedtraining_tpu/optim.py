@@ -302,9 +302,7 @@ class FusedAdamW:
 
     The per-leaf optax chain lowers to several XLA fusions per parameter
     leaf; on a 200+-leaf model (SwinIR-S: 222) that is >1000 tiny
-    dispatches whose fixed per-op cost dominates the update (measured
-    2.4 ms of a 3.7 ms step on-chip — `benchmarks/profile_swinir.py`
-    `full` vs `fwd_bwd`). Here grads and params are ravelled once into a
+    dispatches whose fixed per-op cost dominates the update. Here grads and params are ravelled once into a
     single vector, clip → Adam → weight decay → lr run as full-width
     vector ops, and the new params are unravelled once — the same
     economics as apex/DeepSpeed FusedAdam on CUDA, expressed as one XLA

@@ -1,36 +1,26 @@
-"""Resilience: fault injection, outage classification, resilient capture.
+"""Resilience: fault injection and outage classification.
 
 The reference stack's robustness contract is implicit (elastic restarts,
 rendezvous retry, preemption save — SURVEY §5) and was never adversarially
-exercised; five rounds of benchmark captures died to pool outages because
-every layer classified and retried failures its own way. This package makes
-the contract explicit and shared:
+exercised; every layer classified and retried failures its own way. This
+package makes the contract explicit and shared:
 
 - :mod:`.faults` — a deterministic fault-injection harness
   (:class:`FaultPlan` + :func:`fault_point`): env/JSON-driven failures at
-  named sites threaded through the launcher, rendezvous, data loader,
-  checkpoint writer, and bench capture pipeline, so every recovery path has
-  a repeatable chaos test instead of hoping.
+  named sites threaded through the launcher, rendezvous, data loader and
+  checkpoint writer, so every recovery path has a repeatable chaos test
+  instead of hoping.
 - :mod:`.outage` — ONE outage classifier (:func:`classify`,
   :func:`classify_exception`) plus :class:`RetryPolicy` (exponential
   backoff + deterministic jitter) and :class:`CircuitBreaker` (half-open
-  probes), reused by ``bench.py``, the launcher's restart monitor, and the
-  W&B sink — no more ad-hoc sentinel string matching per call site.
-- :mod:`.capture` — the bench capture state machine
-  (PROBE → CAPTURE → RIDE_OUTAGE → FALLBACK → EMIT) and the structured
-  FALLBACK artifact builder: a pool outage degrades to an honest
-  provenance-flagged record carrying the last-good on-chip number and a
-  CPU-envelope measurement, never a bare value-0.0 artifact.
+  probes), reused by the launcher's restart monitor, the rendezvous,
+  checkpoint writes and the W&B sink — no ad-hoc sentinel string matching
+  per call site.
 
-Everything here is stdlib-only at import time: the bench parent (which must
+Everything here is stdlib-only at import time: the launcher (which must
 stay jax-free) and spawn-context loader workers both import it.
 """
 
-from .capture import (
-    CaptureMachine,
-    CaptureState,
-    build_fallback_record,
-)
 from .faults import (
     FaultPlan,
     FaultRule,
@@ -48,15 +38,12 @@ from .outage import (
 )
 
 __all__ = [
-    "CaptureMachine",
-    "CaptureState",
     "CircuitBreaker",
     "FaultPlan",
     "FaultRule",
     "InjectedFault",
     "OutageClass",
     "RetryPolicy",
-    "build_fallback_record",
     "classify",
     "classify_exception",
     "external_termination",

@@ -1,8 +1,8 @@
 """Deterministic fault injection: FaultPlan + fault_point hooks.
 
 Every recovery path in this stack (elastic restarts, rendezvous retry,
-preemption save, loader worker replacement, checkpoint-write retry, the
-bench outage ride-out) existed before this module — but none were ever
+preemption save, loader worker replacement, checkpoint-write retry) existed
+before this module — but none were ever
 *exercised* except by a real pool flap. A :class:`FaultPlan` injects the
 failure repeatably so the chaos tests in ``tests/test_resilience.py`` can
 assert recovery instead of hoping.
@@ -28,10 +28,6 @@ Named sites (each threaded into the layer that owns it):
                        crash-consistency drills (``checkpoint_sharded.py``)
 ``train.preempt``      mid-step SIGTERM preemption, delivered to self at a
                        chosen ``maybe_save`` call (``checkpoint_sharded.py``)
-``bench.probe``        bench probe child dies with an outage signature —
-                       a simulated total pool outage (``bench.py``)
-``bench.child``        bench measurement child dies mid-attempt
-                       (``bench.py``)
 ``launch.grow``        elastic launcher is about to initiate a grow-back
                        reshard — ``raise`` vetoes this grow attempt (the
                        gate re-arms), ``sleep`` delays the teardown
@@ -65,8 +61,8 @@ Named sites (each threaded into the layer that owns it):
 =====================  =====================================================
 
 A plan is JSON — inline in ``GRAFT_FAULT_PLAN`` or a file path — so it
-crosses process boundaries for free (the launcher's children, spawn-context
-loader workers, and the bench's probe children all inherit the env)::
+crosses process boundaries for free (the launcher's children and
+spawn-context loader workers inherit the env)::
 
     {"faults": [
         {"site": "loader.fetch", "at": 3, "times": 1,
@@ -117,8 +113,6 @@ SITES = frozenset({
     "checkpoint.write",
     "ckpt.write",
     "train.preempt",
-    "bench.probe",
-    "bench.child",
     "serve.admit",
     "serve.client",
     "route.dispatch",
@@ -136,9 +130,9 @@ def _telemetry_on_fire(site: str, action: str, msg: str) -> None:
     """Mark the injection in the telemetry stream, if telemetry is loaded.
 
     Looked up via ``sys.modules`` — never imported — so this module keeps
-    its stdlib-only contract (the jax-free bench parent and launcher both
-    import it). When the tracer is live, the injection lands as an instant
-    event and the flight recorder is flushed BEFORE the action executes:
+    its stdlib-only contract (the jax-free launcher imports it). When the
+    tracer is live, the injection lands as an instant event and the
+    flight recorder is flushed BEFORE the action executes:
     for ``kill``/``exit`` actions this flush is the only record the process
     leaves behind.
     """
@@ -251,8 +245,7 @@ class FaultPlan:
                 "match", "message", "arg", "after_s",
             }
             if unknown:
-                # a typoed key would silently never fire — fail loudly, the
-                # same convention as bench_knobs.json's unknown-key guard
+                # a typoed key would silently never fire — fail loudly
                 raise ValueError(
                     f"fault rule has unknown keys {sorted(unknown)}: {raw}"
                 )

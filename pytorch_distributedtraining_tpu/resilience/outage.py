@@ -1,15 +1,15 @@
 """Shared outage classifier + retry policy + circuit breaker.
 
-Extracted from the ad-hoc probe-failure classification that lived in
-``bench.py`` (round 5): every layer that has to decide "is this failure the
-shared pool flapping, or is my code broken?" now asks the same question of
+Extracted from an ad-hoc probe-failure classification (round 5): every
+layer that has to decide "is this failure the shared pool flapping, or is
+my code broken?" now asks the same question of
 the same classifier. The sentinel set is deliberately broad (ADVICE r5 #4):
 the round-1..5 capture failures surfaced as ``UNAVAILABLE`` raises, rc=124
 driver timeouts, connection-refused text *without* the literal UNAVAILABLE,
 and silent hangs — a classifier that only knows one signature reintroduces
 the capture-failure mode this module exists to end.
 
-Stdlib-only: the bench parent (jax-free by contract) imports this.
+Stdlib-only: the launcher (jax-free by contract) imports this.
 """
 
 from __future__ import annotations
@@ -59,8 +59,8 @@ _CONNECTION_SENTINELS = (
 
 # return codes that are outage-class by construction:
 #   None — the caller killed a hung child (pool claim wedged)
-#   3    — the probe's own CPU-fallback refusal (pool dropped mid-run)
-#   4    — the bench child's CPU-fallback refusal (pool dropped after probe)
+#   3, 4 — a child's refusal to fall back to the CPU (pool dropped
+#          before or after it was probed)
 #   124  — coreutils `timeout` expiry (driver-side kill of a hung capture)
 _OUTAGE_RCS = frozenset({3, 4, 124})
 

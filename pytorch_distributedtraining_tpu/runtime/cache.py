@@ -10,7 +10,7 @@ platform, version and device kinds (``jax/_src/cache_key.py``), so entries a
 CPU run wrote are never loaded for a TPU program that shares the directory.
 
 This module imports jax only inside :func:`enable_compile_cache`, so
-jax-free parents (bench.py's orchestrator) can ask for the path.
+jax-free parents can ask for the path.
 """
 
 from __future__ import annotations

@@ -641,10 +641,9 @@ class Stoke:
         self._numerics_count = 0
         # op-cost attribution + anomaly-triggered capture (env >
         # TPUConfig): an armed OnDemandProfiler polls the anomaly
-        # sources once per fused step (dict reads — priced inside the 1%
-        # telemetry budget by bench.py); when a capture fires and the
-        # opcost plane is on, the post-fire hook parses it into the
-        # per-axis bandwidth gauges the fleet endpoint publishes
+        # sources once per fused step (dict reads); when a capture fires
+        # and the opcost plane is on, the post-fire hook parses it into
+        # the per-axis bandwidth gauges the fleet endpoint publishes
         self.opcost = _opcost_from_env(self.tpu_config)
         capture_on, capture_dir = _capture_from_env(self.tpu_config)
         self.capture = None

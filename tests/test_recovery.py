@@ -1,5 +1,5 @@
 """Elastic recovery: async checkpointing, crash consistency, N→M reshard,
-shrink-to-survive launcher, and the bench recovery arm (ISSUE 8)."""
+shrink-to-survive launcher, and the recovery drill under it (ISSUE 8)."""
 
 import json
 import os
@@ -686,105 +686,27 @@ class TestElasticLauncher:
         ]
 
 
-# -- bench recovery arm (end to end) ---------------------------------------
+# -- the recovery drill under the elastic launcher (end to end) -------------
 
 
-def test_bench_recovery_arm_end_to_end(tmp_path):
-    """Acceptance: GRAFT_BENCH_RECOVERY=1 trips train.preempt, the elastic
-    launcher resumes at the surviving world size from the latest COMMITTED
-    checkpoint, and the JSON record carries time_to_recover_s > 0 +
-    recovery_mode — with the torn dir provably not the resume source."""
-    env = dict(os.environ)
-    env["GRAFT_BENCH_RECOVERY"] = "1"
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        env=env, capture_output=True, text=True, timeout=480, cwd=REPO,
-    )
-    assert proc.returncode == 0, (proc.stdout[-2000:], proc.stderr[-1000:])
-    rec = None
-    for line in reversed(proc.stdout.splitlines()):
-        line = line.strip()
-        if line.startswith("{"):
-            rec = json.loads(line)
-            break
-    assert rec is not None, proc.stdout[-2000:]
-    assert rec["metric"] == "time_to_recover_s"
-    assert rec["value"] > 0
-    assert rec["recovery_mode"] == "shrink"
-    assert rec["world_from"] == 2 and rec["world_to"] == 1
-    assert rec["mesh_from"] == 4 and rec["mesh_to"] == 2
-    # torn step dir never became the resume source: the drill resumed
-    # from the last COMMITTED step, two below the crash step
-    assert rec["torn_dirs_skipped"], rec
-    torn_steps = [
-        int(d.split("_")[1].split(".")[0]) for d in rec["torn_dirs_skipped"]
-    ]
-    assert rec["resume_step"] < min(torn_steps)
-    assert rec["resume_step"] == rec["crash_step"] - 2
-
-
-# -- elastic grow-back + multi-node membership (ISSUE 11) -------------------
-
-
-@pytest.mark.slow
-def test_bench_grow_arm_end_to_end():
-    """Acceptance: the grow drill shrinks 2→1 on the preemption, the
-    controller's capacity probes fire the hysteresis gate, the world is
-    torn down gracefully (forced portable save) and relaunched at 2 with
-    GRAFT_RECOVERY_MODE=grow — and the grown state is BITWISE equal to an
-    independent single-device read of the same checkpoint. The bench
-    record publishes time_to_grow_s."""
-    env = dict(os.environ)
-    env["GRAFT_BENCH_RECOVERY"] = "1"
-    env["GRAFT_BENCH_RECOVERY_GROW"] = "1"
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        env=env, capture_output=True, text=True, timeout=600, cwd=REPO,
-    )
-    assert proc.returncode == 0, (proc.stdout[-2000:], proc.stderr[-1000:])
-    rec = None
-    for line in reversed(proc.stdout.splitlines()):
-        line = line.strip()
-        if line.startswith("{"):
-            rec = json.loads(line)
-            break
-    assert rec is not None, proc.stdout[-2000:]
-    if rec.get("skipped"):
-        pytest.skip(f"no multiprocess CPU world here: {rec.get('reason')}")
-    assert rec["metric"] == "time_to_recover_s"
-    assert rec["recovery_mode"] == "shrink"
-    assert rec["world_from"] == 2 and rec["world_to"] == 1
-    assert rec["time_to_grow_s"] > 0
-    assert rec["grow_world_to"] == 2 and rec["grow_mesh_to"] == 4
-    assert rec["grow_bitwise_ok"] is True
-    # the grow generation resumed at (or past) the shrink generation's
-    # resume point — a grow must never lose committed progress
-    assert rec["grow_resume_step"] >= rec["resume_step"]
-    assert rec["torn_dirs_skipped"], rec
-
-
-@pytest.mark.slow
-def test_kill_during_pre_grow_save_leaves_committed_checkpoint(tmp_path):
-    """Chaos: SIGKILL the trainer INSIDE its first attempt-1 checkpoint
-    write (which — depending on when the grow teardown lands — is either
-    the pre-grow forced save or the last scheduled save before it). The
-    torn .tmp must never become a resume source: whichever generation
-    comes next resumes from the last COMMITTED step, and the run still
-    grows back to the full world with a bitwise-clean reshard."""
+def _drill_under_launcher(tmp_path, extra_faults=(), *, grow=False,
+                          crash_step=4, timeout=300):
+    """Run ``runtime/recovery_drill.py`` under the elastic launcher with a
+    fault plan that (a) wedges the step-(K-1) checkpoint write inside the
+    background writer, leaving a torn, uncommitted ``.tmp`` step dir, and
+    (b) SIGKILLs rank 0 at step K's ``maybe_save``. Returns the drill's
+    JSONL events (its own clock); skips where no multiprocess CPU world
+    can be built."""
     from pytorch_distributedtraining_tpu.runtime import recovery_drill
 
     out = tmp_path / "events.jsonl"
-    crash_step = 4
     plan = {
         "faults": [
             {"site": "ckpt.write", "action": "sleep", "arg": 600,
              "rank": 0, "attempt": 0, "match": {"step": crash_step - 1}},
             {"site": "train.preempt", "action": "kill",
              "rank": 0, "attempt": 0, "match": {"step": crash_step}},
-            # the new rule under test: the shrunken generation's FIRST
-            # save dies mid-write, leaving a second torn .tmp behind
-            {"site": "ckpt.write", "action": "kill",
-             "rank": 0, "attempt": 1, "at": 1},
+            *extra_faults,
         ]
     }
     plan_path = tmp_path / "fault_plan.json"
@@ -794,17 +716,23 @@ def test_kill_during_pre_grow_save_leaves_committed_checkpoint(tmp_path):
         GRAFT_FAULT_PLAN=str(plan_path),
         GRAFT_DRILL_OUT=str(out),
         GRAFT_DRILL_CKPT=str(tmp_path / "ckpt"),
-        GRAFT_DRILL_STEPS=str(crash_step + 12),
-        GRAFT_DRILL_GROW="1",
-        GRAFT_DRILL_STEP_SLEEP_S="0.25",
-        GRAFT_GROW_PROBES="2",
-        GRAFT_GROW_PROBE_INTERVAL_S="0.3",
-        GRAFT_GROW_MIN_INTERVAL_S="3",
+        GRAFT_DRILL_STEPS=str(crash_step + 2),
         GRAFT_LAUNCH_ESCALATE_S="5",
         GRAFT_RESTART_BACKOFF="0.1",
         JAX_PLATFORMS="cpu",
         PYTHONUNBUFFERED="1",
     )
+    if grow:
+        # the shrunken generation dawdles so the launcher's capacity
+        # probes can fire, then takes the graceful teardown
+        env.update(
+            GRAFT_DRILL_GROW="1",
+            GRAFT_DRILL_STEP_SLEEP_S="0.25",
+            GRAFT_DRILL_STEPS=str(crash_step + 12),
+            GRAFT_GROW_PROBES="2",
+            GRAFT_GROW_PROBE_INTERVAL_S="0.3",
+            GRAFT_GROW_MIN_INTERVAL_S="3",
+        )
     if "xla_force_host_platform_device_count" not in env.get("XLA_FLAGS", ""):
         env["XLA_FLAGS"] = (
             env.get("XLA_FLAGS", "")
@@ -815,15 +743,104 @@ def test_kill_during_pre_grow_save_leaves_committed_checkpoint(tmp_path):
             sys.executable, "-m",
             "pytorch_distributedtraining_tpu.runtime.launch",
             "--nproc_per_node=2", "--max_restarts=2",
-            "--elastic", "--grow", "--min_world=1",
+            "--elastic", "--min_world=1", *(["--grow"] if grow else []),
             recovery_drill.__file__,
         ],
-        env=env, capture_output=True, text=True, timeout=300, cwd=REPO,
+        env=env, capture_output=True, text=True, timeout=timeout, cwd=REPO,
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
     events = [json.loads(l) for l in out.read_text().splitlines() if l.strip()]
     if any(e["event"] == "skip" for e in events):
         pytest.skip("no multiprocess CPU world here")
+    return events
+
+
+def _shrink_facts(events, crash_step=4):
+    """The shrink generation's resume, held to the crash-consistency
+    contract: world 2 -> 1, mesh 4 -> 2, resumed from the last COMMITTED
+    step, below every torn directory. Returns (resume event, seconds from
+    the last pre-crash trained step to the first one after the resume)."""
+    steps0 = [e for e in events if e["event"] == "step" and e["attempt"] == 0]
+    resume = next(e for e in events if e["event"] == "resume")
+    first_back = next(
+        e for e in events
+        if e["event"] == "step" and e["attempt"] == resume["attempt"]
+    )
+    assert resume["mode"] == "shrink"
+    assert steps0[0]["world"] == 2 and resume["world"] == 1
+    assert steps0[0]["fsdp"] == 4 and resume["fsdp"] == 2
+    # torn step dir never became the resume source: the drill resumed
+    # from the last COMMITTED step, two below the crash step
+    assert resume["torn_dirs"], resume
+    torn_steps = [
+        int(d.split("_")[1].split(".")[0]) for d in resume["torn_dirs"]
+    ]
+    assert resume["step"] < min(torn_steps)
+    assert resume["step"] == crash_step - 2
+    return resume, first_back["t"] - max(e["t"] for e in steps0)
+
+
+def test_recovery_drill_resumes_from_committed_step_end_to_end(tmp_path):
+    """Acceptance: train.preempt kills rank 0, the elastic launcher resumes
+    at the surviving world size from the latest COMMITTED checkpoint, and
+    time_to_recover_s (first post-resume trained step minus last pre-crash
+    one) is > 0 — with the torn dir provably not the resume source."""
+    events = _drill_under_launcher(tmp_path, timeout=480)
+    _, time_to_recover_s = _shrink_facts(events)
+    assert time_to_recover_s > 0
+    assert any(e["event"] == "done" for e in events)
+
+
+# -- elastic grow-back + multi-node membership (ISSUE 11) -------------------
+
+
+@pytest.mark.slow
+def test_recovery_drill_grows_back_end_to_end(tmp_path):
+    """Acceptance: the grow drill shrinks 2→1 on the preemption, the
+    controller's capacity probes fire the hysteresis gate, the world is
+    torn down gracefully (forced portable save) and relaunched at 2 with
+    GRAFT_RECOVERY_MODE=grow — and the grown state is BITWISE equal to an
+    independent single-device read of the same checkpoint."""
+    events = _drill_under_launcher(tmp_path, grow=True, timeout=600)
+    resume, _ = _shrink_facts(events)
+    g_resume = next(
+        e for e in events if e["event"] == "resume" and e["mode"] == "grow"
+    )
+    pre_grow = [
+        e for e in events
+        if e["event"] in ("step", "preempt_exit")
+        and 0 < e["attempt"] < g_resume["attempt"]
+    ]
+    first_grown = next(
+        e for e in events
+        if e["event"] == "step" and e["attempt"] == g_resume["attempt"]
+    )
+    time_to_grow_s = first_grown["t"] - max(e["t"] for e in pre_grow)
+    assert time_to_grow_s > 0
+    assert g_resume["world"] == 2 and g_resume["fsdp"] == 4
+    bit = next(e for e in events if e["event"] == "grow_bitwise")
+    assert bit["ok"] is True
+    # the grow generation resumed at (or past) the shrink generation's
+    # resume point — a grow must never lose committed progress
+    assert g_resume["step"] >= resume["step"]
+
+
+@pytest.mark.slow
+def test_kill_during_pre_grow_save_leaves_committed_checkpoint(tmp_path):
+    """Chaos: SIGKILL the trainer INSIDE its first attempt-1 checkpoint
+    write (which — depending on when the grow teardown lands — is either
+    the pre-grow forced save or the last scheduled save before it). The
+    torn .tmp must never become a resume source: whichever generation
+    comes next resumes from the last COMMITTED step, and the run still
+    grows back to the full world with a bitwise-clean reshard."""
+    events = _drill_under_launcher(
+        tmp_path,
+        # the rule under test: the shrunken generation's FIRST save dies
+        # mid-write, leaving a second torn .tmp behind
+        [{"site": "ckpt.write", "action": "kill",
+          "rank": 0, "attempt": 1, "at": 1}],
+        grow=True,
+    )
     # some generation saw the torn attempt-1 write and still resumed from
     # the last committed step BELOW it (step 2: steps 1,2 committed in
     # gen 0; step 3's writes were torn in both gen 0 and gen 1)

@@ -2,15 +2,11 @@
 
 Every recovery path in the stack existed before this suite — elastic
 restarts, rendezvous retry, loader worker replacement, checkpoint-write
-retry, preemption save, the bench outage ride-out — but none were ever
-exercised except by a real pool flap. Each chaos test injects the failure
+retry, preemption save — but none were ever exercised except by a real pool flap. Each chaos test injects the failure
 deterministically (resilience.faults.FaultPlan) and asserts the recovery,
 site by site:
 
 ==========================  =============================================
-``bench.probe``             total pool outage → structured FALLBACK
-                            artifact, rc=0 (never rc=124 / value-0.0)
-``bench.child``             pool drops mid-capture → FALLBACK, rc=0
 ``dist.rendezvous``         rank dies in the handshake → elastic restart
 ``collective.barrier``      UNAVAILABLE at the barrier → elastic restart
 ``launch.worker``           monitor SIGKILLs a rank → elastic restart
@@ -27,20 +23,16 @@ import signal
 import subprocess
 import sys
 import textwrap
-import time
 
 import numpy as np
 import pytest
 
 from pytorch_distributedtraining_tpu.resilience import (
-    CaptureMachine,
-    CaptureState,
     CircuitBreaker,
     FaultPlan,
     InjectedFault,
     OutageClass,
     RetryPolicy,
-    build_fallback_record,
     classify,
     classify_exception,
     install_plan,
@@ -48,7 +40,6 @@ from pytorch_distributedtraining_tpu.resilience import (
 from pytorch_distributedtraining_tpu.resilience.faults import fault_point
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(REPO, "bench.py")
 
 
 # ---------------------------------------------------------------------------
@@ -218,11 +209,11 @@ class TestFaultPlan:
 
     def test_times_zero_fires_forever(self):
         plan = FaultPlan.from_json(
-            {"faults": [{"site": "bench.probe", "times": 0}]}
+            {"faults": [{"site": "ckpt.write", "times": 0}]}
         )
         for _ in range(5):
             with pytest.raises(InjectedFault):
-                plan.point("bench.probe")
+                plan.point("ckpt.write")
 
     def test_rank_and_attempt_filters(self, monkeypatch):
         plan = FaultPlan.from_json({"faults": [
@@ -257,7 +248,7 @@ class TestFaultPlan:
         assert ei.value.errno == 5
 
     def test_from_env_inline_and_file(self, tmp_path, monkeypatch):
-        raw = '{"faults": [{"site": "bench.probe"}]}'
+        raw = '{"faults": [{"site": "ckpt.write"}]}'
         monkeypatch.setenv("GRAFT_FAULT_PLAN", raw)
         assert len(FaultPlan.from_env().rules) == 1
         f = tmp_path / "plan.json"
@@ -270,52 +261,14 @@ class TestFaultPlan:
     def test_install_plan_drives_fault_point(self):
         try:
             install_plan(FaultPlan.from_json(
-                {"faults": [{"site": "bench.probe", "message": "hi"}]}
+                {"faults": [{"site": "ckpt.write", "message": "hi"}]}
             ))
             with pytest.raises(InjectedFault, match="hi"):
-                fault_point("bench.probe")
-            fault_point("bench.probe")  # exhausted: no-op
+                fault_point("ckpt.write")
+            fault_point("ckpt.write")  # exhausted: no-op
         finally:
             install_plan(None)
-        fault_point("bench.probe")  # cleared: no-op
-
-
-# ---------------------------------------------------------------------------
-# capture machine + fallback artifact
-# ---------------------------------------------------------------------------
-
-
-class TestCaptureMachine:
-    def test_outage_ride_path(self):
-        m = CaptureMachine(clock=lambda: 0.0)
-        m.to(CaptureState.RIDE_OUTAGE, "probe failed")
-        m.to(CaptureState.RIDE_OUTAGE)  # re-entry is a no-op
-        m.to(CaptureState.CAPTURE, "window opened")
-        m.to(CaptureState.EMIT, "measured")
-        assert m.path() == ["PROBE", "RIDE_OUTAGE", "CAPTURE", "EMIT"]
-
-    def test_illegal_transitions_raise(self):
-        m = CaptureMachine()
-        m.to(CaptureState.CAPTURE)
-        with pytest.raises(ValueError, match="illegal capture transition"):
-            m.to(CaptureState.PROBE)
-        m.to(CaptureState.EMIT)
-        with pytest.raises(ValueError):
-            m.to(CaptureState.FALLBACK)
-
-    def test_fallback_record_carries_last_good(self):
-        rec = build_fallback_record(
-            metric="images_per_sec_per_chip", unit="images/sec/chip",
-            reason="pool dark", last_good={"value": 42.5, "vs_baseline": 1.1},
-            capture_path=["PROBE", "RIDE_OUTAGE", "FALLBACK", "EMIT"],
-        )
-        assert rec["provenance"] == "FALLBACK" and rec["measured"] is False
-        assert rec["value"] == 42.5 and rec["vs_baseline"] == 1.1
-        assert rec["fallback"]["capture_path"][-1] == "EMIT"
-
-    def test_fallback_record_without_last_good(self):
-        rec = build_fallback_record(metric="m", unit="u", reason="r")
-        assert rec["value"] == 0.0 and rec["provenance"] == "FALLBACK"
+        fault_point("ckpt.write")  # cleared: no-op
 
 
 # ---------------------------------------------------------------------------
@@ -562,115 +515,6 @@ def test_launcher_gives_up_on_deterministic_failure(tmp_path):
     assert "restarting cannot help" in proc.stderr
     with open(f"{marker}count") as fh:
         assert len(fh.readlines()) == 1  # exactly one generation ran
-
-
-# ---------------------------------------------------------------------------
-# chaos: bench capture pipeline (sites bench.probe, bench.child)
-# ---------------------------------------------------------------------------
-
-
-def _run_bench(env_extra, timeout_s):
-    env = dict(os.environ)
-    env.update(env_extra)
-    proc = subprocess.Popen(
-        [sys.executable, BENCH], env=env, cwd=REPO,
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
-    )
-    try:
-        out, err = proc.communicate(timeout=timeout_s)
-    except subprocess.TimeoutExpired:
-        proc.kill()
-        out, err = proc.communicate()
-        raise AssertionError(
-            f"bench.py outlived the test budget; tail:\n{out[-1500:]}"
-        )
-    return proc.returncode, out, err
-
-
-def _last_record(out):
-    for line in reversed(out.splitlines()):
-        line = line.strip()
-        if line.startswith("{"):
-            return json.loads(line)
-    raise AssertionError(f"no JSON record in output:\n{out[-2000:]}")
-
-
-_LAST_GOOD = {
-    "metric": "images_per_sec_per_chip",
-    "value": 123.4,
-    "unit": "images/sec/chip",
-    "vs_baseline": 1.23,
-}
-
-
-def test_total_pool_outage_emits_structured_fallback(tmp_path):
-    """THE acceptance path: every probe dies with an outage signature and
-    the budget drains — bench.py must exit 0 with a provenance-flagged
-    FALLBACK artifact carrying the last-good number, not rc=124 or a
-    value-0.0 error record."""
-    lg = tmp_path / "last_good.json"
-    lg.write_text(json.dumps(_LAST_GOOD))
-    t0 = time.time()
-    rc, out, _ = _run_bench(
-        {
-            "GRAFT_FAULT_PLAN": json.dumps({"faults": [
-                {"site": "bench.probe", "times": 0, "message":
-                 "UNAVAILABLE: TPU backend not found (injected outage)"},
-            ]}),
-            "GRAFT_BENCH_TOTAL": "30",
-            "GRAFT_BENCH_PROBE": "20",
-            "GRAFT_BENCH_PROBE_INTERVAL": "1",
-            "GRAFT_BENCH_RESERVE": "12",
-            "GRAFT_BENCH_ATTEMPTS": "1",
-            "GRAFT_BENCH_FALLBACK_CPU": "0",
-            "GRAFT_BENCH_LAST_GOOD": str(lg),
-        },
-        timeout_s=120,
-    )
-    rec = _last_record(out)
-    assert rc == 0, out[-1500:]
-    assert rec["provenance"] == "FALLBACK"
-    assert rec["measured"] is False
-    assert rec["value"] == 123.4            # last-good, flagged as such
-    assert rec["vs_baseline"] == 1.23
-    fb = rec["fallback"]
-    assert fb["last_good"]["value"] == 123.4
-    assert fb["outage"]["probes"] >= 1
-    assert "UNAVAILABLE" in fb["outage"]["last_tail"]
-    assert fb["capture_path"] == [
-        "PROBE", "RIDE_OUTAGE", "FALLBACK", "EMIT",
-    ]
-    assert time.time() - t0 < 60  # rides the budget, not the test suite
-
-
-def test_midcapture_outage_emits_fallback(tmp_path):
-    """Probe succeeds, then the pool drops mid-attempt: the attempt
-    loop's outage classification must degrade to FALLBACK (rc=0), not an
-    rc=1 error record."""
-    lg = tmp_path / "last_good.json"
-    lg.write_text(json.dumps(_LAST_GOOD))
-    rc, out, _ = _run_bench(
-        {
-            "GRAFT_FAULT_PLAN": json.dumps({"faults": [
-                {"site": "bench.child", "times": 0, "message":
-                 "UNAVAILABLE: TPU pool went away mid-capture (injected)"},
-            ]}),
-            "GRAFT_BENCH_PLATFORM": "cpu",  # probe passes off-TPU
-            "GRAFT_BENCH_TOTAL": "180",
-            "GRAFT_BENCH_PROBE": "90",
-            "GRAFT_BENCH_PROBE_INTERVAL": "1",
-            "GRAFT_BENCH_RESERVE": "30",
-            "GRAFT_BENCH_ATTEMPTS": "1",
-            "GRAFT_BENCH_FALLBACK_CPU": "0",
-            "GRAFT_BENCH_LAST_GOOD": str(lg),
-        },
-        timeout_s=150,
-    )
-    rec = _last_record(out)
-    assert rc == 0, out[-1500:]
-    assert rec["provenance"] == "FALLBACK"
-    assert rec["fallback"]["outage"]["phase"] == "capture"
-    assert "CAPTURE" in rec["fallback"]["capture_path"]
 
 
 # ---------------------------------------------------------------------------
