@@ -1,155 +1,168 @@
-"""Pallas flash attention vs XLA attention on hardware.
+"""GPT-2's attention core at the two cells' shapes, arm by arm, on the chip.
 
-Measures forward and forward+backward wall time for the framework's Pallas
-flash-attention kernels (`ops/pallas_attn.py`) against plain XLA attention
-(`models/gpt2.default_attention`) at GPT-2-class shapes, bf16, causal.
-Flash's win is O(T) HBM traffic (no [T,T] logits round trip), so the gap
-should widen with T. One JSON line per (T, impl, pass).
+What runs between ``c_attn`` and ``c_proj`` in ``gpt2-125m.train``
+(``qkv [12, 1024, 3 * 12 * 64]``) and on one chip of ``gpt2-xl.zero3-4chip``
+(``[4, 1024, 3 * 25 * 64]``), bf16, causal, forward and forward + backward.
+Every arm is a function of ``qkv`` as the projection wrote it, so the split,
+the transposes and the padded tiles an arm needs are inside its time:
+
+- XLA's einsums (``models/gpt2.default_attention``: T x T scores in HBM);
+- ``ops/pallas_attn.flash_attention`` over split heads, ``[B, H, T, 64]``
+  operands, at blocks of 128 / 256 / 512;
+- ``ops/pallas_attn.causal_attention_qkv``, what the default model runs:
+  two heads to a 128-lane block, read where ``c_attn`` wrote them (12 heads)
+  or copied behind a head of zeros first (25), at several blocks and at
+  the blocks it reads from T itself;
+- jax's shipped ``pallas.ops.tpu.flash_attention``.
+
+Each is held to float32 attention at 'highest' (``glm4_kernels``' reference)
+and prints one JSON line. ISSUE 30's decision gate reads these lines.
+
+    chiprun -- python3 benchmarks/attn_bench.py
+
+A call's time is the wall time of ``REPS`` calls enqueued back to back and
+waited for once, over ``REPS``: the calls take 0.5-6 ms, so the host stays
+ahead and the device's pace is what is read.
 """
 
 from __future__ import annotations
 
 import json
-import os
+import sys
 import time
 
-import numpy as np
-
 import _bootstrap  # noqa: F401  (repo root on sys.path)
-from _roofline import guard, verify_finite
+from glm4_kernels import attention_reference, rel_err
+
+REPS = 40
+SHAPES = {  # cell -> (sequences a chip, T, heads, head size)
+    "gpt2-125m.train": (12, 1024, 12, 64),
+    "gpt2-xl.zero3-4chip": (4, 1024, 25, 64),
+}
 
 
-def main():
+def paced(fn, *args):
+    import jax
+
+    out = fn(*args)
+    jax.block_until_ready(out)
+    t0 = time.perf_counter()
+    for _ in range(REPS):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return out, 1e3 * (time.perf_counter() - t0) / REPS
+
+
+def arms(heads, dh):
+    import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu import flash_attention as jfa
+
+    from pytorch_distributedtraining_tpu.models.gpt2 import default_attention
+    from pytorch_distributedtraining_tpu.ops import pallas_attn as pa
+
+    def split(qkv):
+        b, t, _ = qkv.shape
+        return (a.reshape(b, t, heads, dh) for a in jnp.split(qkv, 3, -1))
+
+    def flat(out):
+        return out.reshape(*out.shape[:2], heads * dh)
+
+    def shipped(block):
+        sizes = None if block is None else jfa.BlockSizes(
+            block_q=block, block_k_major=block, block_k=block, block_b=1,
+            block_q_major_dkv=block, block_k_major_dkv=block,
+            block_k_dkv=block, block_q_dkv=block,
+            block_k_major_dq=block, block_k_dq=block, block_q_dq=block,
+        )
+
+        def fn(qkv):
+            q, k, v = (a.transpose(0, 2, 1, 3) for a in split(qkv))
+            out = jfa.flash_attention(
+                q, k, v, causal=True, sm_scale=dh**-0.5, block_sizes=sizes
+            )
+            return flat(out.transpose(0, 2, 1, 3))
+
+        return fn
+
+    out = {
+        "xla default_attention (T x T scores)": lambda qkv: flat(
+            default_attention(*split(qkv), causal=True)
+        ),
+    }
+    for bq, bk in ((128, 128), (256, 256), (512, 512), (1024, 1024)):
+        out[f"flash_attention [B,H,T,{dh}] bq={bq} bk={bk}"] = (
+            lambda qkv, bq=bq, bk=bk: flat(
+                pa.flash_attention(*split(qkv), True, bq, bk, False)
+            )
+        )
+    how = "in place" if pa.packs(heads, dh) else "behind a head of zeros"
+    for blocks in (
+        (128, 128), (256, 256), (512, 512), (256, 512), (512, 256),
+        (1024, 1024), None,
+    ):
+        at = "its own blocks" if blocks is None else "bq=%d bk=%d" % blocks
+        out[f"causal_attention_qkv, heads packed {how}, {at}"] = (
+            lambda qkv, blocks=blocks: flat(
+                pa.causal_attention_qkv(qkv, heads, blocks=blocks)
+            )
+        )
+    out["jax flash_attention default blocks (128)"] = shipped(None)
+    out["jax flash_attention blocks 512"] = shipped(512)
+    return out
+
+
+def run(cell):
     import jax
     import jax.numpy as jnp
 
-    if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-        jax.config.update("jax_platforms", "cpu")
-    from pytorch_distributedtraining_tpu.runtime.cache import (
-        enable_compile_cache,
+    b, t, heads, dh = SHAPES[cell]
+    kq, kd = jax.random.split(jax.random.PRNGKey(0))
+    qkv = jax.random.normal(kq, (b, t, 3 * heads * dh), jnp.float32).astype(
+        jnp.bfloat16
     )
-
-    enable_compile_cache()
-
-    from pytorch_distributedtraining_tpu.models.gpt2 import default_attention
-    from pytorch_distributedtraining_tpu.ops.pallas_attn import flash_attention
-
-    B, H, D = 8, 12, 64
-    STEPS = int(os.environ.get("GRAFT_ATTN_STEPS", "50"))
-    platform = jax.devices()[0].platform
-    if platform not in ("cpu", "tpu"):
-        raise SystemExit(f"attn_bench supports cpu/tpu, got {platform}")
-    interpret = platform != "tpu"
-
-    def time_fn(fn, q, k, v):
-        # vary q per rep INSIDE one jitted program: every timed call is
-        # distinct work — at one dispatch per rep, like the real thing
-        wrapped = jax.jit(lambda e, q_, k_, v_: fn(q_ + e, k_, v_))
-        eps = [
-            jax.device_put(jnp.asarray((i + 1) * 1e-6, q.dtype))
-            for i in range(STEPS)
-        ]
-        out = wrapped(jnp.asarray(0, q.dtype), q, k, v)
-        jax.block_until_ready(out)
-        t0 = time.perf_counter()
-        for i in range(STEPS):
-            out = wrapped(eps[i], q, k, v)
-        jax.block_until_ready(out)
-        dt = (time.perf_counter() - t0) / STEPS
-        verify_finite(
-            float(jnp.asarray(jax.tree.leaves(out)[0]).ravel()[0]),
-            "attention output",
-        )
-        return dt
-
-    raw = os.environ.get("GRAFT_ATTN_SIZES", "512,1024,2048,4096")
-    try:
-        sizes = tuple(int(t) for t in raw.split(",") if t.strip())
-    except ValueError:
-        raise SystemExit(
-            f"GRAFT_ATTN_SIZES must be comma-separated ints, got {raw!r}"
-        )
-    if not sizes:
-        raise SystemExit("GRAFT_ATTN_SIZES parsed to no sizes")
-    for T in sizes:
-        rng = np.random.default_rng(0)
-        q, k, v = (
-            jnp.asarray(
-                rng.normal(size=(B, T, H, D)).astype(np.float32),
-                jnp.bfloat16,
+    do = jax.random.normal(kd, (b, t, heads * dh), jnp.float32).astype(
+        jnp.bfloat16
+    )
+    q, k, v = (a.reshape(b, t, heads, dh) for a in jnp.split(qkv, 3, -1))
+    ref_out, ref_grads = jax.jit(attention_reference)(
+        q, k, v, do.reshape(b, t, heads, dh)
+    )
+    ref_out = ref_out.reshape(b, t, heads * dh)
+    ref_grad = jnp.concatenate(
+        [g.reshape(b, t, heads * dh) for g in ref_grads], -1
+    )
+    scores = b * heads * t * t  # the full square, as PERF.md counts them
+    for name, fn in arms(heads, dh).items():
+        line = {"cell": cell, "arm": name, "shape": [b, t, heads, dh]}
+        try:
+            fwd = jax.jit(fn)
+            both = jax.jit(lambda qkv, do, fn=fn: jax.vjp(fn, qkv)[1](do)[0])
+            out, fwd_ms = paced(fwd, qkv)
+            grad, both_ms = paced(both, qkv, do)
+            line.update(
+                fwd_ms=fwd_ms, fwd_bwd_ms=both_ms,
+                fwd_ps_per_score=1e9 * fwd_ms / scores,
+                bwd_ps_per_score=1e9 * (both_ms - fwd_ms) / scores,
+                out_rel_err=rel_err(out, ref_out),
+                grad_rel_err=rel_err(grad, ref_grad),
             )
-            for _ in range(3)
-        )
+        except Exception as e:  # noqa: BLE001 - an arm that cannot compile is a reading
+            line["error"] = f"{type(e).__name__}: {str(e)[:400]}"
+        print(json.dumps(line), flush=True)
 
-        def xla_loss(q, k, v):
-            return jnp.sum(default_attention(q, k, v, causal=True)
-                           .astype(jnp.float32))
 
-        def flash_loss(q, k, v):
-            return jnp.sum(
-                flash_attention(q, k, v, True, 128, 128, interpret)
-                .astype(jnp.float32)
-            )
+def main(argv):
+    import jax
 
-        arms = {
-            ("xla", "fwd"): jax.jit(xla_loss),
-            ("flash", "fwd"): jax.jit(flash_loss),
-            ("xla", "fwd+bwd"): jax.jit(jax.grad(xla_loss, argnums=(0, 1, 2))),
-            ("flash", "fwd+bwd"): jax.jit(
-                jax.grad(flash_loss, argnums=(0, 1, 2))
-            ),
-        }
-
-        # correctness on this hardware first: fwd and
-        # grad outputs of the Pallas kernels vs XLA attention in bf16 (grad
-        # comparison reuses the timing arms' compiled programs). Gate hard:
-        # timing a wrong-math kernel must fail the bench, not decorate it.
-        o_xla = jax.jit(
-            lambda q, k, v: default_attention(q, k, v, causal=True)
-        )(q, k, v).astype(jnp.float32)
-        o_fl = jax.jit(
-            lambda q, k, v: flash_attention(q, k, v, True, 128, 128, interpret)
-        )(q, k, v).astype(jnp.float32)
-        g_xla = arms[("xla", "fwd+bwd")](q, k, v)
-        g_fl = arms[("flash", "fwd+bwd")](q, k, v)
-        gerr = max(
-            float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
-            for a, b in zip(g_xla, g_fl)
-        )
-        ferr = float(jnp.max(jnp.abs(o_xla - o_fl)))
-        print(json.dumps({
-            "T": T, "impl": "flash", "pass": "correctness",
-            "max_abs_err_fwd": round(ferr, 6),
-            "max_abs_err_grad": round(gerr, 6),
-        }), flush=True)
-        # bf16 rounding at these magnitudes is ~1e-2; a real kernel bug is
-        # orders of magnitude above these bounds
-        if ferr > 0.1 or gerr > 0.3:
-            raise SystemExit(
-                f"flash-vs-XLA mismatch at T={T}: fwd {ferr}, grad {gerr}"
-            )
-        for (impl, passes), fn in arms.items():
-            sec = time_fn(fn, q, k, v)
-            # attention flops: 2 matmuls * 2 flops * B*H*T^2*D (causal ~1/2)
-            flops = 2 * 2 * B * H * T * T * D * 0.5
-            if passes == "fwd+bwd":
-                # XLA bwd reuses stored probs (~2x fwd extra); flash bwd
-                # recomputes the forward in-kernel (~2.5x fwd extra)
-                flops *= 3.0 if impl == "xla" else 3.5
-            tflops = flops / sec / 1e12
-            # no v5e-class chip reaches 1 PFLOP/s bf16 (best sustained
-            # measurement here: 649 TFLOP/s) — a value
-            # above it means the timing loop broke, not a fast kernel
-            guard(
-                f"{impl}/{passes} T={T}", tflops, "TFLOP/s", 1000.0,
-                "1 PFLOP/s chip compute bound",
-            )
-            print(json.dumps({
-                "T": T, "impl": impl, "pass": passes,
-                "ms": round(sec * 1e3, 3),
-                "tflops": round(tflops, 2),
-            }), flush=True)
+    dev = jax.devices()[0]
+    print(json.dumps({"device": dev.device_kind, "platform": dev.platform}),
+          flush=True)
+    if dev.platform != "tpu":
+        raise SystemExit("attn_bench measures on a TPU, found none")
+    for cell in argv or SHAPES:
+        run(cell)
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main(sys.argv[1:]))

@@ -49,6 +49,8 @@ SHARDED_LOSS_TOL = 2e-2
 # kernel vs XLA at "highest" precision, relative to the reference's
 # largest magnitude; bf16 has eps 2^-8 and the backward chains three dots
 KERNEL_REL_TOL = {"float32": 2e-2, "bfloat16": 4e-2}
+# GPT-2's attention core on bf16 operands, as the cells run it (ISSUE 30)
+CORE_REL_TOL = 2e-2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +79,8 @@ class Sizes:
     window_shape: tuple = (18 * 64, 64, 180, 6)  # qkv [B*nW, n, 3c], heads
     window_mask_nw: int = 64
     flash_shape: tuple = (8, 1024, 12, 64)  # [B, T, H, Dh]
+    # the GPT-2 cells' attention cores, a chip's share: [B, T, H, Dh]
+    core_shapes: tuple = ((12, 1024, 12, 64), (4, 1024, 25, 64))
     kernels_interpret: bool = False
     matmul_n: int = 8192
     elementwise_bytes: int = 2 << 30
@@ -441,6 +445,7 @@ def phase_gpt2_train(sizes: Sizes) -> dict:
     import jax.numpy as jnp
 
     from pytorch_distributedtraining_tpu.models import GPT2, cross_entropy_loss
+    from pytorch_distributedtraining_tpu.models.gpt2 import default_attention
     from pytorch_distributedtraining_tpu.parallel import DDP
     from pytorch_distributedtraining_tpu.runtime.mesh import MeshSpec, make_mesh
 
@@ -449,7 +454,13 @@ def phase_gpt2_train(sizes: Sizes) -> dict:
     batch = gpt2_batch(sizes, model.cfg.vocab_size)
 
     # the reference, before step 0 donates the weights: a float32 forward
-    ref_model = GPT2(gpt2_config(sizes, dtype=jnp.float32))
+    # (the einsums by name: the default model's core on a TPU is the kernels)
+    ref_model = GPT2(
+        gpt2_config(sizes, dtype=jnp.float32), attn_fn=default_attention
+    )
+    kernels = require_attention_kernels(
+        step.compiled_text(state, batch), model.cfg.n_layer
+    )
     with jax.default_matmul_precision("highest"):
         ref_loss = float(jax.jit(
             lambda p, tok, tgt: cross_entropy_loss(
@@ -473,12 +484,14 @@ def phase_gpt2_train(sizes: Sizes) -> dict:
                  f"{model.cfg.n_layer}, tokens [{sizes.gpt2_batch}, "
                  f"{sizes.gpt2_seq}], bf16 policy, optim.adamw",
         **log.check_falls(), "losses": log.losses,
+        "attention_kernels_in_step": kernels,
         "step0_vs_float32_reference": {
             "reference": ref_loss, "abs_diff": diff, "tol": BF16_LOSS_TOL,
         },
         "checked": "TrainStep x%d on one seeded batch: loss finite and "
-                   "falling; step-0 loss == float32 'highest' forward within "
-                   "tol" % sizes.train_steps,
+                   "falling; step-0 loss == float32 'highest' forward (the "
+                   "einsums) within tol; on a TPU the compiled step holds the "
+                   "attention kernels, 3 a layer" % sizes.train_steps,
     }
 
 
@@ -564,7 +577,9 @@ def _rel_err(got, ref) -> dict:
     return {"max_abs_err": err, "ref_max": float(jnp.max(jnp.abs(ref)))}
 
 
-def _check_kernel(name, kernel, reference, args, dtype, interpret) -> dict:
+def _check_kernel(
+    name, kernel, reference, args, dtype, interpret, tol=None
+) -> dict:
     """Forward and gradient of ``kernel`` against ``reference`` (XLA at
     'highest' precision, float32) on the same device."""
     import jax
@@ -603,7 +618,7 @@ def _check_kernel(name, kernel, reference, args, dtype, interpret) -> dict:
             key=relative,
         ),
     }
-    tol = KERNEL_REL_TOL[jnp.dtype(dtype).name]
+    tol = tol or KERNEL_REL_TOL[jnp.dtype(dtype).name]
     for label, e in out.items():
         e["tol_rel"] = tol
         if not relative(e) <= tol:
@@ -616,7 +631,10 @@ def phase_kernels(sizes: Sizes) -> dict:
     import jax.numpy as jnp
 
     from pytorch_distributedtraining_tpu.models.gpt2 import default_attention
-    from pytorch_distributedtraining_tpu.ops.pallas_attn import flash_attention
+    from pytorch_distributedtraining_tpu.ops.pallas_attn import (
+        causal_attention_qkv,
+        flash_attention,
+    )
     from pytorch_distributedtraining_tpu.ops.pallas_window_attn import (
         window_attention_qkv,
     )
@@ -676,6 +694,36 @@ def phase_kernels(sizes: Sizes) -> dict:
             qkv, dtype, interpret,
         )
         out[label]["shape"] = list(sizes.flash_shape)
+
+    # What the default GPT-2 runs on a TPU, at the two cells' shapes, over
+    # ``qkv`` as ``c_attn`` writes it (12 heads pack two to a lane block, 25
+    # do not). The reference is written here: einsums over [B, H, T, Dh].
+    def core_ref(heads):
+        def ref(qkv):
+            b, t, d3 = qkv.shape
+            q, k, v = qkv.reshape(b, t, 3, heads, d3 // 3 // heads).transpose(
+                2, 0, 3, 1, 4
+            )
+            s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+            s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+            out = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+            return out.transpose(0, 2, 1, 3)
+        return ref
+
+    for b, t, heads, dh in sizes.core_shapes:
+        label = f"gpt2_core_{heads}_heads"
+        out[label] = _check_kernel(
+            label,
+            lambda qkv, heads=heads: causal_attention_qkv(
+                qkv, heads, interpret
+            ),
+            core_ref(heads),
+            (jax.random.normal(
+                keys[0], (b, t, 3 * heads * dh), jnp.float32
+            ).astype(jnp.bfloat16),),
+            jnp.bfloat16, interpret, tol=CORE_REL_TOL,
+        )
+        out[label]["shape"] = [b, t, heads, dh]
     out["checked"] = (
         "forward and gradient of each kernel vs XLA ('highest', float32) on "
         "the same device; compiled program holds a tpu_custom_call"
@@ -777,6 +825,21 @@ def require_collectives(counts: dict, *, gathers: bool) -> None:
         raise AssertionError(f"collectives missing from the step: {counts}")
 
 
+def require_attention_kernels(text: str, n_layer: int) -> int:
+    """A GPT-2 step compiled for a TPU runs its attention core as Mosaic
+    kernels: forward, dq and dk/dv a layer (unrolled). A CPU rehearsal holds
+    the einsums and none."""
+    import jax
+
+    found = text.count('custom_call_target="tpu_custom_call"')
+    want = 3 * n_layer if jax.devices()[0].platform == "tpu" else 0
+    if found != want:
+        raise AssertionError(
+            f"{found} Mosaic kernels in the compiled step, expected {want}"
+        )
+    return found
+
+
 def compare_losses(a: list, b: list, tol: float) -> dict:
     diffs = [abs(x - y) for x, y in zip(a, b)]
     if len(a) != len(b) or not a or max(diffs) > tol:
@@ -801,8 +864,13 @@ def phase_fsdp4_gpt2(sizes: Sizes) -> dict:
             info["opt_state"] = shard_table(
                 state.opt_state, mesh, "fsdp4_opt_state"
             )
-            info["collectives"] = hlo.counts(step.compiled_text(state, batch))
+            text = step.compiled_text(state, batch)
+            info["collectives"] = hlo.counts(text)
             require_collectives(info["collectives"], gathers=True)
+            # each device runs the kernels over its own sequences
+            info["attention_kernels_in_step"] = require_attention_kernels(
+                text, model.cfg.n_layer
+            )
             for part in ("params", "opt_state"):
                 if not info[part]["leaves_sharded"]:
                     raise AssertionError(f"ZeRO-3 sharded no {part} leaf")
@@ -823,7 +891,8 @@ def phase_fsdp4_gpt2(sizes: Sizes) -> dict:
         **info, "losses": compare_losses(losses4, losses1, SHARDED_LOSS_TOL),
         "checked": "3 steps on 4 devices == same step, seed and global batch "
                    "on 1 device within tol; every sharded leaf spread over "
-                   "the devices; gathers and reductions in the HLO",
+                   "the devices; gathers, reductions and (on a TPU) the "
+                   "attention kernels in the HLO",
     }
 
 

@@ -7,9 +7,15 @@ transformer, pre-LN, learned positional embeddings, tied LM head.
 
 TPU-native choices:
   - [B, T, D] activations, fused QKV projection — one big MXU matmul.
-  - ``attn_fn`` is pluggable: default is XLA softmax attention (fused by the
-    compiler); `ops.pallas_attn.flash_attention` or
-    `ops.ring_attention.ring_attention` slot in for long context / sp.
+  - the attention core of a training block is decided from what the
+    program can observe when no ``attn_fn`` is named: the blockwise kernels
+    of `ops/pallas_attn.py` (no T x T scores in HBM) where the program is
+    lowered for a TPU, the shapes meet the kernels' contract and the step's
+    mesh is known; XLA's einsums (``default_attention``) everywhere else,
+    a CPU included. ``attn_fn=default_attention`` names the einsums,
+    ``ops.make_flash_attn_fn(...)`` the kernel or an error,
+    `ops.ring_attention.make_ring_attn_fn` slots in for sp. Each trace says
+    which it took in the instant ``attention.path``.
   - Param layout is Megatron-friendly under pjit: sharding the QKV/MLP-in
     kernels on the output dim and proj/MLP-out on the input dim over "tp"
     yields the classic two-allreduce-per-block pattern from XLA, no manual
@@ -18,15 +24,19 @@ TPU-native choices:
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
+from functools import partial
 from typing import Callable, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import PartitionSpec as P
 
-from ..parallel.spec import pin_batch
+from ..parallel.spec import pin_batch, published_batch_mesh
+from ..runtime.mesh import data_axes
 from ..precision import fp8_dot_general_cls
 from .generate import (
     kv_scale_block,
@@ -139,6 +149,81 @@ def default_attention(q, k, v, *, causal: bool = True):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def _split_heads(qkv, heads):
+    """``[B, T, 3 * H * dh]`` as ``c_attn`` wrote it -> q, k, v
+    ``[B, T, H, dh]``."""
+    b, t, d3 = qkv.shape
+    return tuple(
+        a.reshape(b, t, heads, d3 // 3 // heads)
+        for a in jnp.split(qkv, 3, axis=-1)
+    )
+
+
+@partial(jax.jit, static_argnames=("heads", "mesh"))
+def _kernel_or_einsum_attention(qkv, heads, mesh=None):
+    """The causal core between the projections, ``qkv [B, T, 3 * H * dh]``
+    -> ``[B, T, H, dh]``: the blockwise kernels where the program is lowered
+    for a TPU, ``default_attention`` on any other platform. One traced
+    program serves both and only the branch of the platform it is lowered
+    for is compiled. Jitted so that a model's layers, which call it with the
+    same shapes, share one trace of both branches (trace and lowering are
+    paid in every run's set-up, compile cache or not).
+
+    The partitioner cannot split a Mosaic kernel and refuses a program that
+    spans devices with one in it. Given the step's ``mesh``, each device
+    runs the core over its own sequences (``shard_map`` over the data axes)."""
+    from ..ops.pallas_attn import causal_attention_qkv
+
+    def core(qkv):
+        return jax.lax.platform_dependent(
+            qkv,
+            tpu=partial(causal_attention_qkv, heads=heads),
+            default=lambda qkv: default_attention(
+                *_split_heads(qkv, heads), causal=True
+            ),
+        )
+
+    if mesh is None:
+        return core(qkv)
+    rows = P(data_axes(mesh))
+    return jax.shard_map(
+        core, mesh=mesh, in_specs=rows, out_specs=rows,
+        check_vma=False,  # a pallas_call says nothing of varying axes
+    )(qkv)
+
+
+def _kernel_placement(b, t, heads, dh, dtype):
+    """Where a training block that was given no ``attn_fn`` may place the
+    kernels: ``(mesh, None)``, with the mesh to split the batch over or None
+    for a program on one device, or ``(None, why not)`` in words. A program
+    that spans devices takes them only where the step has published its
+    mesh (``spec.batch_layout``) and every axis of it splits the batch."""
+    from ..ops.pallas_attn import kernel_contract
+
+    why = kernel_contract(t, heads, dh, dtype)
+    if why is not None:
+        return None, why
+    mesh = published_batch_mesh()
+    if mesh is None:
+        if jax.device_count() == 1:
+            return None, None
+        return None, (
+            f"{jax.device_count()} devices and no step has published the "
+            "batch's layout: the program may span them, and the partitioner "
+            "cannot split a kernel"
+        )
+    if mesh.size == 1:
+        return None, None
+    shards = math.prod(mesh.shape[a] for a in data_axes(mesh))
+    if shards != mesh.size:
+        return None, (
+            f"the mesh {dict(mesh.shape)} has axes that do not split the "
+            "batch: heads or sequence may be split there"
+        )
+    if b % shards:
+        return None, f"{b} sequences do not split over {shards} devices"
+    return mesh, None
+
 
 class Block(nn.Module):
     """Pre-LN transformer block: LN → attn → +res, LN → MLP → +res.
@@ -157,7 +242,9 @@ class Block(nn.Module):
     """
 
     cfg: GPT2Config
-    attn_fn: AttnFn = default_attention
+    # the training path's attention core; None decides from the shapes, the
+    # platform the program is lowered for and the step's mesh (``_decide``)
+    attn_fn: Optional[AttnFn] = None
     decode: bool = False
     # scan-body mode: return (x, None) so the block slots into nn.scan
     as_scan_body: bool = False
@@ -242,6 +329,39 @@ class Block(nn.Module):
             k_scales=ks.value, v_scales=vs.value,
         )
 
+    def _decide(self, qkv):
+        """With no ``attn_fn`` named: may the blockwise kernels compute this
+        block's core, and placed over which mesh (None: one device)?
+        Decided from what can be seen, the mode, the shapes and the
+        published mesh now (the platform when the program is lowered:
+        ``_kernel_or_einsum_attention``), and said at every trace."""
+        from ..observe import trace
+        from ..ops.pallas_attn import attention_blocks
+
+        heads = self.cfg.n_head
+        b, t, d3 = qkv.shape
+        dh = d3 // 3 // heads
+        mesh = None
+        if self.decode:
+            why = "decode=True: the step attends the " + (
+                "KV cache" if self.paged is None else "page pool"
+            )
+        else:
+            mesh, why = _kernel_placement(b, t, heads, dh, qkv.dtype)
+        kernels = why is None
+        if kernels:
+            why = "shapes meet the kernels' contract" + (
+                "" if mesh is None else
+                f"; each of the mesh's {mesh.size} devices its own sequences"
+            )
+        bq, bk = attention_blocks(t) if kernels else (None, None)
+        trace.instant(
+            "attention.path", path="by_platform" if kernels else "einsum",
+            reason=why, b=b, t=t, heads=heads, dh=dh, bq=bq, bk=bk,
+            mesh=1 if mesh is None else mesh.size,
+        )
+        return kernels, mesh
+
     @nn.compact
     def __call__(self, x, deterministic: bool = True, start_index=None,
                  page_table=None, lengths=None):
@@ -259,23 +379,29 @@ class Block(nn.Module):
 
         y = nn.LayerNorm(epsilon=1e-5, dtype=cfg.dtype, name="ln_1")(x)
         qkv = dense(3 * d, "c_attn")(y)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        reshape = lambda a: a.reshape(*a.shape[:2], h, d // h)  # noqa: E731
         # the attention core (score, mask, softmax, value product; not the
-        # projections, which Flax names) under one scope, whatever
-        # ``attn_fn``: metadata only, so a profile gives attention's share
+        # projections, which Flax names) under one scope, whatever computes
+        # it: metadata only, so a profile gives attention's share
         with jax.named_scope("attention"):
-            q, k, v = reshape(q), reshape(k), reshape(v)
-            if self.decode and self.paged is not None:
-                y = self._paged_attention(q, k, v, page_table, lengths)
-            elif self.decode:
-                y = self._cached_attention(
-                    q, k, v,
-                    jnp.zeros((), jnp.int32)
-                    if start_index is None else start_index,
-                )
+            kernels, mesh = (
+                self._decide(qkv) if self.attn_fn is None else (False, None)
+            )
+            if kernels:
+                y = _kernel_or_einsum_attention(qkv, h, mesh)
             else:
-                y = self.attn_fn(q, k, v, causal=True)
+                q, k, v = _split_heads(qkv, h)
+                if not self.decode:
+                    y = (self.attn_fn or default_attention)(
+                        q, k, v, causal=True
+                    )
+                elif self.paged is not None:
+                    y = self._paged_attention(q, k, v, page_table, lengths)
+                else:
+                    y = self._cached_attention(
+                        q, k, v,
+                        jnp.zeros((), jnp.int32)
+                        if start_index is None else start_index,
+                    )
         # named-remat tag (parallel/remat.py "names"/"offload" policies):
         # save the softmax·V product, recompute the cheap projections
         y = checkpoint_name(y, "attn_out")
@@ -310,7 +436,11 @@ class GPT2(nn.Module):
     """
 
     cfg: GPT2Config = GPT2Config()
-    attn_fn: AttnFn = default_attention
+    # None: each training block decides (``Block._decide``: the blockwise
+    # kernels on a TPU where shapes and mesh allow, else the einsums);
+    # ``default_attention`` names the einsums, ``make_flash_attn_fn(...)``
+    # the kernel or an error
+    attn_fn: Optional[AttnFn] = None
     decode: bool = False
     paged: tuple | None = None  # (num_pages, page_size); needs decode=True
     # quantized page residency (with ``paged``): resolved WireFormat whose

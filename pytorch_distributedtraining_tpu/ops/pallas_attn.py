@@ -21,15 +21,30 @@ skipping applies in all three kernels (upper-triangular tiles never run).
 ``make_flash_attn_fn`` returns a drop-in ``attn_fn`` for the model zoo.
 The kernels compile for the TPU or raise; CPU tests exercise the same code
 by asking for ``interpret=True`` themselves.
+
+``causal_attention_qkv`` is the core a model runs when its caller names
+none (`models/gpt2.py`): it takes ``qkv [B, T, 3 * H * dh]`` as the fused
+projection wrote it and reads its blocks from the shapes. Where heads pack
+into 128 lanes (two heads of 64, one of 128) the kernels read q, k and v
+straight out of ``qkv`` a lane block at a time and write ``out`` and
+``d qkv`` the same way: no ``[B, T, H, dh] <-> [B, H, T, dh]`` transpose, no
+half-filled 64-lane tile, a head taken out of its block by a lane mask
+(``(q * m_h) k^T`` contracts over all 128 lanes: the MXU pass a contraction
+of 64 costs), the causal mask paid on the diagonal blocks only. Where the
+count is odd (GPT-2 XL: 25 heads, k starts at column 1,600 = 12.5 blocks)
+q, k and v are first copied into thirds of their own with a head of zeros
+behind. ``kernel_contract`` says which shapes it takes.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas import tpu as pltpu
 
 _BIG_NEG = -1e30
@@ -362,3 +377,451 @@ def make_flash_attn_fn(
         return flash_attention(q, k, v, causal, bq, bk, interpret)
 
     return attn_fn
+
+
+# ---------------------------------------------------------------------------
+# The core on ``qkv`` as the fused projection wrote it: heads packed into
+# 128-lane blocks, causal.
+# ---------------------------------------------------------------------------
+
+_LANES = 128
+_NT = (((1,), (1,)), ((), ()))  # a @ b^T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+_TN = (((0,), (0,)), ((), ()))  # a^T @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _each_head(hb, dh, one_head, init):
+    """``one_head(h, mask, carry) -> carry`` over the ``hb`` heads of a lane
+    block, ``mask [1, 128]`` saying which columns are head ``h``'s (None for
+    a block that is one head). A loop in the program, not in Python: a
+    kernel's body is traced, lowered and compiled once whatever ``hb`` (its
+    trace is most of what the core adds to a run's set-up)."""
+    if hb == 1:
+        return one_head(0, None, init)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+
+    def step(h, carry):
+        return one_head(h, (lane >= h * dh) & (lane < (h + 1) * dh), carry)
+
+    return jax.lax.fori_loop(0, hb, step, init)
+
+
+def _only(mask, x):
+    """``x`` with the other heads' columns zeroed: a product over all 128
+    lanes is then this head's alone."""
+    return x if mask is None else jnp.where(mask, x, jnp.zeros_like(x))
+
+
+def _merge(mask, x, into):
+    """This head's columns of ``x`` written into ``into``."""
+    return x if mask is None else jnp.where(mask, x, into)
+
+
+def _folds(scale):
+    """A power of two multiplies bf16 operands exactly: the scale then goes
+    onto a [block, 128] operand once instead of onto every score."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _causal(s, row0, col0):
+    bq, bk = s.shape
+    qpos = row0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    kpos = col0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    return jnp.where(kpos <= qpos, s, _BIG_NEG)
+
+
+def _key_blocks(qi, bq, bk):
+    """Key blocks a query block sees whole (no mask needed), and where its
+    last visible one ends: only the blocks on the diagonal pay for a mask."""
+    return (qi * bq + 1) // bk, ((qi + 1) * bq + bk - 1) // bk
+
+
+def _query_blocks(ki, bq, bk, nq):
+    """For a key block: the first query block that sees any of it, and the
+    first that sees all of it."""
+    return (ki * bk) // bq, jnp.minimum(nq, ((ki + 1) * bk + bq - 2) // bq)
+
+
+def _packed_fwd_kernel(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, *, bq, bk, hb, dh, scale
+):
+    qi = pl.program_id(2)
+    q = q_ref[0]  # [bq, 128]: hb heads side by side
+    whole, end = _key_blocks(qi, bq, bk)
+
+    def one_head(h, mask, out):
+        q_h = _only(mask, q)
+        if _folds(scale):
+            q_h = q_h * jnp.asarray(scale, q_h.dtype)
+
+        def body(j, carry, masked):
+            acc, m, l = carry
+            at = pl.ds(pl.multiple_of(j * bk, bk), bk)
+            k, v = k_ref[0, at, :], v_ref[0, at, :]
+            s = _dot(q_h, k, _NT)  # [bq, bk]
+            if not _folds(scale):
+                s = s * scale
+            if masked:
+                s = _causal(s, qi * bq, j * bk)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1))
+            corr = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[:, None])
+            l = l * corr + jnp.sum(p, axis=1)
+            # [bq, 128]: this head's columns are its product, the others'
+            # are dropped at the merge
+            acc = acc * corr[:, None] + _dot(p.astype(v.dtype), v, _NN)
+            return acc, m_new, l
+
+        carry = (
+            jnp.zeros((bq, _LANES), jnp.float32),
+            jnp.full((bq,), _BIG_NEG, jnp.float32),
+            jnp.zeros((bq,), jnp.float32),
+        )
+        carry = jax.lax.fori_loop(
+            0, whole, functools.partial(body, masked=False), carry
+        )
+        acc, m, l = jax.lax.fori_loop(
+            whole, end, functools.partial(body, masked=True), carry
+        )
+        lse_ref[0, h] = jnp.broadcast_to(
+            (m + jnp.log(l))[:, None], (bq, _STAT_LANES)
+        )
+        return _merge(mask, acc / l[:, None], out)
+
+    out = _each_head(
+        hb, dh, one_head, jnp.zeros((bq, _LANES), jnp.float32)
+    )
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _packed_dq_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+    *, bq, bk, hb, dh, scale,
+):
+    qi = pl.program_id(2)
+    q, do = q_ref[0], do_ref[0]  # [bq, 128]
+    whole, end = _key_blocks(qi, bq, bk)
+
+    def one_head(h, mask, out):
+        q_h, do_h = _only(mask, q), _only(mask, do)
+        if _folds(scale):
+            q_h = q_h * jnp.asarray(scale, q_h.dtype)
+        lse = lse_ref[0, h, :, 0]  # [bq]
+        delta = delta_ref[0, h, :, 0]
+
+        def body(j, dq, masked):
+            at = pl.ds(pl.multiple_of(j * bk, bk), bk)
+            k, v = k_ref[0, at, :], v_ref[0, at, :]
+            s = _dot(q_h, k, _NT)
+            if not _folds(scale):
+                s = s * scale
+            if masked:
+                s = _causal(s, qi * bq, j * bk)
+            p = jnp.exp(s - lse[:, None])  # masked entries -> 0
+            ds = p * (_dot(do_h, v, _NT) - delta[:, None])
+            return dq + _dot(ds.astype(k.dtype), k, _NN)
+
+        dq = jax.lax.fori_loop(
+            0, whole, functools.partial(body, masked=False),
+            jnp.zeros((bq, _LANES), jnp.float32),
+        )
+        dq = jax.lax.fori_loop(
+            whole, end, functools.partial(body, masked=True), dq
+        )
+        return _merge(mask, dq * scale, out)
+
+    out = _each_head(
+        hb, dh, one_head, jnp.zeros((bq, _LANES), jnp.float32)
+    )
+    dq_ref[0] = out.astype(dq_ref.dtype)
+
+
+def _packed_dkv_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+    *, bq, bk, hb, dh, scale,
+):
+    ki = pl.program_id(2)
+    k, v = k_ref[0], v_ref[0]  # [bk, 128]
+    nq = q_ref.shape[1] // bq
+    first, whole = _query_blocks(ki, bq, bk, nq)
+
+    def one_head(h, mask, outs):
+        k_h, v_h = _only(mask, k), _only(mask, v)
+        if _folds(scale):
+            k_h = k_h * jnp.asarray(scale, k_h.dtype)
+
+        def body(i, carry, masked):
+            dk, dv = carry
+            at = pl.ds(pl.multiple_of(i * bq, bq), bq)
+            q, do = q_ref[0, at, :], do_ref[0, at, :]  # every head's columns
+            s = _dot(q, k_h, _NT)  # [bq, bk]: k_h's zeros leave this head's
+            if not _folds(scale):
+                s = s * scale
+            if masked:
+                s = _causal(s, i * bq, ki * bk)
+            p = jnp.exp(s - lse_ref[0, h, at, 0][:, None])
+            dv = dv + _dot(p.astype(do.dtype), do, _TN)  # [bk, 128]
+            ds = p * (_dot(do, v_h, _NT) - delta_ref[0, h, at, 0][:, None])
+            dk = dk + _dot(ds.astype(q.dtype), q, _TN)
+            return dk, dv
+
+        carry = jax.lax.fori_loop(
+            first, whole, functools.partial(body, masked=True),
+            (jnp.zeros((bk, _LANES), jnp.float32),) * 2,
+        )
+        dk, dv = jax.lax.fori_loop(
+            whole, nq, functools.partial(body, masked=False), carry
+        )
+        return _merge(mask, dk * scale, outs[0]), _merge(mask, dv, outs[1])
+
+    dk, dv = _each_head(
+        hb, dh, one_head, (jnp.zeros((bk, _LANES), jnp.float32),) * 2
+    )
+    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+
+
+def _lane_specs(first):
+    """Block specs on a grid (batch, lane block, sequence block) for arrays
+    ``[B, T, lanes]`` whose lane block ``first + p`` belongs to grid column
+    ``p``, a tile of ``rows`` a step or the whole sequence; and for the
+    per-row statistics ``[B, H, T, 8]`` of the ``hb`` heads of a block."""
+    def lanes(rows, whole=False):
+        return pl.BlockSpec(
+            (1, rows, _LANES),
+            (lambda b, p, i: (b, 0, first + p)) if whole
+            else (lambda b, p, i: (b, i, first + p)),
+        )
+    return lanes
+
+
+def _stat_specs(hb):
+    def stats(rows, whole=False):
+        return pl.BlockSpec(
+            (1, hb, rows, _STAT_LANES),
+            (lambda b, p, i: (b, p, 0, 0)) if whole
+            else (lambda b, p, i: (b, p, i, 0)),
+        )
+    return stats
+
+
+_KERNEL_STATICS = ("first", "n", "dh", "bq", "bk", "interpret")
+
+
+# jitted: autodiff asks for a kernel more than once (the primal and the
+# custom-vjp forward; the backward at linearisation and at transposition),
+# and tracing a kernel's body is most of what the core adds to a run's set-up
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
+def _packed_forward(qkv, first, n, dh, bq, bk, interpret):
+    """``qkv``: the arrays q, k and v are read from (one array three times
+    where the fused projection wrote them side by side), ``first``: the lane
+    block each starts at, ``n``: lane blocks of heads. Returns ``out
+    [B, T, n * 128]`` and ``lse [B, n * hb, T, 8]``."""
+    b, t, _ = qkv[0].shape
+    hb = _LANES // dh
+    q_at, k_at, v_at = (_lane_specs(f) for f in first)
+    out_at, stats = _lane_specs(0), _stat_specs(hb)
+    return pl.pallas_call(
+        functools.partial(
+            _packed_fwd_kernel, bq=bq, bk=bk, hb=hb, dh=dh, scale=dh**-0.5
+        ),
+        grid=(b, n, t // bq),
+        in_specs=[q_at(bq), k_at(t, whole=True), v_at(t, whole=True)],
+        out_specs=[out_at(bq), stats(bq)],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, t, n * _LANES), qkv[0].dtype),
+            jax.ShapeDtypeStruct((b, n * hb, t, _STAT_LANES), jnp.float32),
+        ],
+        compiler_params=_vmem_params(
+            "forward", t, _LANES, bq, bk, qkv[0].dtype,
+            whole=2, tiles=2, stat_rows=hb * bq,
+        ),
+        interpret=interpret,
+        name="flash_packed_fwd",
+    )(*qkv)
+
+
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
+def _packed_backward(qkv, first, n, dh, out, lse, do, bq, bk, interpret):
+    """-> ``(dq, dk, dv)``, each ``[B, T, n * 128]``."""
+    b, t, _ = qkv[0].shape
+    hb = _LANES // dh
+    # delta_i = dO_i . O_i per head: one elementwise pass, lane-broadcast
+    # like lse (see _STAT_LANES)
+    delta = jnp.sum(
+        (do.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
+            b, t, n * hb, dh
+        ),
+        axis=-1,
+    ).transpose(0, 2, 1)
+    delta = jnp.broadcast_to(delta[..., None], (b, n * hb, t, _STAT_LANES))
+    kernel = dict(bq=bq, bk=bk, hb=hb, dh=dh, scale=dh**-0.5)
+    third = jax.ShapeDtypeStruct((b, t, n * _LANES), qkv[0].dtype)
+    q_at, k_at, v_at = (_lane_specs(f) for f in first)
+    own, stats = _lane_specs(0), _stat_specs(hb)  # out, d out, d q / k / v
+
+    dq = pl.pallas_call(
+        functools.partial(_packed_dq_kernel, **kernel),
+        grid=(b, n, t // bq),
+        in_specs=[
+            q_at(bq), k_at(t, whole=True), v_at(t, whole=True), own(bq),
+            stats(bq), stats(bq),
+        ],
+        out_specs=own(bq),
+        out_shape=third,
+        compiler_params=_vmem_params(
+            "dq", t, _LANES, bq, bk, qkv[0].dtype,
+            whole=2, tiles=3, stat_rows=2 * hb * bq,
+        ),
+        interpret=interpret,
+        name="flash_packed_dq",
+    )(*qkv, do, lse, delta)
+
+    dk, dv = pl.pallas_call(
+        functools.partial(_packed_dkv_kernel, **kernel),
+        grid=(b, n, t // bk),
+        in_specs=[
+            q_at(t, whole=True), k_at(bk), v_at(bk), own(t, whole=True),
+            stats(t, whole=True), stats(t, whole=True),
+        ],
+        out_specs=[own(bk), own(bk)],
+        out_shape=[third, third],
+        compiler_params=_vmem_params(
+            "dk/dv", t, _LANES, bq, bk, qkv[0].dtype,
+            whole=2, tiles=4, stat_rows=2 * hb * t,
+        ),
+        interpret=interpret,
+        name="flash_packed_dkv",
+    )(*qkv, do, lse, delta)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def flash_attention_qkv(qkv, heads, bq, bk, interpret=False):
+    """Causal flash attention over ``qkv [B, T, 3 * H * dh]`` as the fused
+    projection wrote it -> ``[B, T, H * dh]``, for heads that fill 128-lane
+    blocks (``packs``): q, k and v are read where they lie."""
+    return _fwd_qkv(qkv, heads, bq, bk, interpret)[0]
+
+
+def _in_place(qkv, heads):
+    n = qkv.shape[-1] // 3 // _LANES
+    return (qkv,) * 3, (0, n, 2 * n), n, qkv.shape[-1] // 3 // heads
+
+
+def _fwd_qkv(qkv, heads, bq, bk, interpret):
+    out, lse = _packed_forward(*_in_place(qkv, heads), bq, bk, interpret)
+    return out, (qkv, out, lse)
+
+
+def _bwd_qkv(heads, bq, bk, interpret, res, g):
+    qkv, out, lse = res
+    grads = _packed_backward(
+        *_in_place(qkv, heads), out, lse, g, bq, bk, interpret
+    )
+    return (jnp.concatenate(grads, axis=-1),)
+
+
+flash_attention_qkv.defvjp(_fwd_qkv, _bwd_qkv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def flash_attention_lanes(q, k, v, dh, bq, bk, interpret=False):
+    """Causal flash attention over q, k, v ``[B, T, n * 128]``, heads of
+    ``dh`` side by side in the lanes, -> ``[B, T, n * 128]``: the layout a
+    head count that does not fill its last block is padded into."""
+    return _fwd_lanes(q, k, v, dh, bq, bk, interpret)[0]
+
+
+def _fwd_lanes(q, k, v, dh, bq, bk, interpret):
+    n = q.shape[-1] // _LANES
+    out, lse = _packed_forward(
+        (q, k, v), (0, 0, 0), n, dh, bq, bk, interpret
+    )
+    return out, (q, k, v, out, lse)
+
+
+def _bwd_lanes(dh, bq, bk, interpret, res, g):
+    q, k, v, out, lse = res
+    n = q.shape[-1] // _LANES
+    return _packed_backward(
+        (q, k, v), (0, 0, 0), n, dh, out, lse, g, bq, bk, interpret
+    )
+
+
+flash_attention_lanes.defvjp(_fwd_lanes, _bwd_lanes)
+
+
+def packs(heads, dh):
+    """Whether whole heads fill 128-lane blocks of ``qkv``'s thirds: q, k
+    and v then start on a block each and ``flash_attention_qkv`` reads them
+    where they lie."""
+    return _LANES % dh == 0 and heads % (_LANES // dh) == 0
+
+
+def attention_blocks(t):
+    """(bq, bk) the core runs a sequence of ``t`` in: the largest of 512,
+    256, 128 that divides it, or None where none does. On the v5e at
+    T = 1,024 and heads of 64, forward + backward, blocks of 512 took 2.8 ms
+    against 4.0 at 256 and 6.7 at 128 (a larger block skips less of the
+    causal square and still wins: fewer, fuller loop steps), 1,024 no less
+    (my chip run, PR 30: `PERF.md` §6)."""
+    return next(((b, b) for b in (512, 256, 128) if t % b == 0), None)
+
+
+def kernel_contract(t, heads, dh, dtype):
+    """None where ``causal_attention_qkv`` takes these shapes, else why it
+    does not, in words."""
+    name = jnp.dtype(dtype).name
+    if name not in ("bfloat16", "float32"):
+        return f"dtype {name} is neither bfloat16 nor float32"
+    if dh not in (64, 128):
+        return f"head size {dh} does not fill 128 lanes by ones or twos"
+    blocks = attention_blocks(t)
+    if blocks is None:
+        return f"T={t} is not a multiple of the smallest block, 128"
+    try:
+        _vmem_params(
+            "dk/dv", t, _LANES, *blocks, dtype,
+            whole=2, tiles=4, stat_rows=2 * (_LANES // dh) * t,
+        )
+    except ValueError as e:
+        return str(e)
+    return None
+
+
+def causal_attention_qkv(qkv, heads, interpret=False, blocks=None):
+    """The causal attention core between the two projections:
+    ``qkv [B, T, 3 * H * dh]`` -> ``[B, T, H, dh]``, blocks ``(bq, bk)`` read
+    from the shapes unless given. Heads that fill 128-lane blocks are read
+    out of ``qkv`` where they lie; an odd count of heads of 64 (GPT-2 XL's
+    25: k starts half way into a block) is copied into thirds of their own
+    with one head of zeros behind, which costs a pass over ``qkv``. Raises
+    where ``kernel_contract`` refuses the shapes."""
+    b, t, d3 = qkv.shape
+    d = d3 // 3
+    dh = d // heads
+    why = kernel_contract(t, heads, dh, qkv.dtype)
+    if why is not None:
+        raise ValueError(f"causal_attention_qkv: {why}")
+    bq, bk = blocks or attention_blocks(t)
+    if packs(heads, dh):
+        out = flash_attention_qkv(qkv, heads, bq, bk, interpret)
+    else:
+        # ``qkv`` is held T-minor here, the layout XLA gives it anyway where
+        # ``c_attn``'s kernel is sharded (ZeRO-3: a matmul over gathered
+        # column slices, each landing whole in its rows). A pad alone hands
+        # the kernels' row-major layout back through to that matmul, which
+        # then lands every slice at a lane offset of 1,200:
+        # gpt2-xl.zero3-4chip lost in `c_attn` what the kernels won (+59 ms
+        # a step of `dynamic-update-slice`; my chip runs, PR 30). The copy
+        # below is then the one transpose.
+        qkv = with_layout_constraint(qkv, Layout(major_to_minor=(0, 2, 1)))
+        to_block = [(0, 0), (0, 0), (0, -d % _LANES)]
+        q, k, v = (jnp.pad(a, to_block) for a in jnp.split(qkv, 3, axis=-1))
+        out = flash_attention_lanes(q, k, v, dh, bq, bk, interpret)[..., :d]
+    return out.reshape(b, t, heads, dh)

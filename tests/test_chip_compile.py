@@ -74,6 +74,10 @@ def _compile(fn, *args):
 WINDOW = (18 * 64, 64, 180, 6)
 WINDOW_M = (18 * 64, 64, 540, 6)
 FLASH = (8, 1024, 12, 64)  # GPT-2 125M: [B, T, H, Dh]
+# the GPT-2 cells' cores: 12 heads pack two to a lane block, 25 do not
+CORE_125M, CORE_XL, CORE_HEAD_128 = (
+    (12, 1024, 12, 64), (4, 1024, 25, 64), (4, 1024, 8, 128)
+)
 # GLM-4.7-Flash's cell: MLA's head of 192 + 64, blocks of 512; K and V ride
 # whole in VMEM (2 MiB each), over the default scoped limit in the backward
 FLASH_MLA, FLASH_MLA_BLOCK = (2, 4096, 20, 256), 512
@@ -120,6 +124,21 @@ def _flash(dev, *, dtype, grad, shape=FLASH, block=128):
         ),
         (qkv, qkv, qkv),
     )
+
+
+def _causal_qkv(dev, *, shape, grad):
+    """The core the default GPT-2 runs on a TPU, over ``qkv`` as ``c_attn``
+    wrote it: heads that pack into 128 lanes are read where they lie."""
+    from pytorch_distributedtraining_tpu.ops.pallas_attn import (
+        causal_attention_qkv,
+    )
+
+    b, t, heads, dh = shape
+    qkv = _on(dev, (b, t, 3 * heads * dh), jnp.bfloat16)
+    fwd = lambda qkv: causal_attention_qkv(qkv, heads)  # noqa: E731
+    if not grad:
+        return fwd, (qkv,)
+    return jax.grad(lambda x: jnp.sum(fwd(x).astype(jnp.float32))), (qkv,)
 
 
 def _grouped(dev, *, grad):
@@ -239,6 +258,18 @@ KERNEL_CASES = {
     "flash_bwd_bf16_mla_head_256": (
         lambda d: _flash(d, dtype=jnp.bfloat16, grad=True, shape=FLASH_MLA,
                          block=FLASH_MLA_BLOCK), True,
+    ),
+    "core_qkv_fwd_gpt2_125m": (
+        lambda d: _causal_qkv(d, shape=CORE_125M, grad=False), True,
+    ),
+    "core_qkv_bwd_gpt2_125m": (
+        lambda d: _causal_qkv(d, shape=CORE_125M, grad=True), True,
+    ),
+    "core_qkv_bwd_gpt2_xl_25_heads": (
+        lambda d: _causal_qkv(d, shape=CORE_XL, grad=True), True,
+    ),
+    "core_qkv_bwd_head_128": (
+        lambda d: _causal_qkv(d, shape=CORE_HEAD_128, grad=True), True,
     ),
     "grouped_matmul_fwd": (lambda d: _grouped(d, grad=False), True),
     "grouped_matmul_bwd": (lambda d: _grouped(d, grad=True), True),
@@ -403,9 +434,13 @@ def test_gpt2_125m_train_step_one_chip(topo):
     step, state, batch = _gpt2_step(
         _mesh(topo, 1, dp=1), DDP(), GPT2Config.gpt2_125m()
     )
-    compiled, _ = _lower(step, state, batch)
+    compiled, text = _lower(step, state, batch)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+    # the default model's core on a TPU is the blockwise kernels: a forward
+    # and two backward (dq, dk/dv) a layer, and no T x T scores in HBM
+    assert _kernel_calls(text) == 3 * 12
+    assert _largest_scores(text) is None
 
 
 @pytest.mark.slow
@@ -428,7 +463,10 @@ def test_gpt2_125m_zero3_on_four_chips(topo):
     gathered = _gathered_shapes(text)
     assert {(768, 2304), (768, 3072)} <= gathered, gathered
     assert not [s for s in gathered if s[0] == 8], gathered
-    assert _largest_scores(text) == (2, 12, 1024, 1024)
+    # each chip runs the kernels over its own 2 of the 8 sequences, placed
+    # by shard_map over the mesh the step published
+    assert _kernel_calls(text) == 3 * 12
+    assert _largest_scores(text) is None
 
 
 def _gathered_shapes(text):
@@ -445,20 +483,30 @@ def _gathered_shapes(text):
 
 
 def _largest_scores(text):
-    """The largest [B, H, T, T] tensor a device holds (XLA also slices its
-    own sequences apart to prefetch them: smaller leading dimensions)."""
+    """The largest [B, H, T, T] tensor a device holds, or None where the
+    program holds none (the attention core is the blockwise kernels)."""
     return max(
-        tuple(int(d) for d in dims.split(","))
-        for dims in re.findall(r"\bb?f\d+\[(\d+,\d+,1024,1024)\]", text)
+        (
+            tuple(int(d) for d in dims.split(","))
+            for dims in re.findall(r"\bb?f\d+\[(\d+,\d+,1024,1024)\]", text)
+        ),
+        default=None,
     )
+
+
+def _kernel_calls(text):
+    """Mosaic kernels in a compiled program (a scanned body's count once)."""
+    return len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text))
 
 
 @pytest.mark.slow
 def test_gpt2_xl_zero3_at_the_cells_sizes(topo):
     """``gpt2-xl.zero3-4chip`` as the benchmark runs it (16 x 1,024, scan +
-    remat): each chip holds the scores of its own 4 sequences and all 25
-    heads, gathers one scanned layer's kernels at a time, and plans 4.5 GB
-    of temporaries (7.6 GB while the batch was gathered instead)."""
+    remat): each chip runs the attention kernels over its own 4 sequences
+    and all 25 heads (forward, the forward again in the rematerialised
+    backward, dq, dk/dv: four calls in the scanned bodies), gathers one
+    scanned layer's kernels at a time, and plans under 4.5 GB of
+    temporaries (7.6 GB while the batch was gathered instead)."""
     from pytorch_distributedtraining_tpu.models import GPT2Config
     from pytorch_distributedtraining_tpu.parallel import ZeRO3
 
@@ -467,8 +515,16 @@ def test_gpt2_xl_zero3_at_the_cells_sizes(topo):
     )
     step, state, batch = _gpt2_step(_mesh(topo, fsdp=4), ZeRO3(), cfg, batch=16)
     compiled, text = _lower(step, state, batch)
-    assert compiled.memory_analysis().temp_size_in_bytes < 5.5e9
-    assert _largest_scores(text) == (4, 25, 1024, 1024)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
+    assert _kernel_calls(text) == 4
+    assert _largest_scores(text) is None
+    # c_attn's sharded matmul lands its gathered column slices in a T-minor
+    # ``qkv``; row-major (what the kernels' operands would hand back through
+    # a pad alone) they land at lane offsets of 1,200: 59 ms a step
+    landed = set(re.findall(
+        r"bf16\[4,1024,4800\]\{([0-9,]+)[^ ]* dynamic-update-slice\(", text
+    ))
+    assert landed == {"1,2,0"}, landed
     gathered = _gathered_shapes(text)
     assert {(1600, 4800), (1600, 6400), (1600, 1600)} <= gathered, gathered
     assert not [s for s in gathered if s[0] == 16], gathered
