@@ -10,7 +10,11 @@ BASELINE ladder (BASELINE.json): ResNet-18/50, GPT-2 125M, ViT-B/16.
 
 Beyond both: ``MoEMLP`` (a Switch layer over "ep", ``.moe``: imported from
 its module) and ``Glm4MoeLite`` (GLM-4.7-Flash: latent attention, a
-sigmoid-routed dropless expert layer that is told which experts it holds).
+sigmoid-routed dropless expert layer that is told which experts it holds)
+and ``SmallThinker`` (SmallThinker-21BA3B: window and position-free full
+layers 3:1 over grouped-query heads, a softmax router that reads the layer's
+raw input; the held-experts layer is one piece of code for both,
+``.held_experts``).
 
 All models are Flax linen modules in NHWC (images) / [B, T, D] (sequences) —
 the layouts XLA:TPU tiles best — with bf16-friendly parameterization.
@@ -38,6 +42,8 @@ _LAZY = {
     "cross_entropy_loss": ".gpt2",
     "Glm4MoeLite": ".glm4_moe_lite",
     "Glm4MoeLiteConfig": ".glm4_moe_lite",
+    "SmallThinker": ".smallthinker",
+    "SmallThinkerConfig": ".smallthinker",
     "ViT": ".vit",
     "ViTConfig": ".vit",
     "ViTB16": ".vit",

@@ -47,18 +47,18 @@ from dataclasses import dataclass
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops.grouped_matmul import grouped_matmul
 from ..ops.pallas_attn import make_flash_attn_fn
 from ..parallel.spec import pin_batch
 from .gpt2 import AttnFn
+from .held_experts import (  # noqa: F401 (this module's names too)
+    MOE_COUNTERS, MOE_PROBE, expert_loads, held_experts_sum,
+    routing_counters, sow_probe,
+)
 from .scan_utils import remat_block
 
 ROUTER_STATE = "router_state"  # the selection bias, one [E] vector a layer
-MOE_COUNTERS = "moe_counters"  # what the routing did this call
-MOE_PROBE = "moe_probe"  # an expert layer's input, scores, picks, output
 # bq = bk of ops/pallas_attn.py (cut to T below it): at [2, 4096, 20, 256]
 # bf16 on a v5e 4.19 | 14.06 ms forward | forward + backward, against XLA's
 # T x T attention 7.30 | 20.13 with 2.7 GB of scores (PERF.md, PR 27)
@@ -220,50 +220,6 @@ class MLA(nn.Module):
         return self.o_proj(out.reshape(*out.shape[:2], -1))
 
 
-@jax.custom_vjp
-def spread_rows(tokens, order, slot):
-    """``tokens`` [N, D] -> one row an assignment, in sorted order: row ``r``
-    is the token of assignment ``order[r]`` (a token has k = M / N
-    assignments, ``n * k .. n * k + k - 1``). ``slot`` is ``order``'s
-    inverse. The gradient of a gather is a scatter-add, which XLA:TPU runs
-    at a fraction of a gather's pace; since every row is read by exactly
-    one assignment, the gradient is a gather too (by ``slot``, summed over
-    a token's k rows), and is written as one."""
-    return tokens[order // (order.shape[0] // tokens.shape[0])]
-
-
-def _spread_fwd(tokens, order, slot):
-    return spread_rows(tokens, order, slot), (slot, tokens.shape[0])
-
-
-def _spread_bwd(res, g):
-    slot, n = res
-    per_token = g[slot].reshape(n, -1, g.shape[-1]).astype(jnp.float32)
-    return jnp.sum(per_token, 1).astype(g.dtype), None, None
-
-
-spread_rows.defvjp(_spread_fwd, _spread_bwd)
-
-
-@jax.custom_vjp
-def collect_rows(rows, slot, order):
-    """``rows`` [M, D] in sorted order -> in assignment order (``rows[slot]``);
-    ``slot`` and ``order`` are inverse permutations, so the gradient is the
-    gather by ``order`` (see :func:`spread_rows`)."""
-    return rows[slot]
-
-
-def _collect_fwd(rows, slot, order):
-    return rows[slot], order
-
-
-def _collect_bwd(order, g):
-    return g[order], None, None
-
-
-collect_rows.defvjp(_collect_fwd, _collect_bwd)
-
-
 def route(scores, bias, cfg: Glm4MoeLiteConfig):
     """``scores`` [N, E] float32 (sigmoid), ``bias`` [E] -> the chosen
     experts [N, k] and their weights [N, k]: the bias selects (``noaux_tc``
@@ -287,9 +243,7 @@ class ExpertLayer(nn.Module):
     def __call__(self, x):
         cfg = self.cfg
         b, t, d = x.shape
-        n, k, e = b * t, cfg.num_experts_per_tok, cfg.n_routed_experts
-        held = cfg.held
-        n_held, f = len(held), cfg.moe_intermediate_size
+        n, e, f = b * t, cfg.n_routed_experts, cfg.moe_intermediate_size
         tokens = x.reshape(n, d)
         init = nn.initializers.normal(cfg.initializer_range)
 
@@ -309,7 +263,7 @@ class ExpertLayer(nn.Module):
                 precision=jax.lax.Precision.HIGHEST,
             ))
             sel, weights = route(scores, bias, cfg)
-            load = jnp.zeros((e,), jnp.float32).at[sel.reshape(-1)].add(1.0)
+            load = expert_loads(sel, e)
             if bias_var is not None and not self.is_initializing() and (
                 self.is_mutable_collection(ROUTER_STATE)
             ):
@@ -317,58 +271,13 @@ class ExpertLayer(nn.Module):
                     jnp.mean(load) - load
                 )
 
-        with jax.named_scope("dispatch"):
-            # every assignment gets a row: sorted by held expert, the
-            # assignments to absent experts last (group ``n_held``)
-            local = np.full((e,), n_held, np.int32)
-            local[list(held)] = np.arange(n_held)
-            group = jnp.asarray(local)[sel.reshape(-1)]  # [N * k]
-            order = jnp.argsort(group, stable=True).astype(jnp.int32)
-            slot = jnp.zeros((n * k,), jnp.int32).at[order].set(
-                jnp.arange(n * k, dtype=jnp.int32)
-            )  # assignment -> its row: order's inverse
-            here = group[order] < n_held  # rows that exist
-            rows = load[jnp.asarray(held)].astype(jnp.int32)  # a held expert
-            xs = jnp.where(here[:, None], spread_rows(tokens, order, slot), 0)
-
-        with jax.named_scope("experts"):
-            w_gate = self.param("experts_gate", init, (n_held, d, f))
-            w_up = self.param("experts_up", init, (n_held, d, f))
-            w_down = self.param("experts_down", init, (n_held, f, d))
-            gmm = lambda a, w: grouped_matmul(  # noqa: E731
-                a, w, rows, interpret=self.interpret
-            )
-            ys = gmm(nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up), w_down)
-
-        with jax.named_scope("combine"):
-            mine = (group < n_held).reshape(n, k)
-            # masked before the product: a row no expert wrote is unwritten
-            parts = jnp.where(
-                mine[..., None],
-                collect_rows(ys, slot, order).reshape(n, k, d), 0,
-            ).astype(jnp.float32)
-            routed = jnp.sum(weights[..., None] * parts, 1).astype(cfg.dtype)
-
-        with jax.named_scope("shared_expert"):
-            shared = GatedMLP(
-                cfg, cfg.n_shared_experts * f, name="mlp_shared"
-            )(tokens)
-
-        landed = jnp.sum(mine)
-        for name, value in (
-            ("rows_max", jnp.max(rows)), ("rows_mean", jnp.mean(rows)),
-            ("landed", landed), ("dropped", landed - jnp.sum(rows)),
-            ("active", jnp.sum(rows > 0)),
-        ):
-            self.sow(MOE_COUNTERS, name, value.astype(jnp.float32),
-                     reduce_fn=lambda _, new: new, init_fn=lambda: None)
-        out = routed + shared
-        for name, value in (
-            ("input", tokens), ("scores", scores), ("picks", sel),
-            ("output", out),
-        ):
-            self.sow(MOE_PROBE, name, value,
-                     reduce_fn=lambda _, new: new, init_fn=lambda: None)
+        out = held_experts_sum(
+            self, tokens, sel, weights, load, held=cfg.held, width=f,
+            gate=nn.silu, init=init, dtype=cfg.dtype,
+            interpret=self.interpret,
+            shared=GatedMLP(cfg, cfg.n_shared_experts * f, name="mlp_shared"),
+        )
+        sow_probe(self, input=tokens, scores=scores, picks=sel, output=out)
         return out.reshape(b, t, d)
 
 
@@ -440,23 +349,3 @@ class Glm4MoeLite(nn.Module):
                 preferred_element_type=jnp.float32,
             )
             return pin_batch(logits)
-
-
-def routing_counters(counters: dict) -> dict:
-    """The ``moe_counters`` collection of one call, over its expert layers,
-    as the scalars a step reports: rows per held expert (the fullest
-    expert's, and the mean), assignments that landed here, held experts
-    that got any (both summed over the layers), assignments dropped (0: the
-    buffer covers the worst case)."""
-    layers = [v["moe"] for _, v in sorted(counters.items()) if "moe" in v]
-    pick = lambda name: jnp.stack([c[name] for c in layers])  # noqa: E731
-    return {
-        "expert_rows_max": jnp.max(pick("rows_max")),
-        "expert_rows_mean": jnp.mean(pick("rows_mean")),
-        "expert_load_max_over_mean": jnp.mean(
-            pick("rows_max") / jnp.maximum(pick("rows_mean"), 1e-9)
-        ),
-        "assignments_landed": jnp.sum(pick("landed")),
-        "experts_active": jnp.sum(pick("active")),
-        "dropped_assignments": jnp.sum(pick("dropped")),
-    }

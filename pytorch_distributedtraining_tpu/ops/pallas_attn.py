@@ -64,7 +64,64 @@ _VMEM_DEFAULT = 16 * 2**20
 _STAT_LANES = 8
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, bq, bk, causal, scale):
+def _visible(s, qi, ki, window):
+    """Scores ``s [bq, bk]`` of query block ``qi`` and key block ``ki`` with
+    what a query may not see set to ``_BIG_NEG``: keys after it and, under a
+    ``window``, keys more than ``window - 1`` before it."""
+    bq, bk = s.shape
+    qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    keep = kpos <= qpos
+    if window is not None:
+        keep = keep & (kpos > qpos - window)
+    return jnp.where(keep, s, _BIG_NEG)
+
+
+def _band_key_blocks(qi, bq, bk, window, nk):
+    """Under a window: the key blocks ``[lo, end)`` query block ``qi`` sees
+    any of, and ``[a, b)`` inside them that every one of its queries sees
+    whole. Only the band's edges (the window's side ``[lo, a)``, the
+    diagonal's ``[b, end)``) pay for a mask; blocks outside never run."""
+    row0 = qi * bq
+    end = jnp.minimum(nk, (row0 + bq + bk - 1) // bk)
+    lo = jnp.maximum(row0 - window + 1, 0) // bk
+    a = jnp.clip(
+        (jnp.maximum(row0 + bq - window, 0) + bk - 1) // bk, lo, end
+    )
+    b = jnp.clip((row0 + 1) // bk, a, end)
+    return lo, a, b, end
+
+
+def _band_query_blocks(ki, bq, bk, window, nq):
+    """Under a window: the query blocks ``[first, end)`` that see any of key
+    block ``ki``, and ``[a, b)`` inside them that see all of it."""
+    col0 = ki * bk
+    first = col0 // bq
+    end = jnp.minimum(nq, (col0 + bk + window - 2) // bq + 1)
+    a = jnp.clip((col0 + bk + bq - 2) // bq, first, end)
+    b = jnp.clip((col0 + window) // bq, a, end)
+    return first, a, b, end
+
+
+def _over_band(blocks, body, init):
+    """``body(i, carry, masked)`` over a band's masked edge, its whole
+    blocks and its other masked edge."""
+    lo, a, b, end = blocks
+    carry = jax.lax.fori_loop(
+        lo, a, functools.partial(body, masked=True), init
+    )
+    carry = jax.lax.fori_loop(
+        a, b, functools.partial(body, masked=False), carry
+    )
+    return jax.lax.fori_loop(
+        b, end, functools.partial(body, masked=True), carry
+    )
+
+
+def _fwd_kernel(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, *, bq, bk, causal, scale,
+    window=None,
+):
     qi = pl.program_id(2)
     # operands stay in the caller's dtype (bf16 feeds the MXU at full
     # rate; an f32 matmul takes several passes), products accumulate in f32
@@ -73,7 +130,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, bq, bk, causal, scale):
     dh = q.shape[-1]
     nk = t // bk
 
-    def body(j, carry):
+    def body(j, carry, masked=causal):
         acc, m, l = carry
         k = k_ref[0, 0, pl.ds(j * bk, bk), :]
         v = v_ref[0, 0, pl.ds(j * bk, bk), :]
@@ -81,10 +138,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, bq, bk, causal, scale):
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [bq, bk]
-        if causal:
-            qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(kpos <= qpos, s, _BIG_NEG)
+        if masked:
+            s = _visible(s, qi, j, window)
         m_new = jnp.maximum(m, jnp.max(s, axis=1))
         corr = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new[:, None])
@@ -99,8 +154,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, bq, bk, causal, scale):
     m0 = jnp.full((bq,), _BIG_NEG, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
     # causal: blocks with j*bk > (qi+1)*bq - 1 are fully masked; skip them
-    nk_run = jnp.minimum(nk, (qi + 1) * bq // bk + 1) if causal else nk
-    acc, m, l = jax.lax.fori_loop(0, nk_run, body, (acc0, m0, l0))
+    if window is None:
+        nk_run = jnp.minimum(nk, (qi + 1) * bq // bk + 1) if causal else nk
+        acc, m, l = jax.lax.fori_loop(0, nk_run, body, (acc0, m0, l0))
+    else:
+        acc, m, l = _over_band(
+            _band_key_blocks(qi, bq, bk, window, nk), body, (acc0, m0, l0)
+        )
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0, 0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
     # per-row logsumexp of scaled logits, lane-broadcast (see _STAT_LANES)
@@ -111,7 +171,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, bq, bk, causal, scale):
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-    *, bq, bk, causal, scale,
+    *, bq, bk, causal, scale, window=None,
 ):
     qi = pl.program_id(2)
     q = q_ref[0, 0]  # [bq, dh]
@@ -121,17 +181,15 @@ def _dq_kernel(
     t = k_ref.shape[2]
     nk = t // bk
 
-    def body(j, dq):
+    def body(j, dq, masked=causal):
         k = k_ref[0, 0, pl.ds(j * bk, bk), :]
         v = v_ref[0, 0, pl.ds(j * bk, bk), :]
         s = scale * jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [bq, bk]
-        if causal:
-            qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(kpos <= qpos, s, _BIG_NEG)
+        if masked:
+            s = _visible(s, qi, j, window)
         p = jnp.exp(s - lse[:, None])  # [bq, bk], masked entries -> 0
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -143,16 +201,22 @@ def _dq_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    nk_run = jnp.minimum(nk, (qi + 1) * bq // bk + 1) if causal else nk
-    dq = jax.lax.fori_loop(
-        0, nk_run, body, jnp.zeros((bq, q.shape[-1]), jnp.float32)
-    )
+    if window is None:
+        nk_run = jnp.minimum(nk, (qi + 1) * bq // bk + 1) if causal else nk
+        dq = jax.lax.fori_loop(
+            0, nk_run, body, jnp.zeros((bq, q.shape[-1]), jnp.float32)
+        )
+    else:
+        dq = _over_band(
+            _band_key_blocks(qi, bq, bk, window, nk), body,
+            jnp.zeros((bq, q.shape[-1]), jnp.float32),
+        )
     dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    *, bq, bk, causal, scale,
+    *, bq, bk, causal, scale, window=None,
 ):
     ki = pl.program_id(2)
     k = k_ref[0, 0]  # [bk, dh]
@@ -161,7 +225,7 @@ def _dkv_kernel(
     dh = k.shape[-1]
     nq = t // bq
 
-    def body(i, carry):
+    def body(i, carry, masked=causal):
         dk, dv = carry
         q = q_ref[0, 0, pl.ds(i * bq, bq), :]
         do = do_ref[0, 0, pl.ds(i * bq, bq), :]
@@ -171,10 +235,8 @@ def _dkv_kernel(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [bq, bk]
-        if causal:
-            qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(kpos <= qpos, s, _BIG_NEG)
+        if masked:
+            s = _visible(s, i, ki, window)
         p = jnp.exp(s - lse[:, None])  # [bq, bk]
         dv = dv + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -193,11 +255,17 @@ def _dkv_kernel(
 
     # causal: q tiles strictly above the diagonal band never attend this
     # key tile — start at the first row tile whose end reaches ki*bk
-    i0 = (ki * bk) // bq if causal else 0
-    dk, dv = jax.lax.fori_loop(
-        i0, nq, body,
-        (jnp.zeros((bk, dh), jnp.float32), jnp.zeros((bk, dh), jnp.float32)),
-    )
+    if window is None:
+        i0 = (ki * bk) // bq if causal else 0
+        dk, dv = jax.lax.fori_loop(
+            i0, nq, body,
+            (jnp.zeros((bk, dh), jnp.float32), jnp.zeros((bk, dh), jnp.float32)),
+        )
+    else:
+        dk, dv = _over_band(
+            _band_query_blocks(ki, bq, bk, window, nq), body,
+            (jnp.zeros((bk, dh), jnp.float32), jnp.zeros((bk, dh), jnp.float32)),
+        )
     dk_ref[0, 0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
@@ -235,10 +303,43 @@ def _vmem_params(what, t, dh, bq, bk, dtype, *, whole, tiles, stat_rows):
     )
 
 
-def _flash_forward(q, k, v, *, causal, bq, bk, interpret):
+def _kv_head(group, whole):
+    """Index map of a key / value block on a grid (batch, QUERY head, block):
+    query head ``h`` reads key-value head ``h // group``, a tile a step or
+    the whole sequence. No copy of k or v is made for the group; steps that
+    share a key-value head find its block already in VMEM."""
+    if group == 1:  # today's maps, today's program
+        return (
+            (lambda b_, h_, i: (b_, h_, 0, 0)) if whole
+            else (lambda b_, h_, i: (b_, h_, i, 0))
+        )
+    return (
+        (lambda b_, h_, i: (b_, h_ // group, 0, 0)) if whole
+        else (lambda b_, h_, i: (b_, h_ // group, i, 0))
+    )
+
+
+def _check_heads(q, k, causal, window):
+    h, kvh = q.shape[2], k.shape[2]
+    if h % kvh:
+        raise ValueError(
+            f"flash_attention: {h} query heads do not divide over {kvh} "
+            "key-value heads"
+        )
+    if window is not None and not (causal and window >= 1):
+        raise ValueError(
+            "flash_attention: a window (>= 1) is the causal one: query t "
+            "sees keys t - window + 1 .. t"
+        )
+    return h // kvh
+
+
+def _flash_forward(q, k, v, *, causal, bq, bk, interpret, window=None):
     """Returns (out, lse) in the caller's [B, T, H, Dh] layout for out and
-    [B, H, T, _STAT_LANES] (lane-broadcast) for lse."""
+    [B, H, T, _STAT_LANES] (lane-broadcast) for lse. ``k`` and ``v`` may
+    have fewer heads than ``q`` (a divisor of its count)."""
     b, t, h, dh = q.shape
+    group = _check_heads(q, k, causal, window)
     bq, bk = min(bq, t), min(bk, t)
     _check_blocks(t, bq, bk)
     scale = 1.0 / (dh**0.5)
@@ -247,13 +348,14 @@ def _flash_forward(q, k, v, *, causal, bq, bk, interpret):
     grid = (b, h, t // bq)
     out, lse = pl.pallas_call(
         functools.partial(
-            _fwd_kernel, bq=bq, bk=bk, causal=causal, scale=scale
+            _fwd_kernel, bq=bq, bk=bk, causal=causal, scale=scale,
+            window=window,
         ),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, dh), lambda b_, h_, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, t, dh), lambda b_, h_, i: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, t, dh), lambda b_, h_, i: (b_, h_, 0, 0)),
+            pl.BlockSpec((1, 1, t, dh), _kv_head(group, whole=True)),
+            pl.BlockSpec((1, 1, t, dh), _kv_head(group, whole=True)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, dh), lambda b_, h_, i: (b_, h_, i, 0)),
@@ -273,8 +375,11 @@ def _flash_forward(q, k, v, *, causal, bq, bk, interpret):
     return out.transpose(0, 2, 1, 3), lse
 
 
-def _flash_backward(q, k, v, out, lse, do, *, causal, bq, bk, interpret):
+def _flash_backward(
+    q, k, v, out, lse, do, *, causal, bq, bk, interpret, window=None
+):
     b, t, h, dh = q.shape
+    group = _check_heads(q, k, causal, window)
     bq, bk = min(bq, t), min(bk, t)
     _check_blocks(t, bq, bk)
     scale = 1.0 / (dh**0.5)
@@ -293,6 +398,8 @@ def _flash_backward(q, k, v, out, lse, do, *, causal, bq, bk, interpret):
     tile_q = pl.BlockSpec((1, 1, bq, dh), lambda b_, h_, i: (b_, h_, i, 0))
     tile_k = pl.BlockSpec((1, 1, bk, dh), lambda b_, h_, i: (b_, h_, i, 0))
     full_seq = pl.BlockSpec((1, 1, t, dh), lambda b_, h_, i: (b_, h_, 0, 0))
+    full_kv = pl.BlockSpec((1, 1, t, dh), _kv_head(group, whole=True))
+    tile_kv = pl.BlockSpec((1, 1, bk, dh), _kv_head(group, whole=False))
     row_q = pl.BlockSpec(
         (1, 1, bq, _STAT_LANES), lambda b_, h_, i: (b_, h_, i, 0)
     )
@@ -302,10 +409,11 @@ def _flash_backward(q, k, v, out, lse, do, *, causal, bq, bk, interpret):
 
     dq = pl.pallas_call(
         functools.partial(
-            _dq_kernel, bq=bq, bk=bk, causal=causal, scale=scale
+            _dq_kernel, bq=bq, bk=bk, causal=causal, scale=scale,
+            window=window,
         ),
         grid=(b, h, t // bq),
-        in_specs=[tile_q, full_seq, full_seq, tile_q, row_q, row_q],
+        in_specs=[tile_q, full_kv, full_kv, tile_q, row_q, row_q],
         out_specs=tile_q,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         compiler_params=_vmem_params(
@@ -314,22 +422,34 @@ def _flash_backward(q, k, v, out, lse, do, *, causal, bq, bk, interpret):
         interpret=interpret,
     )(qt, kt, vt, dot_, lse, delta)
 
+    # one program a QUERY head: where a key-value head serves a group, each
+    # of its query heads writes its own part of dk and dv (float32) and one
+    # pass sums the group's
+    part = k.dtype if group == 1 else jnp.float32
     dk, dv = pl.pallas_call(
         functools.partial(
-            _dkv_kernel, bq=bq, bk=bk, causal=causal, scale=scale
+            _dkv_kernel, bq=bq, bk=bk, causal=causal, scale=scale,
+            window=window,
         ),
         grid=(b, h, t // bk),
-        in_specs=[full_seq, tile_k, tile_k, full_seq, row_full, row_full],
+        in_specs=[full_seq, tile_kv, tile_kv, full_seq, row_full, row_full],
         out_specs=[tile_k, tile_k],
         out_shape=[
-            jax.ShapeDtypeStruct(kt.shape, k.dtype),
-            jax.ShapeDtypeStruct(vt.shape, v.dtype),
+            jax.ShapeDtypeStruct(qt.shape[:3] + kt.shape[3:], part),
+            jax.ShapeDtypeStruct(qt.shape[:3] + vt.shape[3:], part),
         ],
         compiler_params=_vmem_params(
             "dk/dv", t, dh, bq, bk, q.dtype, whole=2, tiles=4, stat_rows=2 * t
         ),
         interpret=interpret,
     )(qt, kt, vt, dot_, lse, delta)
+    if group > 1:
+        dk, dv = (
+            jnp.sum(a.reshape(b, h // group, group, t, -1), axis=2).astype(
+                k.dtype
+            )
+            for a in (dk, dv)
+        )
     return (
         dq.transpose(0, 2, 1, 3),
         dk.transpose(0, 2, 1, 3),
@@ -337,30 +457,35 @@ def _flash_backward(q, k, v, out, lse, do, *, causal, bq, bk, interpret):
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(
     q, k, v, causal: bool = True, bq: int = 128, bk: int = 128,
-    interpret: bool = False,
+    interpret: bool = False, window: int | None = None,
 ):
-    """Flash attention. q/k/v: [B, T, H, Dh] -> [B, T, H, Dh]."""
+    """Flash attention. q: [B, T, H, Dh], k/v: [B, T, KVH, Dh] with KVH a
+    divisor of H (query head h reads key-value head h // (H / KVH)) ->
+    [B, T, H, Dh]. ``window``: query t sees keys t - window + 1 .. t and
+    the kernels run the band's blocks only; None is the causal triangle."""
     out, _ = _flash_forward(
-        q, k, v, causal=causal, bq=bq, bk=bk, interpret=interpret
+        q, k, v, causal=causal, bq=bq, bk=bk, interpret=interpret,
+        window=window,
     )
     return out
 
 
-def _fwd(q, k, v, causal, bq, bk, interpret):
+def _fwd(q, k, v, causal, bq, bk, interpret, window):
     out, lse = _flash_forward(
-        q, k, v, causal=causal, bq=bq, bk=bk, interpret=interpret
+        q, k, v, causal=causal, bq=bq, bk=bk, interpret=interpret,
+        window=window,
     )
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, bq, bk, interpret, res, g):
+def _bwd(causal, bq, bk, interpret, window, res, g):
     q, k, v, out, lse = res
     return _flash_backward(
         q, k, v, out, lse, g, causal=causal, bq=bq, bk=bk,
-        interpret=interpret,
+        interpret=interpret, window=window,
     )
 
 
@@ -373,8 +498,8 @@ def make_flash_attn_fn(
     """Drop-in ``attn_fn`` for models/. ``interpret=True`` is for CPU tests;
     the default compiles the kernel, and a compile error propagates."""
 
-    def attn_fn(q, k, v, *, causal: bool = True):
-        return flash_attention(q, k, v, causal, bq, bk, interpret)
+    def attn_fn(q, k, v, *, causal: bool = True, window: int | None = None):
+        return flash_attention(q, k, v, causal, bq, bk, interpret, window)
 
     return attn_fn
 

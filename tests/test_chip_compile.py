@@ -82,6 +82,10 @@ CORE_125M, CORE_XL, CORE_HEAD_128 = (
 # whole in VMEM (2 MiB each), over the default scoped limit in the backward
 FLASH_MLA, FLASH_MLA_BLOCK = (2, 4096, 20, 256), 512
 GROUPED = (2 * 4096 * 4, 8, 2048, 1536)  # buffer rows, experts held, D, F
+# SmallThinker-21B-A3B's cell: 28 query heads of 128 on 4 key-value heads,
+# one sequence of 16,384, blocks of 512; K and V ride whole in VMEM (4 MiB
+# each), Q, dO and the row statistics whole in dk/dv (the limit is raised)
+FLASH_GQA, FLASH_GQA_KV, FLASH_GQA_BLOCK = (1, 16384, 28, 128), 4, 512
 GPT2_HEADS, GPT2_HEAD_DIM, PAGE = 12, 64, 16
 
 
@@ -108,21 +112,25 @@ def _window(dev, *, mask, grad, dtype=jnp.float32, shape=None):
     )
 
 
-def _flash(dev, *, dtype, grad, shape=FLASH, block=128):
+def _flash(dev, *, dtype, grad, shape=FLASH, block=128, kv_heads=None,
+           window=None):
     from pytorch_distributedtraining_tpu.ops.pallas_attn import flash_attention
 
     qkv = _on(dev, shape, dtype)
+    kv = qkv if kv_heads is None else _on(
+        dev, (*shape[:2], kv_heads, shape[3]), dtype
+    )
 
     def fwd(q, k, v):
-        return flash_attention(q, k, v, True, block, block, False)
+        return flash_attention(q, k, v, True, block, block, False, window)
 
     if not grad:
-        return fwd, (qkv, qkv, qkv)
+        return fwd, (qkv, kv, kv)
     return (
         jax.grad(
             lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)), argnums=(0, 1, 2)
         ),
-        (qkv, qkv, qkv),
+        (qkv, kv, kv),
     )
 
 
@@ -258,6 +266,15 @@ KERNEL_CASES = {
     "flash_bwd_bf16_mla_head_256": (
         lambda d: _flash(d, dtype=jnp.bfloat16, grad=True, shape=FLASH_MLA,
                          block=FLASH_MLA_BLOCK), True,
+    ),
+    "flash_bwd_bf16_gqa_16k_window_4096": (
+        lambda d: _flash(d, dtype=jnp.bfloat16, grad=True, shape=FLASH_GQA,
+                         block=FLASH_GQA_BLOCK, kv_heads=FLASH_GQA_KV,
+                         window=4096), True,
+    ),
+    "flash_bwd_bf16_gqa_16k_full": (
+        lambda d: _flash(d, dtype=jnp.bfloat16, grad=True, shape=FLASH_GQA,
+                         block=FLASH_GQA_BLOCK, kv_heads=FLASH_GQA_KV), True,
     ),
     "core_qkv_fwd_gpt2_125m": (
         lambda d: _causal_qkv(d, shape=CORE_125M, grad=False), True,
@@ -528,6 +545,52 @@ def test_gpt2_xl_zero3_at_the_cells_sizes(topo):
     gathered = _gathered_shapes(text)
     assert {(1600, 4800), (1600, 6400), (1600, 1600)} <= gathered, gathered
     assert not [s for s in gathered if s[0] == 16], gathered
+
+
+@pytest.mark.slow
+def test_smallthinker_cell_step_one_chip(topo):
+    """``smallthinker-21b-a3b.train-16k`` as the benchmark builds it (its
+    family, its job's ``plan``): every attention core a kernel (per layer a
+    forward, the rematerialised forward, dq and dk/dv: 16), no T x T
+    scores, no copy of k or v for the seven query heads that share them,
+    and at least a quarter of the chip filled by the step's own plan."""
+    from chipbench import cells
+    from chipbench import plan as planner
+
+    cell = cells.load_cell("smallthinker-21b-a3b.train-16k")
+    family = cells.load_module("families", cell.config["family"], cell.roots)
+    job = cells.load_module("jobs", cell.workload["job"], cell.roots)
+    kept = {}
+
+    def compile_and_keep(jitted, *args):
+        compiled = jitted.lower(*args).compile()
+        kept["text"], kept["memory"] = (
+            compiled.as_text(), compiled.memory_analysis()
+        )
+        return {}
+
+    original, planner.compile_plan = planner.compile_plan, compile_and_keep
+    try:
+        job.plan(cell, family, list(topo.devices)[:1])
+    finally:
+        planner.compile_plan = original
+    text, mem = kept["text"], kept["memory"]
+    planned = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+    assert 4e9 < planned < 15.75e9
+    attention = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "/attention/" in line
+    ]
+    assert len(attention) == 16
+    assert sum("/attention_sliding/" in line for line in attention) == 12
+    assert sum("/attention_global/" in line for line in attention) == 4
+    assert _kernel_calls(text) == 16 + 4 * 12  # and the grouped matmuls
+    assert not re.findall(r"\[(?:\d+,)*16384,16384\]", text)
+    # k and v stay at 4 heads: nothing of [.., 28, ..] is made from them
+    assert not re.findall(
+        r"bf16\[1,28,16384,128\]\S* broadcast\(|"
+        r"bf16\[1,16384,4,7,128\]", text
+    )
 
 
 @pytest.mark.slow

@@ -95,3 +95,41 @@ def test_indivisible_seq_raises(qkv):
     q, k, v = qkv
     with pytest.raises(ValueError, match="must divide"):
         flash_attention(q[:, :100], k[:, :100], v[:, :100], True, 64, 64, True)
+
+
+@pytest.mark.parametrize("group", [1, 7])
+@pytest.mark.parametrize("window", [None, 20, 32, 75, 128])
+def test_band_and_grouped_heads_match_the_masked_einsum(window, group):
+    """A causal window (shorter than a block of 32, one block, several
+    blocks, the whole sequence; None = the causal kernel) and ``group``
+    query heads reading one key-value head where it lies: forward and the
+    three gradients (dk and dv summed over the group) against the masked
+    einsum, at blocks that divide the band unevenly too."""
+    from pytorch_distributedtraining_tpu.models.smallthinker import (
+        banded_attention,
+    )
+
+    keys = jax.random.split(jax.random.PRNGKey(group), 4)
+    q, do = (jax.random.normal(k, (2, T, 2 * group, DH)) for k in keys[:2])
+    k, v = (jax.random.normal(k, (2, T, 2, DH)) for k in keys[2:])
+    for bq, bk in ((32, 32), (64, 32)):
+        out, vjp = jax.vjp(
+            lambda *a: flash_attention(*a, True, bq, bk, True, window), q, k, v
+        )
+        want, want_vjp = jax.vjp(
+            lambda *a: banded_attention(*a, window=window), q, k, v
+        )
+        np.testing.assert_allclose(out, want, atol=2e-5)
+        for g, w in zip(vjp(do), want_vjp(do)):
+            assert g.shape == w.shape
+            np.testing.assert_allclose(g, w, atol=1e-4)
+
+
+def test_a_window_is_causal_and_heads_must_divide(qkv):
+    q, k, v = qkv
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, k, v, False, 32, 32, True, 16)
+    with pytest.raises(ValueError, match="do not divide"):
+        flash_attention(
+            jnp.concatenate([q, q[:, :, :1]], 2), k, v, True, 32, 32, True
+        )
