@@ -10,7 +10,16 @@ eighth holds rows, 8 groups, 2,048 x 1,536 and back): ``ops/grouped_matmul.py``
 against a loop over the groups. One JSON line per arm; the model calls the
 faster arm that is correct, and PERF.md keeps both readings (ISSUE 27).
 
-    chiprun -- python3 benchmarks/glm4_kernels.py [attention] [grouped]
+Routing (ISSUE 33) at the two sparse cells' expert layers ([16384, 2560] bf16
+tokens at 6 picks, [8192, 2048] at 4; 8 of 64 experts held) with none, an
+eighth and all of the N x k assignments landing: the gathers over the whole
+worst-case buffer that ``models/held_experts.py`` made until PR 32 against
+its row loops over the landed rows, the two primitives alone at five tiles,
+and a kernel of one row DMA a landed row. Dispatch + combine with nothing between them, forward and
+forward + backward (tokens' and weights' gradients), and the index work
+alone.
+
+    chiprun -- python3 benchmarks/glm4_kernels.py [attention] [grouped] [routing]
 
 Wall time of a jitted call ending in ``block_until_ready``, the median of
 ``REPS``. The attention calls take 4-55 ms; the grouped matmuls about a
@@ -50,7 +59,7 @@ def rel_err(a, b):
     import jax.numpy as jnp
 
     a, b = a.astype(jnp.float32), b.astype(jnp.float32)
-    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+    return float(jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b), 1e-30))
 
 
 def attention_reference(q, k, v, do, chunk=512):
@@ -247,6 +256,214 @@ def run_grouped():
             print(json.dumps(line), flush=True)
 
 
+ROUTING_SHAPES = ((16384, 6, 2560), (8192, 4, 2048))  # N, k, D of a layer
+EXPERTS, HELD = 64, 8
+
+
+def whole_buffer_route(tokens, weights, group):
+    """Dispatch and combine as they were until PR 32: every one of the N x k
+    rows gathered out and gathered back (the gradients gathers too, by the
+    inverse permutation), masked, weighed in a float32 [N, k, D] array and
+    summed, whatever landed."""
+    import jax
+    import jax.numpy as jnp
+
+    (n, d), k = tokens.shape, weights.shape[1]
+
+    @jax.custom_vjp
+    def spread_rows(tokens, order, slot):
+        return tokens[order // k]
+
+    def spread_bwd(slot, g):
+        per_token = g[slot].reshape(n, k, d).astype(jnp.float32)
+        return jnp.sum(per_token, 1).astype(g.dtype), None, None
+
+    spread_rows.defvjp(
+        lambda t, order, slot: (spread_rows(t, order, slot), slot), spread_bwd
+    )
+
+    @jax.custom_vjp
+    def collect_rows(rows, slot, order):
+        return rows[slot]
+
+    collect_rows.defvjp(
+        lambda rows, slot, order: (rows[slot], order),
+        lambda order, g: (g[order], None, None),
+    )
+
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    slot = jnp.zeros((n * k,), jnp.int32).at[order].set(
+        jnp.arange(n * k, dtype=jnp.int32)
+    )
+    here = group[order] < HELD
+    xs = jnp.where(here[:, None], spread_rows(tokens, order, slot), 0)
+    mine = (group < HELD).reshape(n, k)
+    parts = jnp.where(
+        mine[..., None], collect_rows(xs, slot, order).reshape(n, k, d), 0
+    ).astype(jnp.float32)
+    return jnp.sum(weights[..., None] * parts, 1).astype(tokens.dtype)
+
+
+def landed_rows_route(tokens, weights, group):
+    """Dispatch and combine as ``models/held_experts.py`` makes them: row
+    loops over the rows that landed, with their hand-written backward."""
+    from pytorch_distributedtraining_tpu.models import held_experts as he
+
+    landed = he.find_landed(group, HELD, weights.shape[1])
+    xs = he.spread_rows(tokens, landed)
+    return he.weighted_sum(xs, weights, landed, tokens.dtype)
+
+
+def row_dma_spread(src, token, count, tile=512):
+    """``out[r] = src[token[r]]`` for ``r < count``, one HBM -> HBM row DMA
+    each, the tile's tokens in SMEM: the kernel the issue proposed for
+    dispatch's forward."""
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, d = token.shape[0], src.shape[1]
+
+    def kernel(count_ref, token_ref, src_ref, out_ref, sem):
+        base = pl.program_id(0) * tile
+
+        def copy(r):
+            return pltpu.make_async_copy(
+                src_ref.at[pl.ds(token_ref[r], 1)],
+                out_ref.at[pl.ds(base + r, 1)], sem,
+            )
+
+        @pl.when(base < count_ref[0])
+        def _():
+            jax.lax.fori_loop(0, tile, lambda r, c: copy(r).start() or c, 0)
+            jax.lax.fori_loop(0, tile, lambda r, c: copy(r).wait() or c, 0)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(m // tile,),
+            in_specs=[
+                pl.BlockSpec((tile,), lambda i, c: (i,),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA],
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, d), src.dtype),
+    )(count.reshape(1), token, src)
+
+
+def routing_picks(key, n, k, share):
+    """Picks [N, k] over the published experts of which ``share`` land on
+    the held ones (0 .. HELD - 1): none, about an eighth (k distinct of all,
+    evenly), or all (k distinct of the held)."""
+    import jax
+    import jax.numpy as jnp
+
+    among = {"none": (HELD, EXPERTS), "eighth": (0, EXPERTS),
+             "all": (0, HELD)}[share]
+    draw = jax.random.uniform(key, (n, among[1] - among[0]))
+    return among[0] + jnp.argsort(draw, axis=-1)[:, :k].astype(jnp.int32)
+
+
+def run_routing():
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_distributedtraining_tpu.models import held_experts as he
+
+    arms = {
+        "whole buffer (until PR 32)": whole_buffer_route,
+        f"landed rows, tile {he.ROW_TILE} (models/held_experts.py)":
+            landed_rows_route,
+    }
+    _, floor_ms = timed(jax.jit(lambda x: x + 1), jnp.zeros((8, 128)))
+    print(json.dumps({
+        "kernel": "routing", "fwd_ms": floor_ms,
+        "arm": "a jitted call that does nothing (the floor under every line)",
+    }), flush=True)
+    for n, k, d in ROUTING_SHAPES:
+        kt, kw, kg, ks = jax.random.split(jax.random.PRNGKey(n), 4)
+        tokens = jax.random.normal(kt, (n, d), jnp.float32).astype(jnp.bfloat16)
+        weights = jax.nn.softmax(jax.random.normal(kw, (n, k)), -1)
+        g = jax.random.normal(kg, (n, d), jnp.float32).astype(jnp.bfloat16)
+        local = jnp.minimum(jnp.arange(EXPERTS), HELD).astype(jnp.int32)
+        for share in ("none", "eighth", "all"):
+            group = local[routing_picks(ks, n, k, share).reshape(-1)]
+            landed, index_ms = timed(
+                jax.jit(lambda group: he.find_landed(group, HELD, k)), group
+            )
+            base = {"kernel": "routing", "shape": [n, k, d], "lands": share,
+                    "landed": int(landed.count)}
+            ids = lambda: jnp.arange(n * k, dtype=jnp.int32)  # noqa: E731
+            pieces = {  # ways to apply or invert a permutation of N x k
+                "sort_ms": lambda at: jax.lax.sort(
+                    (at.order, ids()), num_keys=1),
+                "scatter_ms": lambda at: jnp.zeros_like(at.order).at[
+                    at.order].set(ids()),
+                "gather_ms": lambda at: at.slot[at.order],
+            }
+            print(json.dumps({
+                **base, "arm": "find_landed (the index work)",
+                "fwd_ms": index_ms, **{
+                    name: timed(jax.jit(f), landed)[1]
+                    for name, f in pieces.items()
+                },
+            }), flush=True)
+            want = None
+            for name, fn in arms.items():
+                line = {**base, "arm": name}
+                try:
+                    out, line["fwd_ms"] = timed(
+                        jax.jit(fn), tokens, weights, group
+                    )
+                    grads, line["fwd_bwd_ms"] = timed(jax.jit(
+                        lambda t, w, group, g, fn=fn: jax.vjp(
+                            lambda a, b: fn(a, b, group), t, w
+                        )[1](g)
+                    ), tokens, weights, group, g)
+                    if want is None:
+                        want = (out, grads)
+                    else:
+                        line["out_rel_err"] = rel_err(out, want[0])
+                        line["grad_rel_err"] = [
+                            rel_err(a, b) for a, b in zip(grads, want[1])
+                        ]
+                except Exception as e:  # noqa: BLE001
+                    line["error"] = f"{type(e).__name__}: {str(e)[:400]}"
+                print(json.dumps(line), flush=True)
+            # the two primitives alone, by the rows a loop step moves
+            for tile in (128, 256, 512, 1024, 2048):
+                rows, spread_ms = timed(jax.jit(
+                    lambda t, at, tile=tile: he.spread(t, at, tile=tile)
+                ), tokens, landed)
+                _, sum_ms = timed(jax.jit(
+                    lambda r, w, at, tile=tile: he.gather_sum(
+                        r, at, weight=w, tile=tile
+                    )
+                ), rows, weights, landed)
+                print(json.dumps({
+                    **base, "arm": f"spread | gather_sum alone, tile {tile}",
+                    "spread_ms": spread_ms, "gather_sum_ms": sum_ms,
+                }), flush=True)
+            if share != "eighth":
+                continue
+            line = {**base, "arm": "spread as one row DMA a landed row"}
+            try:
+                ours = he.spread(tokens, landed)
+                live = (jnp.arange(n * k) < landed.count)[:, None]
+                rows, line["spread_ms"] = timed(
+                    jax.jit(row_dma_spread), tokens, landed.token, landed.count
+                )
+                line["out_rel_err"] = rel_err(
+                    jnp.where(live, rows, 0), jnp.where(live, ours, 0)
+                )
+            except Exception as e:  # noqa: BLE001
+                line["error"] = f"{type(e).__name__}: {str(e)[:400]}"
+            print(json.dumps(line), flush=True)
+
+
 def main(argv):
     import jax
 
@@ -255,11 +472,13 @@ def main(argv):
           flush=True)
     if dev.platform != "tpu":
         raise SystemExit("glm4_kernels measures on a TPU, found none")
-    what = argv or ["attention", "grouped"]
+    what = argv or ["attention", "grouped", "routing"]
     if "attention" in what:
         run_attention()
     if "grouped" in what:
         run_grouped()
+    if "routing" in what:
+        run_routing()
     return 0
 
 

@@ -9,7 +9,12 @@ written**, forward or backward (the gradient of ``x`` too): they hold
 whatever the buffer held, so a caller masks them out BEFORE they meet a
 product (``where(valid, y, 0) * weight``, never ``where(valid, y * weight,
 0)``: a product's gradient multiplies a zero cotangent by the unwritten
-row).
+row), or never reads them. Nor need they be written on the way IN: the
+rows of ``x`` and of the result's cotangent past the groups may hold
+anything, NaN included (the kernels mask a tile's rows by its group before
+the product, ``tgmm`` too). The one caller, ``models/held_experts.py``,
+does both since PR 33: its dispatch writes only the rows that landed into
+an allocated buffer, and its combine reads only those.
 
 The kernel is the Pallas ``megablox.gmm`` that jax ships (with its backward:
 ``gmm`` for the rows' gradient, ``tgmm`` for the weights'). Its grid runs

@@ -594,6 +594,44 @@ def test_smallthinker_cell_step_one_chip(topo):
 
 
 @pytest.mark.slow
+def test_smallthinker_cell_step_moves_only_the_rows_that_land(topo):
+    """The same step (PR 33): the held-experts layer's dispatch and combine
+    are row loops over buffers that are allocated, not filled, and combine
+    makes no float32 copy of the whole N x k buffer; the attention kernels
+    are the 16 they were."""
+    from chipbench import cells
+    from chipbench import plan as planner
+
+    cell = cells.load_cell("smallthinker-21b-a3b.train-16k")
+    family = cells.load_module("families", cell.config["family"], cell.roots)
+    job = cells.load_module("jobs", cell.workload["job"], cell.roots)
+    kept = {}
+
+    def compile_and_keep(jitted, *args):
+        kept["text"] = jitted.lower(*args).compile().as_text()
+        return {}
+
+    original, planner.compile_plan = planner.compile_plan, compile_and_keep
+    try:
+        job.plan(cell, family, list(topo.devices)[:1])
+    finally:
+        planner.compile_plan = original
+    text = kept["text"]
+    assert "f32[16384,6,2560]" not in text
+    buffers = [
+        line for line in text.splitlines()
+        if "AllocateBuffer" in line and "bf16[98304,2560]" in line
+    ]
+    assert buffers and all(
+        "/dispatch/" in line or "/combine/" in line for line in buffers
+    )
+    assert sum(
+        "tpu_custom_call" in line and "/attention/" in line
+        for line in text.splitlines()
+    ) == 16
+
+
+@pytest.mark.slow
 def test_gpt2_block_with_fp8_dots(topo):
     from pytorch_distributedtraining_tpu.models import GPT2Config
     from pytorch_distributedtraining_tpu.parallel import DDP

@@ -244,6 +244,33 @@ def test_the_model_calls_the_blockwise_kernel_unless_told_otherwise():
     assert kernels(model) - kernels(xla) == cfg.num_hidden_layers
 
 
+def test_every_traced_expert_layer_says_its_routing_path():
+    """One ``routing.path`` instant a traced expert layer (the first layer
+    is dense), with the shapes the held-experts layer adapts on."""
+    from pytorch_distributedtraining_tpu.models import held_experts
+    from pytorch_distributedtraining_tpu.observe import trace
+
+    tracer = trace.get_tracer()
+    was = tracer.enabled
+    cfg, model, params, state, x, _ = build("some")
+    trace.enable(crash_handler=False)
+    trace.clear()
+    try:
+        jax.make_jaxpr(lambda p: model.apply({"params": p, **state}, x))(params)
+        said = [
+            r["attrs"] for r in trace.records() if r["name"] == "routing.path"
+        ]
+    finally:
+        trace.clear()
+        tracer.enabled = was
+    assert len(said) == cfg.num_hidden_layers - cfg.first_k_dense_replace
+    n, k = x.size, cfg.num_experts_per_tok
+    assert {(a["path"], a["n"], a["k"], a["d"], a["tile"]) for a in said} == {
+        ("jnp", n, k, cfg.hidden_size, min(held_experts.ROW_TILE, n * k))
+    }
+    assert all("landed rows" in a["reason"] for a in said)
+
+
 def test_the_probe_gives_each_expert_layers_input_scores_picks_and_output():
     """``mutable=["moe_probe"]``: what a reference needs to be held against
     one expert layer alone; nothing of it without the asking."""

@@ -243,6 +243,33 @@ def test_the_model_calls_the_banded_kernel_unless_told_otherwise():
     assert said[0]["bq"] == said[0]["bk"] == 16
 
 
+def test_every_traced_expert_layer_says_its_routing_path():
+    """``routing.path``: how the held-experts layer moves its rows, with the
+    shapes it adapts on (one instant a traced layer)."""
+    from pytorch_distributedtraining_tpu.models import held_experts
+    from pytorch_distributedtraining_tpu.observe import trace
+
+    tracer = trace.get_tracer()
+    was = tracer.enabled
+    cfg, model, params, x, _ = build("some")
+    trace.enable(crash_handler=False)
+    trace.clear()
+    try:
+        jax.make_jaxpr(lambda p: model.apply({"params": p}, x))(params)
+        said = [
+            r["attrs"] for r in trace.records() if r["name"] == "routing.path"
+        ]
+    finally:
+        trace.clear()
+        tracer.enabled = was
+    assert len(said) == cfg.num_hidden_layers
+    n, k = x.size, cfg.moe_num_active_primary_experts
+    assert {(a["path"], a["n"], a["k"], a["d"], a["tile"]) for a in said} == {
+        ("jnp", n, k, cfg.hidden_size, min(held_experts.ROW_TILE, n * k))
+    }
+    assert all("landed rows" in a["reason"] for a in said)
+
+
 def test_the_two_copies_of_the_reference_are_one_text():
     assert filecmp.cmp(
         os.path.join(REPO, "chipbench", "reference", "smallthinker.py"),
