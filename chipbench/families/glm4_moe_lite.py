@@ -8,7 +8,8 @@ from __future__ import annotations
 import dataclasses
 import functools
 
-from chipbench.families.gpt2 import TRAIN_MULT, zipf_batches
+from chipbench import seeded
+from chipbench.families.gpt2 import TRAIN_MULT
 
 
 def model_config(config: dict, job: dict):
@@ -26,11 +27,12 @@ def model_config(config: dict, job: dict):
         "num_attention_heads", "q_lora_rank", "kv_lora_rank",
         "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
         "n_shared_experts", "num_experts_per_tok", "routed_scaling_factor",
-        "norm_topk_prob", "rms_norm_eps",
+        "norm_topk_prob", "rms_norm_eps", "bias_update_rate",
     )
     return Glm4MoeLiteConfig(
         **{key: config[key] for key in same},
         rope_theta=float(config["rope_theta"]),
+        initializer_range=config["initializer"]["range"],
         n_routed_experts=config["n_routed_experts_published"],
         held_experts=tuple(range(config["n_routed_experts"])),
         dtype=jnp.dtype(job.get("compute_dtype", "bfloat16")),
@@ -156,7 +158,10 @@ class Task:
     units_per_step: int
     flops_per_step: float
     batches: object  # seed -> iterator of host (tokens, targets)
-    reference: object  # (params, batch) -> {"loss", "grad_norm"}
+    # the plain reference, for chipbench/first_steps.py:
+    reference_grads: object  # (params, model_state, batch) -> (loss, grads)
+    reference_state: object  # (params, model_state, batch) -> model_state
+    layers: object  # (params, batch) -> the program's layers' distances
     kernel_costs: object  # (rows, active experts) a step -> {kernel: (FLOPs, bytes)}
 
 
@@ -207,6 +212,43 @@ def expert_layer_distances(reference, arch, operands, params, bias, probe):
     }
 
 
+def moved_biases(reference, arch, rate, chunk, params, router_state, tokens):
+    """The selection biases after a step on ``tokens``, as the reference
+    routes them: every expert layer's ``b + rate * sign(mean load - load)``,
+    the loads the assignments that each published expert got in the step's
+    forward pass (the configuration's ``assumed.bias_update_rate``). The
+    reference's own forward pass gives no picks back and is held to one text
+    with the repository's copy, so its layers are walked once more here."""
+    import jax
+    import jax.numpy as jnp
+
+    moved = {}
+    with jax.default_matmul_precision("highest"):
+        x = params["embed_tokens"][tokens]
+        for i in range(arch["layers"]):
+            name = f"layers_{i}"
+            p = params[name]
+            h = x + reference.mla(
+                reference.rms(x, p["norm_attn"], arch["eps"]), p["mla"], arch,
+                chunk, None,
+            )
+            y = reference.rms(h, p["norm_ffn"], arch["eps"])
+            if i < arch["first_dense"]:
+                x = h + reference.gated_mlp(y, p["mlp_dense"], None)
+                continue
+            flat = y.reshape(-1, y.shape[-1])
+            bias = router_state[name]["moe"]["bias"]
+            sel, _ = reference.route(flat, p["moe"]["router"], bias, arch)
+            load = jnp.zeros(bias.shape, jnp.float32).at[sel.reshape(-1)].add(1.0)
+            moved[name] = {"moe": {
+                "bias": bias + rate * jnp.sign(jnp.mean(load) - load)
+            }}
+            x = h + reference.expert_layer(
+                flat, p["moe"], bias, arch
+            ).reshape(y.shape)
+    return moved
+
+
 def task(config: dict, job: dict) -> Task:
     import jax
     import jax.numpy as jnp
@@ -224,13 +266,18 @@ def task(config: dict, job: dict) -> Task:
     # the model's own kernels; "interpret" only where a CPU rehearsal says so
     model = Glm4MoeLite(cfg, interpret=job.get("interpret", False))
 
+    factors = seeded.leaf_factors(config["initializer"])
+
     def init_fn(rng):
         # parameters do not depend on the attention function: XLA's, so
-        # that no kernel is compiled for the init's 8 tokens
+        # that no kernel is compiled for the init's 8 tokens; the model's
+        # own draw, its named leaves at the configuration's scales
         variables = Glm4MoeLite(cfg, default_attention, interpret=True).init(
             rng, jnp.zeros((1, 8), jnp.int32)
         )
-        return variables["params"], {ROUTER_STATE: variables[ROUTER_STATE]}
+        return seeded.rescale(variables["params"], factors), {
+            ROUTER_STATE: variables[ROUTER_STATE]
+        }
 
     def loss_fn(params, batch, rng, model_state):
         tokens, targets = batch
@@ -244,10 +291,20 @@ def task(config: dict, job: dict) -> Task:
         }
 
     arch = reference.arch_of(config)
-    ref = jax.jit(functools.partial(
-        reference.loss_and_grad_norm, arch=arch,
-        chunk=job["reference_query_chunk"],
-    ))
+    chunk = job["reference_query_chunk"]
+
+    def reference_grads(params, model_state, batch, **lower):
+        return reference.loss_and_grads(
+            params, model_state[ROUTER_STATE], *batch, arch, chunk=chunk,
+            **lower,
+        )
+
+    def reference_state(params, model_state, batch):
+        return {ROUTER_STATE: moved_biases(
+            reference, arch, config["bias_update_rate"], chunk, params,
+            model_state[ROUTER_STATE], batch[0],
+        )}
+
     cast = Precision.from_name(job["precision"]).cast_to_compute
 
     @jax.jit
@@ -265,27 +322,25 @@ def task(config: dict, job: dict) -> Task:
         expert_layer_distances, reference, arch, cfg.dtype
     ))
 
-    def run_reference(params, first_batch):
+    def layers(params, first_batch):
         bias = {  # before step 0 every selection bias is zero
             f"layers_{i}": {"moe": {"bias": jnp.zeros(
                 (cfg.n_routed_experts,), jnp.float32
             )}}
             for i in range(cfg.first_k_dense_replace, cfg.num_hidden_layers)
         }
-        loss, gnorm = ref(params, bias, *first_batch)
-        layers = distances(params, bias, probe(params, bias, first_batch[0]))
-        return {
-            "loss": float(loss), "grad_norm": float(gnorm),
-            **{k: float(v) for k, v in layers.items()},
-        }
+        tokens = jnp.asarray(first_batch[0])
+        read = distances(params, bias, probe(params, bias, tokens))
+        return {k: float(v) for k, v in read.items()}
 
     return Task(
         init_fn=init_fn, loss_fn=loss_fn,
         units_per_step=batch * seq,
         flops_per_step=train_flops_per_token(config, seq) * batch * seq,
-        batches=lambda seed: zipf_batches(
-            seed, batch, seq, cfg.vocab_size, job["zipf_exponent"]
+        batches=lambda seed: seeded.even_batches(
+            seed, batch, seq, cfg.vocab_size
         ),
-        reference=run_reference,
+        reference_grads=reference_grads, reference_state=reference_state,
+        layers=layers,
         kernel_costs=functools.partial(kernel_costs, config, job),
     )

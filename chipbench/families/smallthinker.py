@@ -9,8 +9,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 
-import numpy as np
-
+from chipbench import seeded
 from chipbench.families.gpt2 import TRAIN_MULT
 
 
@@ -35,6 +34,7 @@ def model_config(config: dict, job: dict):
     return SmallThinkerConfig(
         **{key: config[key] for key in same},
         rope_theta=float(config["rope_theta"]),
+        initializer_range=config["initializer"]["range"],
         sliding_window_layout=tuple(config["sliding_window_layout"]),
         rope_layout=tuple(config["rope_layout"]),
         moe_num_primary_experts=config["moe_num_primary_experts_published"],
@@ -172,18 +172,6 @@ def kernel_costs(config: dict, job: dict, rows: float, active: float) -> dict:
     }
 
 
-def even_batches(seed: int, batch: int, seq: int, vocab: int):
-    """Endless host batches of token ids drawn evenly and independently
-    over the vocabulary held, every step a fresh draw from the seed's
-    stream: with random weights a router is nearly a function of the token
-    id, and under even ids the rows that land on the held experts do not
-    depend on which ids a seed made hot."""
-    rng = np.random.default_rng(seed)
-    while True:
-        tok = rng.integers(0, vocab, (batch, seq + 1), dtype=np.int32)
-        yield tok[:, :-1], tok[:, 1:]
-
-
 @dataclasses.dataclass
 class Task:
     init_fn: object
@@ -191,7 +179,10 @@ class Task:
     units_per_step: int
     flops_per_step: float
     batches: object  # seed -> iterator of host (tokens, targets)
-    reference: object  # (params, batch) -> {"loss", "grad_norm", ...}
+    # the plain reference, for chipbench/first_steps.py:
+    reference_grads: object  # (params, model_state, batch) -> (loss, grads)
+    reference_state: object  # None: a step moves nothing but the weights
+    layers: object  # (params, batch) -> the program's layers' distances
     kernel_costs: object  # (rows, active experts) a step -> {kernel: (FLOPs, bytes)}
 
 
@@ -294,12 +285,16 @@ def task(config: dict, job: dict) -> Task:
     # the model's own kernels; "interpret" only where a CPU rehearsal says so
     model = SmallThinker(cfg, interpret=job.get("interpret", False))
 
+    factors = seeded.leaf_factors(config["initializer"])
+
     def init_fn(rng):
         # parameters do not depend on the attention function: the einsum,
-        # so that no kernel is compiled for the init's 8 tokens
-        return SmallThinker(cfg, banded_attention, interpret=True).init(
+        # so that no kernel is compiled for the init's 8 tokens; the model's
+        # own draw, its named leaves at the configuration's scales
+        params = SmallThinker(cfg, banded_attention, interpret=True).init(
             rng, jnp.zeros((1, 8), jnp.int32)
-        )["params"], {}
+        )["params"]
+        return seeded.rescale(params, factors), {}
 
     def loss_fn(params, batch, rng, model_state):
         tokens, targets = batch
@@ -311,10 +306,12 @@ def task(config: dict, job: dict) -> Task:
         )
 
     arch = reference.arch_of(config)
-    ref = jax.jit(functools.partial(
-        reference.loss_and_grad_norm, arch=arch,
-        chunk=job["reference_query_chunk"],
-    ))
+
+    def reference_grads(params, model_state, batch, **lower):
+        return reference.loss_and_grads(
+            params, *batch, arch, chunk=job["reference_query_chunk"], **lower
+        )
+
     cast = Precision.from_name(job["precision"]).cast_to_compute
 
     @jax.jit
@@ -335,20 +332,19 @@ def task(config: dict, job: dict) -> Task:
         attention_distances, reference, arch, job["reference_query_chunk"]
     ))
 
-    def run_reference(params, first_batch):
-        loss, gnorm = ref(params, *first_batch)
-        probed = probe(params, first_batch[0])
-        layers = {**distances(params, probed), **cores(probed)}
-        return {
-            "loss": float(loss), "grad_norm": float(gnorm),
-            **{k: float(v) for k, v in layers.items()},
-        }
+    def layers(params, first_batch):
+        probed = probe(params, jnp.asarray(first_batch[0]))
+        read = {**distances(params, probed), **cores(probed)}
+        return {k: float(v) for k, v in read.items()}
 
     return Task(
         init_fn=init_fn, loss_fn=loss_fn,
         units_per_step=batch * seq,
         flops_per_step=train_flops_per_token(config, seq) * batch * seq,
-        batches=lambda seed: even_batches(seed, batch, seq, cfg.vocab_size),
-        reference=run_reference,
+        batches=lambda seed: seeded.even_batches(
+            seed, batch, seq, cfg.vocab_size
+        ),
+        reference_grads=reference_grads, reference_state=None,
+        layers=layers,
         kernel_costs=functools.partial(kernel_costs, config, job),
     )

@@ -18,8 +18,9 @@ ATTENTION_LIMITS = ("attention_rel",)
 
 class Job(trainstep_counted.Job):
     def check(self, setup: dict, window) -> list:
+        problems = super().check(setup, window)
         tol, ref = self.env.cell.workload["tolerance"], setup["reference"]
-        return super().check(setup, window) + [
-            f"step-0 {k} {ref[k]} over {tol[k]}"
-            for k in ATTENTION_LIMITS if ref[k] > tol[k]
-        ]
+        self.env.counters["compared"].update(
+            {k: [ref[k], tol[k]] for k in ATTENTION_LIMITS}
+        )
+        return problems

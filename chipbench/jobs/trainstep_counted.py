@@ -70,11 +70,10 @@ class Job(trainstep.Job):
     def check(self, setup: dict, window) -> list:
         problems = super().check(setup, window)
         tol, ref = self.env.cell.workload["tolerance"], setup["reference"]
-        problems += [
-            f"step-0 {k} {ref[k]} over {tol[k]}"
-            for k in LAYER_LIMITS if ref[k] > tol[k]
-        ]
-        dropped = self.env.counters["dropped_assignments"]
-        if dropped:
-            problems.append(f"{dropped} assignments dropped in the window")
+        self.env.counters["compared"].update(
+            {k: [ref[k], tol[k]] for k in LAYER_LIMITS},
+            dropped_assignments=[
+                self.env.counters["dropped_assignments"], 0
+            ],
+        )
         return problems

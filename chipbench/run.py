@@ -94,29 +94,43 @@ def start_backend(cell: cells.Cell, rehearse: bool):
     return devices[: cell.chips], len(devices)
 
 
-def falling(losses) -> bool:
-    """Mean of the last tenth below the mean of the first tenth."""
+def tenths(losses) -> tuple:
+    """Means of the first and of the last tenth."""
     n = max(1, len(losses) // 10)
-    return sum(losses[-n:]) / n < sum(losses[:n]) / n
+    return sum(losses[:n]) / n, sum(losses[-n:]) / n
 
 
-def window_problems(window, window_compile: dict) -> list:
-    """What every cell requires of its window, whatever the job."""
+def window_problems(window, window_compile: dict, workload: dict,
+                    compared: dict) -> list:
+    """What every cell requires of its window, whatever the job. What has a
+    limit goes into ``compared`` as ``[number, limit]``; returned are the
+    reasons of another kind. Where no reference follows the step's first
+    updates (``follow_steps``: chipbench/first_steps.py), the loss has to
+    fall over the window: the one thing that sees a step that leaves its
+    state as it was."""
     problems = []
-    if window_compile["compiles"]:
-        problems.append(
-            f"{window_compile['compiles']} compilations in the window"
-        )
-    if window.failed or not window.losses:
-        # a step that raised ended the run; these returned a non-finite loss
-        problems.append(f"{window.failed} of {window.attempted} steps failed")
-    elif not falling(window.losses):
-        problems.append("the loss did not fall over the window")
+    compared["compilations_in_window"] = [window_compile["compiles"], 0]
+    compared["steps_failed"] = [window.failed, 0]
+    if not window.losses:
+        problems.append(f"none of {window.attempted} steps came back")
+    elif not window.failed and not workload.get("follow_steps"):
+        first, last = tenths(window.losses)
+        compared["loss_last_tenth_less_first"] = [last - first, 0.0]
+        if last == first:  # at its limit, and still not fallen
+            problems.append("the loss is where it was over the window")
     if not window.step_s > 0:
         problems.append(
             f"{len(window.gaps)} intervals between steps: no median pace"
         )
     return problems
+
+
+def over_limit(compared: dict) -> list:
+    """The numbers of ``compared`` that are not within their limits."""
+    return [
+        f"{name} {value} is over its limit {limit}"
+        for name, (value, limit) in compared.items() if not value <= limit
+    ]
 
 
 def main(argv=None) -> int:
@@ -162,13 +176,21 @@ def main(argv=None) -> int:
         setup_s = time.perf_counter() - t_start
         window = job.run(opt.seconds)
         window_compile = meter.take()
+        # the peak is the program's: read before a reference takes its room
+        memory_peaks = instruments.memory_peaks(devices)
+        t_check = time.perf_counter()
         problems = job.check(setup, window)
+        check_s = time.perf_counter() - t_check
     finally:
         job.close()
 
     rate = window.rate  # at the window's median pace: see loop.py
     kind = devices[0].device_kind
-    problems += window_problems(window, window_compile)
+    compared = env.counters.setdefault("compared", {})
+    problems += window_problems(
+        window, window_compile, cell.workload, compared
+    )
+    problems += over_limit(compared)
     if not rehearse:
         over = above_physical_bound(
             env.counters["flops_per_step"] / window.step_s, kind, cell.chips
@@ -182,7 +204,6 @@ def main(argv=None) -> int:
         reduction = trace_reduce.reduce(
             trace_reduce.load(xplane), job_module.STEP_MODULES
         )
-    memory_peaks = instruments.memory_peaks(devices)
     peak_bytes = instruments.memory_peak_bytes(memory_peaks)
     context = ReadContext(
         window=window, trace=reduction, spans=env.spans, calls=env.calls,
@@ -216,6 +237,7 @@ def main(argv=None) -> int:
         "rate": rate, "rate_wall": window.units / window.seconds,
         "step_s": window.step_s, "stall_s": window.stall_s,
         "gaps": window.gaps, "tenths": window.tenths, "setup_s": setup_s,
+        "check_s": check_s,
         "setup": setup, "setup_compile": setup_compile,
         "window_compile": window_compile, "calls": env.calls,
         "spans_s": env.spans.seconds, "problems": problems,
@@ -242,6 +264,17 @@ def main(argv=None) -> int:
         device["busy_s"] = reduction["busy_mean_s"]
         device["window_s"] = reduction["window_s"]
         line["breakdown"] = reduction["breakdown"]
+    # each number compared beside its limit: the end of both streams
+    line["compared"] = {
+        name: {"value": value, "limit": limit}
+        for name, (value, limit) in compared.items()
+    }
+    for name, entry in line["compared"].items():
+        print(f"compared {name}: {entry['value']} limit {entry['limit']}",
+              file=sys.stderr)
+    for problem in problems:
+        print(f"not correct: {problem}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

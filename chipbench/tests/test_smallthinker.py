@@ -12,7 +12,9 @@ import types
 
 import pytest
 
-from chipbench import attention_kinds, cells, program_trace as pt
+from chipbench import (
+    attention_kinds, cells, program_trace as pt, run, seeded,
+)
 from chipbench.trace_reduce import Event, Trace
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -97,10 +99,10 @@ def test_the_traffic_is_the_issues(cell):
 
 
 def test_ids_are_drawn_evenly_and_independently_from_the_seed(family):
-    first = family.even_batches(2**31 + 7, 1, 4096, 18992)
+    first = seeded.even_batches(2**31 + 7, 1, 4096, 18992)
     tokens, targets = next(first)
-    again = next(family.even_batches(2**31 + 7, 1, 4096, 18992))[0]
-    other = next(family.even_batches(2**31 + 8, 1, 4096, 18992))[0]
+    again = next(seeded.even_batches(2**31 + 7, 1, 4096, 18992))[0]
+    other = next(seeded.even_batches(2**31 + 8, 1, 4096, 18992))[0]
     assert tokens.shape == targets.shape == (1, 4096)
     assert (tokens[:, 1:] == targets[:, :-1]).all()
     assert (tokens == again).all() and not (tokens == other).all()
@@ -377,11 +379,16 @@ def test_the_job_holds_the_attention_cores_beside_the_expert_layers():
                       "router_score_rms": 0.0, "expert_layer_rel": 4e-3},
     }
     job = job_module.Job(env)
-    assert job.check(found, None) == []
+
+    def over():
+        assert job.check(found, None) == []
+        return [p.split()[0] for p in run.over_limit(env.counters["compared"])]
+
+    assert over() == []
     found["reference"]["attention_rel"] = 0.03  # a band a key off
-    assert any("attention_rel" in p for p in job.check(found, None))
+    assert over() == ["attention_rel"]
     found["reference"].update(attention_rel=3e-3, expert_layer_rel=0.07)
-    assert any("expert_layer_rel" in p for p in job.check(found, None))
+    assert over() == ["expert_layer_rel"]
     assert (job_module.STEP_MODULES, job_module.plan) == (
         job_module.trainstep_counted.STEP_MODULES,
         job_module.trainstep_counted.plan,
@@ -448,11 +455,14 @@ def test_the_new_cell_rehearsed_on_the_cpu():
     lines = done.stdout.strip().splitlines()
     line, notes = json.loads(lines[-1]), json.loads(lines[-2])
     # ids drawn afresh and evenly every step hold nothing a toy could learn
-    # once it is warm, so ``run.py``'s "the loss fell over the window" is a
-    # coin's toss here (PERF.md section 7: the benchmark's to mend); every
-    # other check of the run has to pass
-    assert set(notes["problems"]) <= {"the loss did not fall over the window"}
-    assert line["correct"] is (not notes["problems"]) and line["failed"] == 0
+    # once it is warm: the reference follows the first steps instead
+    assert notes["problems"] == []
+    assert line["correct"] is True and line["failed"] == 0
+    # each number compared beside its limit, last in the line
+    assert list(line)[-1] == "compared"
+    assert line["compared"]["attention_rel"]["limit"] == 0.1
+    assert "loss_last_tenth_less_first" not in line["compared"]
+    assert line["compared"]["update_leaf_rel"]["value"] < 0.1
     found = notes["setup"]["reference"]
     limits = cells.load_cell(
         "tiny-smallthinker.train", REHEARSAL
