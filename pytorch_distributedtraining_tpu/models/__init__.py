@@ -14,7 +14,10 @@ sigmoid-routed dropless expert layer that is told which experts it holds)
 and ``SmallThinker`` (SmallThinker-21BA3B: window and position-free full
 layers 3:1 over grouped-query heads, a softmax router that reads the layer's
 raw input; the held-experts layer is one piece of code for both,
-``.held_experts``).
+``.held_experts``) and ``Afmoe`` (Trinity-Mini: the same layer kinds with
+a norm on every head of q and k, a sigmoid gate on the core's output and a
+norm after each branch as well as before it; GLM's expert layer as it
+stands, 8 of 128 a token).
 
 All models are Flax linen modules in NHWC (images) / [B, T, D] (sequences) —
 the layouts XLA:TPU tiles best — with bf16-friendly parameterization.
@@ -42,6 +45,8 @@ _LAZY = {
     "cross_entropy_loss": ".gpt2",
     "Glm4MoeLite": ".glm4_moe_lite",
     "Glm4MoeLiteConfig": ".glm4_moe_lite",
+    "Afmoe": ".afmoe",
+    "AfmoeConfig": ".afmoe",
     "SmallThinker": ".smallthinker",
     "SmallThinkerConfig": ".smallthinker",
     "ViT": ".vit",

@@ -264,6 +264,34 @@ class DecoderLayer(nn.Module):
         return pin_batch(h + y)
 
 
+def attention_core(attn_fn: BandedAttnFn | None, interpret: bool, t: int):
+    """The core every layer of a model calls on sequences of ``t``: the
+    banded blockwise kernel of ``ops/pallas_attn.py`` at ``ATTENTION_BLOCK``
+    unless ``attn_fn`` names another; each traced layer says which it got in
+    the instant ``attention.path``."""
+    from ..observe import trace
+
+    block = min(ATTENTION_BLOCK, t)
+    kernel = attn_fn is None
+    inner = attn_fn or make_flash_attn_fn(
+        bq=block, bk=block, interpret=interpret
+    )
+
+    def core(q, k, v, *, window):
+        trace.instant(
+            "attention.path", path="kernel" if kernel else "attn_fn",
+            reason=(
+                "the model's own banded blockwise kernel" if kernel
+                else "the caller named the attention function"
+            ),
+            window=window, heads=q.shape[2], kv_heads=k.shape[2], t=t,
+            bq=block if kernel else None, bk=block if kernel else None,
+        )
+        return inner(q, k, v, window=window)
+
+    return core
+
+
 class SmallThinker(nn.Module):
     """``__call__(tokens [B, T]) -> logits [B, T, vocab]`` (float32).
 
@@ -283,36 +311,10 @@ class SmallThinker(nn.Module):
     attn_fn: BandedAttnFn | None = None
     interpret: bool = False
 
-    def _attention(self, tokens):
-        """The core every layer calls; each traced layer says which it got
-        in the instant ``attention.path``."""
-        from ..observe import trace
-
-        t = tokens.shape[1]
-        block = min(ATTENTION_BLOCK, t)
-        kernel = self.attn_fn is None
-        inner = self.attn_fn or make_flash_attn_fn(
-            bq=block, bk=block, interpret=self.interpret
-        )
-
-        def attn_fn(q, k, v, *, window):
-            trace.instant(
-                "attention.path", path="kernel" if kernel else "attn_fn",
-                reason=(
-                    "the model's own banded blockwise kernel" if kernel
-                    else "the caller named the attention function"
-                ),
-                window=window, heads=q.shape[2], kv_heads=k.shape[2], t=t,
-                bq=block if kernel else None, bk=block if kernel else None,
-            )
-            return inner(q, k, v, window=window)
-
-        return attn_fn
-
     @nn.compact
     def __call__(self, tokens):
         cfg = self.cfg
-        attn_fn = self._attention(tokens)
+        attn_fn = attention_core(self.attn_fn, self.interpret, tokens.shape[1])
         init = nn.initializers.normal(cfg.initializer_range)
         embed = self.param(
             "embed_tokens", init, (cfg.vocab_size, cfg.hidden_size)

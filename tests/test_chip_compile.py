@@ -86,6 +86,10 @@ GROUPED = (2 * 4096 * 4, 8, 2048, 1536)  # buffer rows, experts held, D, F
 # one sequence of 16,384, blocks of 512; K and V ride whole in VMEM (4 MiB
 # each), Q, dO and the row statistics whole in dk/dv (the limit is raised)
 FLASH_GQA, FLASH_GQA_KV, FLASH_GQA_BLOCK = (1, 16384, 28, 128), 4, 512
+# Trinity-Mini's cell: 32 query heads of 128 on 4 key-value heads (8 each),
+# one sequence of 8,192, blocks of 512: a band of 2,048 is 4 blocks and the
+# two masked edges
+FLASH_GQA8, FLASH_GQA8_WINDOW = (1, 8192, 32, 128), 2048
 GPT2_HEADS, GPT2_HEAD_DIM, PAGE = 12, 64, 16
 
 
@@ -274,6 +278,15 @@ KERNEL_CASES = {
     ),
     "flash_bwd_bf16_gqa_16k_full": (
         lambda d: _flash(d, dtype=jnp.bfloat16, grad=True, shape=FLASH_GQA,
+                         block=FLASH_GQA_BLOCK, kv_heads=FLASH_GQA_KV), True,
+    ),
+    "flash_bwd_bf16_gqa8_8k_window_2048": (
+        lambda d: _flash(d, dtype=jnp.bfloat16, grad=True, shape=FLASH_GQA8,
+                         block=FLASH_GQA_BLOCK, kv_heads=FLASH_GQA_KV,
+                         window=FLASH_GQA8_WINDOW), True,
+    ),
+    "flash_bwd_bf16_gqa8_8k_full": (
+        lambda d: _flash(d, dtype=jnp.bfloat16, grad=True, shape=FLASH_GQA8,
                          block=FLASH_GQA_BLOCK, kv_heads=FLASH_GQA_KV), True,
     ),
     "core_qkv_fwd_gpt2_125m": (
@@ -629,6 +642,67 @@ def test_smallthinker_cell_step_moves_only_the_rows_that_land(topo):
         "tpu_custom_call" in line and "/attention/" in line
         for line in text.splitlines()
     ) == 16
+
+
+@pytest.mark.slow
+def test_trinity_mini_cell_step_one_chip(topo):
+    """``trinity-mini.train-8k`` as the benchmark builds it (its family, its
+    job's ``plan``): every attention core a kernel (per layer a forward, the
+    rematerialised forward, dq and dk/dv: 20, 16 of them in the four sliding
+    layers), no T x T scores, no copy of k or v for the eight query heads
+    that share them, the held-experts layer's buffers allocated and not
+    filled, and at least a quarter of the chip filled by the step's own
+    plan."""
+    from chipbench import cells
+    from chipbench import plan as planner
+
+    cell = cells.load_cell("trinity-mini.train-8k")
+    family = cells.load_module("families", cell.config["family"], cell.roots)
+    job = cells.load_module("jobs", cell.workload["job"], cell.roots)
+    kept = {}
+
+    def compile_and_keep(jitted, *args):
+        compiled = jitted.lower(*args).compile()
+        kept["text"], kept["memory"] = (
+            compiled.as_text(), compiled.memory_analysis()
+        )
+        return {}
+
+    original, planner.compile_plan = planner.compile_plan, compile_and_keep
+    try:
+        job.plan(cell, family, list(topo.devices)[:1])
+    finally:
+        planner.compile_plan = original
+    text, mem = kept["text"], kept["memory"]
+    planned = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+    assert 4e9 < planned < 15.75e9
+    attention = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "/attention/" in line
+    ]
+    assert len(attention) == 20
+    assert sum("/attention_sliding/" in line for line in attention) == 16
+    assert sum("/attention_global/" in line for line in attention) == 4
+    assert _kernel_calls(text) == 20 + 4 * 12  # and the grouped matmuls
+    assert not re.findall(r"\[(?:\d+,)*8192,8192\]", text)
+    # k and v stay at 4 heads: nothing of [.., 32, ..] is made from them
+    assert not re.findall(
+        r"bf16\[1,32,8192,128\]\S* broadcast\(|"
+        r"bf16\[1,8192,4,8,128\]", text
+    )
+    # 8 picks a token: buffers of 65,536 rows, allocated under dispatch and
+    # combine alone, and no float32 copy of one
+    assert "f32[8192,8,2048]" not in text
+    buffers = [
+        line for line in text.splitlines()
+        if "AllocateBuffer" in line and "bf16[65536,2048]" in line
+    ]
+    assert buffers and all(
+        "/dispatch/" in line or "/combine/" in line for line in buffers
+    )
+    # the scopes the new readers look for are in the compiled names
+    for scope in ("/qk_norm/", "/attention_gate/", "/post_norm/"):
+        assert scope in text, scope
 
 
 @pytest.mark.slow
