@@ -182,7 +182,7 @@ def giant_constant(ctx):
 def remat_tag_coverage(ctx):
     if ctx.jaxpr is None or ctx.remat in (None, False):
         return
-    from ..parallel.remat import CHECKPOINT_SAVED_NAMES, resolve_remat
+    from ..parallel.remat import kept_names, resolve_remat
 
     try:
         policy = resolve_remat(ctx.remat)
@@ -194,14 +194,17 @@ def remat_tag_coverage(ctx):
     for eqn in _walk_eqns(ctx.jaxpr.jaxpr):
         if eqn.primitive.name == "name":
             tags.add(eqn.params.get("name"))
-    saved = set(CHECKPOINT_SAVED_NAMES)
+    # what the policy keeps beyond ``full`` (which keeps the attention
+    # kernels' residuals too): the activations the models tag
+    saved = set(kept_names(policy)) - set(kept_names("full"))
     if not (tags & saved):
         yield Finding(
             "remat-tag-coverage",
             Severity.WARN,
             "jaxpr",
-            f"remat policy {policy!r} saves only tagged activations "
-            f"({sorted(saved)}) but the traced step contains "
+            f"remat policy {policy!r} saves, beyond what 'full' keeps, "
+            f"only tagged activations ({sorted(saved)}) but the traced "
+            "step contains "
             + (
                 f"no checkpoint_name tags"
                 if not tags

@@ -75,17 +75,18 @@ def remat_block(block_cls, remat, *, static_argnums=(2,), in_scan=False):
     ``remat`` is a bool or a policy name resolved through
     ``parallel.remat`` ("none" returns the class unwrapped). Inside a scan,
     ``prevent_cse=False`` is the standard form (the scan boundary already
-    blocks the unsound CSE remat guards against).
+    blocks the unsound CSE remat guards against). Called while the model
+    is traced, and leaves a ``remat.path`` instant saying what is kept.
     """
-    from ..parallel.remat import checkpoint_policy, resolve_remat
+    from ..parallel.remat import checkpoint_policy, note_remat, resolve_remat
 
     name = resolve_remat(remat)
     if name == "none":
         return block_cls
+    note_remat(name, block_cls.__name__, stacked=in_scan)
     kwargs = {"static_argnums": static_argnums}
     if in_scan:
         kwargs["prevent_cse"] = False
-    policy = checkpoint_policy(name)
-    if policy is not None:
-        kwargs["policy"] = policy
-    return nn.remat(block_cls, **kwargs)
+    return nn.remat(
+        block_cls, policy=checkpoint_policy(name, stacked=in_scan), **kwargs
+    )

@@ -43,6 +43,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas import tpu as pltpu
@@ -62,6 +63,36 @@ _VMEM_DEFAULT = 16 * 2**20
 # makes the block (bq, 8): bq%8==0 and 8==array dim, both legal, at 8x
 # the traffic of a [T] vector — noise next to the O(T*dh) tiles.
 _STAT_LANES = 8
+_LANES = 128
+# The names the forward rules give the two residuals only the forward kernel
+# can produce, ``out`` and ``lse``. Every rematerialising policy of
+# `parallel/remat.py` keeps them (its ``KERNEL_RESIDUALS``), so a
+# checkpointed layer's backward reads them where it would else run the whole
+# forward kernel again to regain them. Not the models' ``attn_out``: in
+# GPT-2 XL the kernel's ``out`` is the padded [B, T, n * 128] and
+# ``attn_out`` its slice, and under one name both would be kept. Outside a
+# checkpoint the tags are the identity.
+RESIDUALS_NAME = "attn_kernel_residuals"
+# ``lse`` again, as whole rows of 128 lanes: what a policy keeps where the
+# layers are a scan's bodies and the kept residuals are stacked over them
+# (`parallel/remat.py` ``kept_names(..., stacked=True)``).
+DENSE_LSE_NAME = "attn_kernel_lse_rows"
+
+
+def _kept(out, lse):
+    """``out`` and ``lse`` under ``RESIDUALS_NAME``, and ``lse`` once more
+    under ``DENSE_LSE_NAME`` as whole rows of 128 lanes. As the kernels read
+    it, [.., T, 8], a tile pads its 8 lanes to 128 in HBM: 16 times the
+    statistic's bytes, which stacked over GPT-2 XL's 48 scanned layers are
+    2.6 GB a chip where 0.16 are meant (compile plan, PR 36). The way there
+    and back is two passes over the padded buffer (0.5-0.9 ms each at the
+    cells' sizes; my chip runs, PR 36), so a policy keeps the rows only where
+    the bytes are stacked; where it keeps ``lse`` as it is, or nothing, the
+    two reshapes meet and cancel when the program is compiled."""
+    out, lse = (checkpoint_name(a, RESIDUALS_NAME) for a in (out, lse))
+    rows = (-1, _LANES) if lse.size % _LANES == 0 else (-1,)
+    dense = checkpoint_name(lse.reshape(rows), DENSE_LSE_NAME)
+    return out, dense.reshape(lse.shape)
 
 
 def _visible(s, qi, ki, window):
@@ -474,10 +505,14 @@ def flash_attention(
 
 
 def _fwd(q, k, v, causal, bq, bk, interpret, window):
-    out, lse = _flash_forward(
+    # ``out`` is named as the caller gets it, [B, T, H, Dh]: named as the
+    # kernel wrote it, [B, H, T, Dh], the backward kernels read it where it
+    # lies but the projection's backward transposes it, and two of three
+    # cells ran 0.5-0.8% slower (my chip runs, PR 36)
+    out, lse = _kept(*_flash_forward(
         q, k, v, causal=causal, bq=bq, bk=bk, interpret=interpret,
         window=window,
-    )
+    ))
     return out, (q, k, v, out, lse)
 
 
@@ -509,7 +544,6 @@ def make_flash_attn_fn(
 # 128-lane blocks, causal.
 # ---------------------------------------------------------------------------
 
-_LANES = 128
 _NT = (((1,), (1,)), ((), ()))  # a @ b^T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
 _TN = (((0,), (0,)), ((), ()))  # a^T @ b
@@ -830,7 +864,7 @@ def flash_attention_qkv(qkv, heads, bq, bk, interpret=False):
     """Causal flash attention over ``qkv [B, T, 3 * H * dh]`` as the fused
     projection wrote it -> ``[B, T, H * dh]``, for heads that fill 128-lane
     blocks (``packs``): q, k and v are read where they lie."""
-    return _fwd_qkv(qkv, heads, bq, bk, interpret)[0]
+    return _packed_forward(*_in_place(qkv, heads), bq, bk, interpret)[0]
 
 
 def _in_place(qkv, heads):
@@ -839,7 +873,9 @@ def _in_place(qkv, heads):
 
 
 def _fwd_qkv(qkv, heads, bq, bk, interpret):
-    out, lse = _packed_forward(*_in_place(qkv, heads), bq, bk, interpret)
+    out, lse = _kept(
+        *_packed_forward(*_in_place(qkv, heads), bq, bk, interpret)
+    )
     return out, (qkv, out, lse)
 
 
@@ -859,13 +895,14 @@ def flash_attention_lanes(q, k, v, dh, bq, bk, interpret=False):
     """Causal flash attention over q, k, v ``[B, T, n * 128]``, heads of
     ``dh`` side by side in the lanes, -> ``[B, T, n * 128]``: the layout a
     head count that does not fill its last block is padded into."""
-    return _fwd_lanes(q, k, v, dh, bq, bk, interpret)[0]
+    n = q.shape[-1] // _LANES
+    return _packed_forward((q, k, v), (0, 0, 0), n, dh, bq, bk, interpret)[0]
 
 
 def _fwd_lanes(q, k, v, dh, bq, bk, interpret):
     n = q.shape[-1] // _LANES
-    out, lse = _packed_forward(
-        (q, k, v), (0, 0, 0), n, dh, bq, bk, interpret
+    out, lse = _kept(
+        *_packed_forward((q, k, v), (0, 0, 0), n, dh, bq, bk, interpret)
     )
     return out, (q, k, v, out, lse)
 

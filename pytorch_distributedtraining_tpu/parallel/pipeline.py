@@ -660,7 +660,7 @@ def pipeline_value_and_grad(
             f"(microbatching is per data-parallel shard)"
         )
 
-    from .remat import checkpoint_policy, resolve_remat
+    from .remat import checkpoint_policy, note_remat, resolve_remat
 
     def chunk_fn(chunk_params, x):
         def body(h, p_layer):
@@ -670,11 +670,12 @@ def pipeline_value_and_grad(
 
     rname = resolve_remat(remat)
     if rname != "none":
-        kw = {"prevent_cse": False}
-        pol = checkpoint_policy(rname)
-        if pol is not None:
-            kw["policy"] = pol
-        chunk_fn = jax.checkpoint(chunk_fn, **kw)
+        # the chunk scans its layers: what it keeps is stacked over them
+        note_remat(rname, "pipeline chunk", stacked=True)
+        chunk_fn = jax.checkpoint(
+            chunk_fn, prevent_cse=False,
+            policy=checkpoint_policy(rname, stacked=True),
+        )
 
     perm = _rank_major_perm(L, n, v, lpv)
     stages_rm = (

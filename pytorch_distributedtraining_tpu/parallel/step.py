@@ -103,12 +103,14 @@ class TrainStep:
         self.policy = policy or Policy()
         # Activation rematerialization (FSDP/DeepSpeed activation-
         # checkpointing twin at the step level), resolved through the named
-        # registry (parallel/remat.py): "full" recomputes the whole forward
-        # (~1/3 extra FLOPs for minimum HBM), "dots" saves matmul outputs,
-        # "names"/"offload" save exactly the checkpoint_name-tagged
-        # activations (attention outputs in the model zoo). Finer-grained
-        # per-block remat lives in the models' own `remat` flags
-        # (gpt2/vit/swinir); both compose (inner checkpoints nest).
+        # registry (parallel/remat.py): "full" recomputes the forward but
+        # for a blockwise attention kernel's, whose output and row
+        # statistics it keeps (~1/3 extra FLOPs for near-minimum HBM),
+        # "dots" saves matmul outputs too, "names"/"offload" the
+        # checkpoint_name-tagged activations (attention outputs in the
+        # model zoo). Finer-grained per-block remat lives in the models'
+        # own `remat` flags (gpt2/vit/swinir); both compose (inner
+        # checkpoints nest).
         # The step owns the mesh, so the step says where activations live:
         # while the loss is traced the batch's layout is published and the
         # model's residual stream is pinned to it (spec.pin_batch), else

@@ -1096,8 +1096,9 @@ class Stoke:
 
         # the eager .backward() path honors Policy.remat too (the fused
         # TrainStep wires it separately), resolved through the same named
-        # registry: "full" recomputes the forward, "dots"/"names"/"offload"
-        # save the policy's subset (parallel/remat.py)
+        # registry: "full" recomputes the forward (keeping an attention
+        # kernel's residuals), "dots"/"names"/"offload" save the policy's
+        # subset (parallel/remat.py)
         fwd_loss = apply_remat(fwd_loss, self.policy.remat)
 
         def loss_grad(params, model_state, x, y, rng, scaler_state):

@@ -476,3 +476,25 @@ def test_the_attention_core_is_the_one_smallthinker_runs():
     assert afmoe.attention_core is smallthinker.attention_core
     assert afmoe.banded_attention is smallthinker.banded_attention
     assert afmoe.ExpertLayer is ExpertLayer
+
+
+def test_rematerialised_layers_run_each_attention_kernel_once(monkeypatch):
+    """Under ``remat=True`` a layer keeps its attention kernel's output and
+    row statistics: the gradient holds the kernels of the plain model plus
+    the rematerialised grouped matmuls, and no second attention forward. A
+    forward rule that did not name its residuals ran one more a layer."""
+    from pytorch_distributedtraining_tpu.ops import pallas_attn
+
+    def kernels(remat):
+        cfg, model, params, bias, x, y = build("some", remat=remat)
+        return cfg.num_hidden_layers, str(jax.make_jaxpr(jax.grad(
+            lambda p: cross_entropy_loss(apply(model, p, bias, x), y)
+        ))(params)).count("pallas_call[")
+
+    layers, plain = kernels(False)
+    _, kept = kernels(True)
+    monkeypatch.setattr(pallas_attn, "_kept", lambda out, lse: (out, lse))
+    _, untagged = kernels(True)
+    assert untagged - kept == layers
+    # what is still rematerialised: the expert layers' forward kernels
+    assert kept > plain

@@ -453,6 +453,31 @@ def _mesh(topo, n=None, **axes):
     return make_mesh(MeshSpec(**axes), devices=devices)
 
 
+@pytest.mark.parametrize("scan", [False, True])
+def test_remat_cuts_the_planned_peak(topo, scan):
+    """Per-block remat cuts the step's planned temporaries, unrolled and
+    scanned, with the attention kernels' residuals among what ``full``
+    keeps. Asked of the TPU's planner: XLA:CPU expands jax.checkpoint's
+    barrier before it schedules and hoists an unrolled program's
+    recomputation, so its plan for ``full`` is ``none``'s
+    (``tests/test_remat_scan.py::test_trainstep_memory_monotonic`` holds the
+    scanned layout there)."""
+    from pytorch_distributedtraining_tpu.models import GPT2Config
+    from pytorch_distributedtraining_tpu.parallel import DDP
+
+    temporaries = {}
+    for remat in ("none", "full"):
+        cfg = GPT2Config.tiny(
+            n_embd=128, n_head=2, n_layer=4, n_positions=1024, remat=remat,
+            scan_layers=scan,
+        )
+        step, state, batch = _gpt2_step(_mesh(topo, 1, dp=1), DDP(), cfg)
+        compiled, text = _lower(step, state, batch)
+        assert _kernel_calls(text) == (3 if scan else 3 * 4)
+        temporaries[remat] = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries["full"] < 0.5 * temporaries["none"], temporaries
+
+
 @pytest.mark.slow
 def test_gpt2_125m_train_step_one_chip(topo):
     """``jax.grad`` of GPT-2 125M at [8, 1024] through TrainStep — and
@@ -533,10 +558,13 @@ def _kernel_calls(text):
 def test_gpt2_xl_zero3_at_the_cells_sizes(topo):
     """``gpt2-xl.zero3-4chip`` as the benchmark runs it (16 x 1,024, scan +
     remat): each chip runs the attention kernels over its own 4 sequences
-    and all 25 heads (forward, the forward again in the rematerialised
-    backward, dq, dk/dv: four calls in the scanned bodies), gathers one
-    scanned layer's kernels at a time, and plans under 4.5 GB of
-    temporaries (7.6 GB while the batch was gathered instead)."""
+    and all 25 heads (forward, dq, dk/dv: three calls in the scanned bodies;
+    four, the forward again, before a rematerialised layer kept the
+    kernel's output and row statistics), gathers one scanned layer's
+    kernels at a time, and plans under 6 GB of temporaries by
+    ``memory_analysis`` (5.68: the 48 layers' kept residuals are 0.82 GB and
+    this count takes what the scan hands its backward twice; 4.04 before,
+    7.6 while the batch was gathered instead)."""
     from pytorch_distributedtraining_tpu.models import GPT2Config
     from pytorch_distributedtraining_tpu.parallel import ZeRO3
 
@@ -545,8 +573,12 @@ def test_gpt2_xl_zero3_at_the_cells_sizes(topo):
     )
     step, state, batch = _gpt2_step(_mesh(topo, fsdp=4), ZeRO3(), cfg, batch=16)
     compiled, text = _lower(step, state, batch)
-    assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
-    assert _kernel_calls(text) == 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
+    assert _kernel_calls(text) == 3
+    # the row statistics are kept as whole 128-lane rows: [.., 1024, 8] as
+    # the kernels read it is padded to 16 times its bytes (2.6 GB here)
+    assert "f32[48,6656,128]" in text
+    assert not re.findall(r"f32\[48,4,26,1024,8\]", text)
     assert _largest_scores(text) is None
     # c_attn's sharded matmul lands its gathered column slices in a T-minor
     # ``qkv``; row-major (what the kernels' operands would hand back through
@@ -560,17 +592,13 @@ def test_gpt2_xl_zero3_at_the_cells_sizes(topo):
     assert not [s for s in gathered if s[0] == 16], gathered
 
 
-@pytest.mark.slow
-def test_smallthinker_cell_step_one_chip(topo):
-    """``smallthinker-21b-a3b.train-16k`` as the benchmark builds it (its
-    family, its job's ``plan``): every attention core a kernel (per layer a
-    forward, the rematerialised forward, dq and dk/dv: 16), no T x T
-    scores, no copy of k or v for the seven query heads that share them,
-    and at least a quarter of the chip filled by the step's own plan."""
+def _cell_step(topo, name):
+    """(compiled text, planned bytes) of a cell's step as the benchmark
+    builds it (its family, its job's ``plan``) for one described chip."""
     from chipbench import cells
     from chipbench import plan as planner
 
-    cell = cells.load_cell("smallthinker-21b-a3b.train-16k")
+    cell = cells.load_cell(name)
     family = cells.load_module("families", cell.config["family"], cell.roots)
     job = cells.load_module("jobs", cell.workload["job"], cell.roots)
     kept = {}
@@ -587,17 +615,33 @@ def test_smallthinker_cell_step_one_chip(topo):
         job.plan(cell, family, list(topo.devices)[:1])
     finally:
         planner.compile_plan = original
-    text, mem = kept["text"], kept["memory"]
-    planned = mem.temp_size_in_bytes + mem.argument_size_in_bytes
-    assert 4e9 < planned < 15.75e9
-    attention = [
+    mem = kept["memory"]
+    return kept["text"], mem.temp_size_in_bytes + mem.argument_size_in_bytes
+
+
+def _attention_calls(text):
+    return [
         line for line in text.splitlines()
         if "tpu_custom_call" in line and "/attention/" in line
     ]
-    assert len(attention) == 16
-    assert sum("/attention_sliding/" in line for line in attention) == 12
-    assert sum("/attention_global/" in line for line in attention) == 4
-    assert _kernel_calls(text) == 16 + 4 * 12  # and the grouped matmuls
+
+
+@pytest.mark.slow
+def test_smallthinker_cell_step_one_chip(topo):
+    """``smallthinker-21b-a3b.train-16k`` as the benchmark builds it (its
+    family, its job's ``plan``): every attention core a kernel (per layer a
+    forward, dq and dk/dv: 12; a rematerialised layer keeps the forward's
+    output and row statistics, and ran it again, 16, before it did), no
+    T x T scores, no copy of k or v for the seven query heads that share
+    them, and at least a quarter of the chip filled by the step's own
+    plan."""
+    text, planned = _cell_step(topo, "smallthinker-21b-a3b.train-16k")
+    assert 4e9 < planned < 15.75e9
+    attention = _attention_calls(text)
+    assert len(attention) == 12
+    assert sum("/attention_sliding/" in line for line in attention) == 9
+    assert sum("/attention_global/" in line for line in attention) == 3
+    assert _kernel_calls(text) == 12 + 4 * 12  # and the grouped matmuls
     assert not re.findall(r"\[(?:\d+,)*16384,16384\]", text)
     # k and v stay at 4 heads: nothing of [.., 28, ..] is made from them
     assert not re.findall(
@@ -611,25 +655,8 @@ def test_smallthinker_cell_step_moves_only_the_rows_that_land(topo):
     """The same step (PR 33): the held-experts layer's dispatch and combine
     are row loops over buffers that are allocated, not filled, and combine
     makes no float32 copy of the whole N x k buffer; the attention kernels
-    are the 16 they were."""
-    from chipbench import cells
-    from chipbench import plan as planner
-
-    cell = cells.load_cell("smallthinker-21b-a3b.train-16k")
-    family = cells.load_module("families", cell.config["family"], cell.roots)
-    job = cells.load_module("jobs", cell.workload["job"], cell.roots)
-    kept = {}
-
-    def compile_and_keep(jitted, *args):
-        kept["text"] = jitted.lower(*args).compile().as_text()
-        return {}
-
-    original, planner.compile_plan = planner.compile_plan, compile_and_keep
-    try:
-        job.plan(cell, family, list(topo.devices)[:1])
-    finally:
-        planner.compile_plan = original
-    text = kept["text"]
+    are the 12 of the test above."""
+    text, _ = _cell_step(topo, "smallthinker-21b-a3b.train-16k")
     assert "f32[16384,6,2560]" not in text
     buffers = [
         line for line in text.splitlines()
@@ -638,52 +665,38 @@ def test_smallthinker_cell_step_moves_only_the_rows_that_land(topo):
     assert buffers and all(
         "/dispatch/" in line or "/combine/" in line for line in buffers
     )
-    assert sum(
-        "tpu_custom_call" in line and "/attention/" in line
-        for line in text.splitlines()
-    ) == 16
+    assert len(_attention_calls(text)) == 12
+
+
+@pytest.mark.slow
+def test_glm_cell_step_one_chip(topo):
+    """``glm-4.7-flash.train-4k`` as the benchmark builds it: five layers,
+    each attention core a kernel run once forward and twice backward (15
+    calls; 20 while the rematerialised forward ran again), no T x T scores,
+    and at least a quarter of the chip filled by the step's own plan."""
+    text, planned = _cell_step(topo, "glm-4.7-flash.train-4k")
+    assert 4e9 < planned < 15.75e9
+    assert len(_attention_calls(text)) == 15
+    assert not re.findall(r"\[(?:\d+,)*4096,4096\]", text)
 
 
 @pytest.mark.slow
 def test_trinity_mini_cell_step_one_chip(topo):
     """``trinity-mini.train-8k`` as the benchmark builds it (its family, its
-    job's ``plan``): every attention core a kernel (per layer a forward, the
-    rematerialised forward, dq and dk/dv: 20, 16 of them in the four sliding
-    layers), no T x T scores, no copy of k or v for the eight query heads
+    job's ``plan``): every attention core a kernel (per layer a forward, dq
+    and dk/dv: 15, 12 of them in the four sliding layers; 20 and 16 while
+    the rematerialised forward ran again), no T x T scores, no copy of k or
+    v for the eight query heads
     that share them, the held-experts layer's buffers allocated and not
     filled, and at least a quarter of the chip filled by the step's own
     plan."""
-    from chipbench import cells
-    from chipbench import plan as planner
-
-    cell = cells.load_cell("trinity-mini.train-8k")
-    family = cells.load_module("families", cell.config["family"], cell.roots)
-    job = cells.load_module("jobs", cell.workload["job"], cell.roots)
-    kept = {}
-
-    def compile_and_keep(jitted, *args):
-        compiled = jitted.lower(*args).compile()
-        kept["text"], kept["memory"] = (
-            compiled.as_text(), compiled.memory_analysis()
-        )
-        return {}
-
-    original, planner.compile_plan = planner.compile_plan, compile_and_keep
-    try:
-        job.plan(cell, family, list(topo.devices)[:1])
-    finally:
-        planner.compile_plan = original
-    text, mem = kept["text"], kept["memory"]
-    planned = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+    text, planned = _cell_step(topo, "trinity-mini.train-8k")
     assert 4e9 < planned < 15.75e9
-    attention = [
-        line for line in text.splitlines()
-        if "tpu_custom_call" in line and "/attention/" in line
-    ]
-    assert len(attention) == 20
-    assert sum("/attention_sliding/" in line for line in attention) == 16
-    assert sum("/attention_global/" in line for line in attention) == 4
-    assert _kernel_calls(text) == 20 + 4 * 12  # and the grouped matmuls
+    attention = _attention_calls(text)
+    assert len(attention) == 15
+    assert sum("/attention_sliding/" in line for line in attention) == 12
+    assert sum("/attention_global/" in line for line in attention) == 3
+    assert _kernel_calls(text) == 15 + 4 * 12  # and the grouped matmuls
     assert not re.findall(r"\[(?:\d+,)*8192,8192\]", text)
     # k and v stay at 4 heads: nothing of [.., 32, ..] is made from them
     assert not re.findall(

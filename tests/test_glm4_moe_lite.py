@@ -394,3 +394,25 @@ def test_router_state_and_counters_ride_the_train_step(accum):
     for layer in state.model_state[glm.ROUTER_STATE].values():
         bias = np.asarray(layer["moe"]["bias"])
         assert 0 < np.max(np.abs(bias)) <= 3 * cfg.bias_update_rate + 1e-9
+
+
+def test_rematerialised_layers_run_each_attention_kernel_once(monkeypatch):
+    """Under ``remat=True`` a layer keeps its attention kernel's output and
+    row statistics: the gradient holds the kernels of the plain model plus
+    the rematerialised grouped matmuls, and no second attention forward. A
+    forward rule that did not name its residuals ran one more a layer."""
+    from pytorch_distributedtraining_tpu.ops import pallas_attn
+
+    def kernels(remat):
+        cfg, model, params, state, x, y = build("some", remat=remat)
+        return cfg.num_hidden_layers, str(jax.make_jaxpr(jax.grad(
+            lambda p: cross_entropy_loss(model.apply({"params": p, **state}, x), y)
+        ))(params)).count("pallas_call[")
+
+    layers, plain = kernels(False)
+    _, kept = kernels(True)
+    monkeypatch.setattr(pallas_attn, "_kept", lambda out, lse: (out, lse))
+    _, untagged = kernels(True)
+    assert untagged - kept == layers
+    # what is still rematerialised: the expert layers' forward kernels
+    assert kept > plain

@@ -49,8 +49,8 @@ def _loss(model, mesh):
 
 
 @contextlib.contextmanager
-def _said():
-    """The ``attention.path`` instants of the traces made inside."""
+def _said(name="attention.path"):
+    """The instants of that name left by the traces made inside."""
     tracer = trace.get_tracer()
     was = tracer.enabled
     trace.enable(crash_handler=False)
@@ -59,8 +59,7 @@ def _said():
     try:
         yield said
         said.extend(
-            r["attrs"] for r in trace.records()
-            if r["name"] == "attention.path"
+            r["attrs"] for r in trace.records() if r["name"] == name
         )
     finally:
         trace.clear()
@@ -239,8 +238,8 @@ def test_kernels_placed_on_four_devices_equal_one_devices(
     """On a mesh the kernels run under ``shard_map`` over the mesh the step
     published, each device over its own sequences: loss and gradients equal
     one device's, with the layers unrolled and as a rematerialised scan
-    (the forward kernels run again in the backward), and stay at the
-    einsums' within the kernels' rounding."""
+    (whose backward reads the forward kernel's kept residuals), and stay at
+    the einsums' within the kernels' rounding."""
     cfg = GPT2Config.tiny(
         n_embd=64 * heads, n_head=heads, n_positions=128, n_layer=2,
         scan_layers=stack == "scan_remat", remat=stack == "scan_remat",
@@ -272,6 +271,75 @@ def test_kernels_placed_on_four_devices_equal_one_devices(
         np.testing.assert_allclose(
             np.asarray(b), np.asarray(c), rtol=2e-3, atol=2e-5
         )
+
+
+@pytest.mark.parametrize("heads", [2, 3])
+@pytest.mark.parametrize("remat", [True, "names", "dots", False])
+def test_rematerialised_scan_on_eight_devices_runs_the_forward_once(
+    on_a_tpu, remat, heads, monkeypatch
+):
+    """``remat_block(..., in_scan=True)`` under ``nn.scan``, the core under
+    the ``shard_map`` a step's mesh asks for (the way GPT-2 XL's cell runs):
+    the gradient's scanned bodies hold three kernels (forward; dq and dk/dv
+    in the backward's body) under every policy, as many as with no remat at
+    all. A forward rule that did not name its residuals ran a fourth, the
+    forward again. Packed heads (2) and an odd count padded to lanes (3)."""
+    cfg = GPT2Config.tiny(
+        n_embd=64 * heads, n_head=heads, n_positions=128, n_layer=2,
+        scan_layers=True, remat=remat,
+    )
+    tok = _tokens(cfg, 8, 128)
+    params = GPT2(cfg, attn_fn=default_attention).init(
+        jax.random.PRNGKey(0), tok
+    )["params"]
+
+    def kernels():
+        gpt2_module._kernel_or_einsum_attention.clear_cache()
+        jaxpr = str(jax.make_jaxpr(jax.grad(_loss(GPT2(cfg), _mesh(8))))(
+            params, tok
+        ))
+        assert "shard_map" in jaxpr and "scan[" in jaxpr
+        return jaxpr.count("pallas_call[")
+
+    assert kernels() == 3
+    monkeypatch.setattr(pallas_attn, "_kept", lambda out, lse: (out, lse))
+    assert kernels() == (3 if remat is False else 4)
+
+
+def test_remat_says_what_it_keeps():
+    """Each trace of a model that wraps its blocks leaves one ``remat.path``
+    instant for the wrapped class, each trace of a function ``apply_remat``
+    wrapped one more, with the policy and the names kept."""
+    from pytorch_distributedtraining_tpu.parallel.remat import (
+        CHECKPOINT_SAVED_NAMES, apply_remat,
+    )
+
+    from pytorch_distributedtraining_tpu.models.scan_utils import (
+        stack_layer_params,
+    )
+
+    cfg = GPT2Config.tiny(n_positions=16, n_layer=2, remat=True)
+    scanned = GPT2Config.tiny(
+        n_positions=16, n_layer=2, remat=True, scan_layers=True
+    )
+    tok = _tokens(cfg, 2, 16)
+    params = GPT2(cfg).init(jax.random.PRNGKey(0), tok)["params"]
+    kernels = [pallas_attn.RESIDUALS_NAME]
+    with _said("remat.path") as said:
+        jax.make_jaxpr(_loss(GPT2(cfg), None))(params, tok)
+        jax.make_jaxpr(_loss(GPT2(scanned), None))(
+            stack_layer_params(dict(params), "h_", 2, "h"), tok
+        )
+        for policy in ("none", True, "dots", "names"):
+            jax.make_jaxpr(apply_remat(lambda x: x * 2, policy))(1.0)
+    assert [(s["policy"], s["keeps"], s["where"]) for s in said] == [
+        ("full", kernels, "Block"),
+        # a scan's bodies: what is kept is stacked, the statistics as rows
+        ("full", kernels + [pallas_attn.DENSE_LSE_NAME], "Block"),
+        ("full", kernels, "apply_remat"),
+        ("dots", kernels, "apply_remat"),
+        ("names", list(CHECKPOINT_SAVED_NAMES), "apply_remat"),
+    ]
 
 
 def test_twelve_layers_share_one_trace_of_the_core(monkeypatch):

@@ -39,10 +39,16 @@ from pytorch_distributedtraining_tpu.observe.memory import (
     MemoryStats,
     tune_batch_size,
 )
+from pytorch_distributedtraining_tpu.analyze.trace_rules import _walk_eqns
+from pytorch_distributedtraining_tpu.ops import pallas_attn
 from pytorch_distributedtraining_tpu.parallel.remat import (
+    CHECKPOINT_SAVED_NAMES,
+    KERNEL_LSE_ROWS,
+    KERNEL_RESIDUALS,
     REMAT_POLICIES,
     apply_remat,
     checkpoint_policy,
+    kept_names,
     resolve_remat,
 )
 
@@ -69,6 +75,7 @@ def test_resolve_remat_forms():
     assert resolve_remat("0") == "none"
     assert resolve_remat("1") == "full"
     assert resolve_remat("DOTS") == "dots"
+    assert REMAT_POLICIES == ("none", "full", "dots", "names", "offload")
     for name in REMAT_POLICIES:
         assert resolve_remat(name) == name
     with pytest.raises(ValueError, match="remat"):
@@ -77,9 +84,25 @@ def test_resolve_remat_forms():
 
 def test_checkpoint_policy_registry():
     assert checkpoint_policy("none") is None
-    assert checkpoint_policy("full") is None  # full = checkpoint, no policy
-    for name in ("dots", "names", "offload"):
+    # every policy that rematerialises keeps the attention kernels'
+    # residuals: none asks for a kernel to be run twice
+    for name in ("full", "dots", "names", "offload"):
         assert callable(checkpoint_policy(name))
+        assert KERNEL_RESIDUALS in kept_names(name)
+    assert kept_names("none") == kept_names("none", stacked=True) == ()
+    assert kept_names("full") == (KERNEL_RESIDUALS,)
+    assert kept_names("names") == CHECKPOINT_SAVED_NAMES
+    assert set(CHECKPOINT_SAVED_NAMES) == {"attn_out", KERNEL_RESIDUALS}
+    # where the kept residuals are stacked over a scan's layers, the row
+    # statistics are kept as dense rows besides
+    for name in ("full", "dots", "names", "offload"):
+        assert kept_names(name, stacked=True) == kept_names(name) + (
+            KERNEL_LSE_ROWS,
+        )
+    # the kernels' file names its residuals itself; the registry's
+    # spellings are those
+    assert pallas_attn.RESIDUALS_NAME == KERNEL_RESIDUALS
+    assert pallas_attn.DENSE_LSE_NAME == KERNEL_LSE_ROWS
 
 
 def test_apply_remat_none_is_identity():
@@ -96,6 +119,172 @@ def test_policy_remat_validates_at_construction():
     assert DDP(remat=True).remat_policy == "full"
     with pytest.raises(ValueError, match="remat"):
         DDP(remat="bogus")
+
+
+# ----------------------------------------- what a checkpointed layer keeps
+
+_B, _T, _H, _DH = 1, 256, 2, 64
+
+
+def _layer_split(w, x):
+    q, k, v = (
+        a.reshape(_B, _T, _H, _DH) for a in jnp.split(x @ w, 3, axis=-1)
+    )
+    out = pallas_attn.flash_attention(q, k, v, True, 128, 128, True, 128)
+    return out.reshape(_B, _T, -1)
+
+
+def _layer_qkv(w, x):
+    return pallas_attn.flash_attention_qkv(x @ w, _H, 128, 128, True)
+
+
+def _layer_lanes(w, x):
+    q, k, v = jnp.split(x @ w, 3, axis=-1)
+    return pallas_attn.flash_attention_lanes(q, k, v, _DH, 128, 128, True)
+
+
+KERNEL_LAYERS = {
+    "flash_attention": _layer_split,
+    "flash_attention_qkv": _layer_qkv,
+    "flash_attention_lanes": _layer_lanes,
+}
+
+
+def _layer_inputs():
+    kx, kw = jax.random.split(jax.random.PRNGKey(0))
+    d = _H * _DH
+    return (
+        jax.random.normal(kw, (d, 3 * d), jnp.float32) * 0.05,
+        jax.random.normal(kx, (_B, _T, d), jnp.float32),
+    )
+
+
+def _grad_of(layer, policy):
+    wrapped = apply_remat(layer, policy)
+    return jax.grad(lambda w, x: jnp.sum(wrapped(w, x) ** 2), argnums=(0, 1))
+
+
+def _primitives(jaxpr) -> list:
+    """Every equation's primitive name, sub-jaxprs included."""
+    return [eqn.primitive.name for eqn in _walk_eqns(jaxpr)]
+
+
+def _kernels_in(fn, *args) -> int:
+    return _primitives(jax.make_jaxpr(fn)(*args).jaxpr).count("pallas_call")
+
+
+@pytest.mark.parametrize("policy", ["full", "dots", "names"])
+@pytest.mark.parametrize("kernel", sorted(KERNEL_LAYERS))
+def test_checkpointed_layer_runs_the_forward_kernel_once(kernel, policy):
+    """The gradient of a checkpointed layer holds three kernels (forward,
+    dq, dk/dv): the backward reads the kept ``out`` and ``lse`` where it
+    would else run the forward kernel again. Gradients equal the
+    uncheckpointed layer's to the bit."""
+    layer, args = KERNEL_LAYERS[kernel], _layer_inputs()
+    assert _kernels_in(_grad_of(layer, policy), *args) == 3
+    got = jax.jit(_grad_of(layer, policy))(*args)
+    want = jax.jit(_grad_of(layer, "none"))(*args)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNEL_LAYERS))
+def test_untagged_forward_rule_runs_twice_under_full(kernel, monkeypatch):
+    """What the tag spares: a forward rule as it was before it named its
+    residuals leaves ``full`` nothing of the layer to keep, and the
+    rematerialised computation holds a second forward kernel."""
+    monkeypatch.setattr(pallas_attn, "_kept", lambda out, lse: (out, lse))
+    layer, args = KERNEL_LAYERS[kernel], _layer_inputs()
+    assert _kernels_in(_grad_of(layer, "full"), *args) == 4
+    assert _kernels_in(_grad_of(layer, "none"), *args) == 3
+
+
+@pytest.mark.parametrize("stacked", [False, True])
+@pytest.mark.parametrize("kernel", sorted(KERNEL_LAYERS))
+def test_full_keeps_one_output_and_one_statistic(kernel, stacked):
+    """Beside its arguments a layer under ``full`` saves the kernel's
+    ``out`` and ``lse``, once each, and nothing else: ``lse`` as the kernel
+    wrote it, or, where what is kept is stacked over a scan's layers, as
+    whole rows of 128 lanes alone (a [.., T, 8] array is padded to 16 times
+    its bytes in a TPU's memory)."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    layer, args = KERNEL_LAYERS[kernel], _layer_inputs()
+    saved = saved_residuals(
+        jax.checkpoint(layer, policy=checkpoint_policy("full", stacked)),
+        *args,
+    )
+    kept = [
+        (aval.shape, aval.dtype) for aval, why in saved
+        if "from the argument" not in why
+    ]
+    assert len(kept) == 2, saved
+    out_shape = (
+        (_B, _T, _H, _DH) if kernel == "flash_attention"
+        else (_B, _T, _H * _DH)
+    )
+    lse_shape = (_B, _H, _T, pallas_attn._STAT_LANES)
+    if stacked:
+        lse_shape = (_B * _H * _T * pallas_attn._STAT_LANES // 128, 128)
+        assert any(f"named '{KERNEL_LSE_ROWS}'" in why for _, why in saved)
+    assert sorted(kept) == sorted(
+        [(out_shape, jnp.float32), (lse_shape, jnp.float32)]
+    )
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNEL_LAYERS))
+def test_without_remat_the_program_is_what_it_was(kernel, monkeypatch):
+    """Outside a checkpoint the tags are identities: the gradient's
+    equations are the untagged rule's plus the three ``name``s and the two
+    reshapes around the dense rows', which cancel when the program is
+    compiled: the compiled program is the untagged rule's text."""
+    import collections
+    import re
+
+    layer, args = KERNEL_LAYERS[kernel], _layer_inputs()
+
+    def program():
+        fn = _grad_of(layer, False)
+        prims = _primitives(jax.make_jaxpr(fn)(*args).jaxpr)
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        # without the stack frames' tables, what points into them, and the
+        # counters in the instructions' names
+        text = text[text.index("\n\n%"):]
+        text = re.sub(r", metadata=\{[^}]*\}", "", text)
+        return collections.Counter(prims), re.sub(r"[._]\d+\b", "", text)
+
+    prims, text = program()
+    monkeypatch.setattr(pallas_attn, "_kept", lambda out, lse: (out, lse))
+    was_prims, was_text = program()
+    assert prims - was_prims == {"name": 3, "reshape": 2}
+    assert not was_prims - prims
+    assert text == was_text
+
+
+@pytest.mark.parametrize("tagged", [False, True])
+def test_lint_knows_what_names_keeps_beyond_full(tagged):
+    """``remat-tag-coverage`` warns where ``names`` would keep nothing that
+    ``full`` does not keep: a step whose only tags are the kernels' own is
+    such a step; one that tags ``attn_out`` is not."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from pytorch_distributedtraining_tpu.analyze.registry import (
+        RULES, AnalysisContext,
+    )
+
+    def layer(w, x):
+        out = _layer_qkv(w, x)
+        return checkpoint_name(out, "attn_out") if tagged else out
+
+    jaxpr = jax.make_jaxpr(_grad_of(layer, "names"))(*_layer_inputs())
+    assert KERNEL_RESIDUALS in str(jaxpr)
+    found = list(RULES["remat-tag-coverage"].fn(
+        AnalysisContext(jaxpr=jaxpr, remat="names")
+    ))
+    assert len(found) == (0 if tagged else 1)
+    if found:
+        assert "behaves like remat='full'" in found[0].message
+        assert "attn_out" in found[0].message
 
 
 # ---------------------------------------------------- numerical equivalence
@@ -282,22 +471,26 @@ def _gpt2_step(devices, remat, scan_layers, tok):
 
 
 def test_trainstep_memory_monotonic(devices8):
-    """Per-block remat must cut the compiled step's projected peak HBM:
-    full < none, and scan+full < loop none (the ISSUE's bigger-batches
-    claim, asserted on XLA's own memory plan)."""
+    """Per-block remat must cut the compiled step's projected peak HBM
+    (the ISSUE's bigger-batches claim, asserted on XLA's own memory plan):
+    scan+full < scan none and < loop none. The unrolled pair is asked of
+    the TPU's planner (``tests/test_chip_compile.py::
+    test_remat_cuts_the_planned_peak``), which plans full at a fifth of
+    none: XLA:CPU expands jax.checkpoint's optimization barrier before it
+    schedules, hoists the unrolled program's recomputation (95 dots against
+    75: it is there) to where the forward ran, and plans the same peak for
+    both, to the byte."""
     tok = jnp.arange(8 * 128, dtype=jnp.int32).reshape(8, 128) % 256
     tgt = jnp.roll(tok, -1, axis=1)
     batch = (tok, tgt)
 
     peaks = {}
-    for scan in (False, True):
-        for remat in ("none", "full"):
-            step, state = _gpt2_step(devices8, remat, scan, tok)
-            mem = step.memory_analysis(state, batch)
-            assert mem is not None and mem.temp_bytes > 0
-            peaks[(scan, remat)] = mem.peak_bytes
+    for scan, remat in ((False, "none"), (True, "none"), (True, "full")):
+        step, state = _gpt2_step(devices8, remat, scan, tok)
+        mem = step.memory_analysis(state, batch)
+        assert mem is not None and mem.temp_bytes > 0
+        peaks[(scan, remat)] = mem.peak_bytes
 
-    assert peaks[(False, "full")] < peaks[(False, "none")], peaks
     assert peaks[(True, "full")] < peaks[(True, "none")], peaks
     assert peaks[(True, "full")] < peaks[(False, "none")], peaks
 

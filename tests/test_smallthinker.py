@@ -368,3 +368,25 @@ def test_counters_ride_the_train_step_and_the_loss_falls():
     assert float(metrics["expert_load_max_over_mean"]) >= 1.0
     # 3 of 8 experts held, 2 picks a token, 4 layers of 32 tokens
     assert 0 < float(metrics["assignments_landed"]) <= 4 * 32 * 2
+
+
+def test_rematerialised_layers_run_each_attention_kernel_once(monkeypatch):
+    """Under ``remat=True`` a layer keeps its attention kernel's output and
+    row statistics: the gradient holds the kernels of the plain model plus
+    the rematerialised grouped matmuls, and no second attention forward. A
+    forward rule that did not name its residuals ran one more a layer."""
+    from pytorch_distributedtraining_tpu.ops import pallas_attn
+
+    def kernels(remat):
+        cfg, model, params, x, y = build("some", remat=remat)
+        return cfg.num_hidden_layers, str(jax.make_jaxpr(jax.grad(
+            lambda p: cross_entropy_loss(model.apply({"params": p}, x), y)
+        ))(params)).count("pallas_call[")
+
+    layers, plain = kernels(False)
+    _, kept = kernels(True)
+    monkeypatch.setattr(pallas_attn, "_kept", lambda out, lse: (out, lse))
+    _, untagged = kernels(True)
+    assert untagged - kept == layers
+    # what is still rematerialised: the expert layers' forward kernels
+    assert kept > plain
