@@ -308,12 +308,18 @@ def train(rank: int, world_size: int, epochs: int, opt=None):
               f"{epochs}-epoch schedule; nothing left to train")
 
     loss = None
+    dispatched = 0
     try:
         for e in range(start_epoch, epochs):
             for iteration, batch in enumerate(training_dataloader, 1):
                 if e == start_epoch and iteration <= skip_iters:
                     continue
                 state, metrics = step(state, batch)
+                dispatched += 1
+                if dispatched == 2:  # the first steady step: where start-up went
+                    print("===> " + telemetry.describe_startup(
+                        telemetry.startup_report()
+                    ))
                 loss = metrics["loss"]
                 if capture_prof is not None:
                     capture_prof.note_step()

@@ -21,6 +21,7 @@ from __future__ import annotations
 import argparse
 import os
 import sys
+import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
@@ -37,6 +38,7 @@ from pytorch_distributedtraining_tpu.data import (
 )
 from pytorch_distributedtraining_tpu.losses import feat_loss
 from pytorch_distributedtraining_tpu.models import SwinIR
+from pytorch_distributedtraining_tpu.observe import trace as telemetry
 from pytorch_distributedtraining_tpu.observe import wandb
 from pytorch_distributedtraining_tpu.optim import OneCycleLR, ReduceLROnPlateau
 from pytorch_distributedtraining_tpu.stoke import (
@@ -104,6 +106,11 @@ def train(train_dataloader, stoke_model: Stoke, scheduler1, scheduler2, epoch: i
             # the fused TrainStep on its own — analyze it explicitly so
             # --analyze means the same thing on every driver.
             _maybe_analyze(stoke_model, inputs, targets)
+        if epoch == 0 and idx == stoke_model.grad_accum_steps:
+            # the first optimizer step is out: where start-up went
+            stoke_model.print_on_devices(telemetry.describe_startup(
+                telemetry.startup_report(until=time.perf_counter())
+            ))
         outputs = stoke_model.model(inputs)
         train_loss = stoke_model.loss(outputs, targets)
 

@@ -441,7 +441,9 @@ class DataLoader:
 
         # pooled fetch: workers load samples, a feeder thread keeps
         # `prefetch` collated batches in flight ahead of the consumer
+        t_pool, had = time.perf_counter(), self._pool
         pool, fetch, keep_pool = self._get_pool()
+        fresh = pool is not had  # workers to start, not a pool kept up
         q: queue.Queue = queue.Queue(maxsize=self.prefetch)
         stop = threading.Event()
         _END, _ERR = object(), object()
@@ -512,6 +514,15 @@ class DataLoader:
                 # consumer blocked on the feeder = input_wait bucket
                 with telemetry.span("input.wait", "input", queued=q.qsize()):
                     item = q.get()
+                if fresh:  # pool creation to the workers' first batch
+                    fresh = False
+                    telemetry.add_span(
+                        "loader.start_workers", "startup", t_pool,
+                        time.perf_counter() - t_pool, {
+                            "workers": self.num_workers,
+                            "context": self._mp_context or "thread",
+                        },
+                    )
                 if item is _END:
                     return
                 if isinstance(item, tuple) and len(item) == 2 and item[0] is _ERR:

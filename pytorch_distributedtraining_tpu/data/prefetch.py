@@ -91,6 +91,8 @@ class _StageStats:
 # With only (source, queue, events, stats) referenced, dropping the last
 # consumer reference triggers __del__ → close() → the feeder exits.
 def _feed(source, mesh, spec, q, stop, drained, stats):
+    t_start = time.perf_counter()  # the thread's start
+
     def put(item) -> bool:
         while not stop.is_set():
             try:
@@ -109,6 +111,12 @@ def _feed(source, mesh, spec, q, stop, drained, stats):
                     fault_point("loader.stage", index=i)
                     item = ("dev", place_on_mesh(batch, mesh, spec))
                     stats.staged += 1
+                    if i == 0:  # thread start to the first batch placed
+                        telemetry.add_span(
+                            "prefetch.start", "startup", t_start,
+                            time.perf_counter() - t_start,
+                            {"depth": q.maxsize},
+                        )
                 except Exception as e:
                     # degrade, don't drop: THIS batch (and all later ones)
                     # go to the consumer as host data for synchronous

@@ -58,6 +58,7 @@ BUCKETS = (
 CATEGORY_BUCKET = {
     "step": "productive",
     "compile": "compile",
+    "startup": "compile",  # once-a-process phases before the first step
     "input": "input_wait",
     "checkpoint": "checkpoint",
     "collective": "collective",

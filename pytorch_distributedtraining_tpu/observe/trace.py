@@ -27,6 +27,23 @@ is the substrate they now all feed:
   per-process file under :func:`run_dir` on an unhandled exception or a
   fault-site trip, so the launcher's restart gate can name what the
   dying step was doing (``runtime/launch.py`` reads these files).
+- the **start-up ledger** — spans of category ``compile`` and ``startup``
+  happen once a process or once a program, so they are kept with no
+  knob, in a bounded list of their own (``LEDGER_CAPACITY`` records, the
+  FIRST that many; ``ledger_dropped`` counts the rest), and in the ring
+  too while it is on. jax's own compile events join them by program name
+  (one ``jax.monitoring`` listener, registered when the first span opens
+  with jax loaded, never at import): ``compile.trace``,
+  ``compile.lower``, ``compile.cache_read`` (the persistent cache hit)
+  or ``compile.xla`` (it missed), each with ``fun_name``, placed on
+  ``perf_counter`` at the callback less the duration, nested by
+  containment, with the innermost open program span's name and ``step``
+  (``program``, ``step``). The listener fires on compile paths only; a
+  steady dispatch never reaches it. :func:`startup_report` reads the
+  ledger: the phases before the first steady step in order, compile
+  seconds as unions and as self time (a jitted function traced inside a
+  jitted step counts once), the gaps under no program span by their
+  neighbours, and what compiled after the end, by ``step``.
 
 Stdlib-only by contract: the bench parent and the launcher (both jax-free)
 may import this, and package import must not touch a backend
@@ -45,13 +62,21 @@ lr|apply|fused``, ``facade.note_loss``, ``facade.shard_batch``,
 ``facade.forward``, ``facade.loss.compute``, ``facade.loss_fetch.flush``;
 ``input.fetch``, ``input.wait``, ``loader.collect``, ``loader.collate``;
 ``checkpoint.write|snapshot|wait``, ``preempt.agreement``;
-``serve.prefill|decode|spec_verify|tile.dispatch``.
+``serve.prefill|decode|spec_verify|tile.dispatch``. Category
+``startup``, kept in the ledger: ``runtime.initialize`` (the ledger's
+origin; carries the ``perf_counter`` / ``time_ns`` pair and the process's
+age), ``mesh.make``, ``state.create``, ``facade.construct``,
+``facade.init_state``, ``facade.program.compile+dispatch`` (category
+``compile``: a facade program's first call, ``program`` its attribute),
+``loader.start_workers``, ``prefetch.start``; and jax's
+``compile.trace|lower|cache_read|xla``.
 
 Env knobs (mirrored by ``TPUConfig.telemetry`` / ``TPUConfig.trace_dir``
 through the stoke facade, and by both drivers' ``--trace``):
 
 - ``GRAFT_TELEMETRY`` = 1/0 — enable the ring buffer (span collection,
-  Chrome export) + crash handler. Annotations need no knob.
+  Chrome export) + crash handler. Annotations and the start-up ledger
+  need no knob.
 - ``GRAFT_TRACE`` = a directory — implies telemetry, and names where
   the Chrome trace JSON is exported.
 - ``GRAFT_RUN_DIR`` — run-scoped scratch directory (default
@@ -78,7 +103,10 @@ __all__ = [
     "add_span",
     "dispatch_span",
     "bucket_dispatch_span",
-    "note_recompile",
+    "startup_records",
+    "startup_report",
+    "describe_startup",
+    "clock_anchor",
     "enable",
     "disable",
     "enabled",
@@ -101,6 +129,7 @@ _TRUTHY = ("1", "true", "on", "yes")
 CATEGORIES = (
     "step",        # compiled-step dispatch + device sync -> productive
     "compile",     # trace/lower/compile, warmup first-calls
+    "startup",     # once-a-process phases before the first steady step
     "input",       # blocked on the input pipeline
     "checkpoint",  # checkpoint write windows
     "collective",  # explicit cross-process sync (barriers, agreements)
@@ -109,6 +138,10 @@ CATEGORIES = (
     "membership",  # elastic membership transitions (runtime/membership.py)
     "other",
 )
+
+# kept in the start-up ledger with no knob: once a process or once a program
+LEDGER_CATEGORIES = frozenset(("compile", "startup"))
+LEDGER_CAPACITY = 512  # the first that many; the rest are counted
 
 
 def run_dir() -> str:
@@ -180,6 +213,12 @@ class Tracer:
         self.enabled = False
         self.capacity = capacity
         self.dropped = 0  # records evicted by the ring bound
+        # the start-up ledger: the first LEDGER_CAPACITY records of
+        # LEDGER_CATEGORIES, kept whether or not the ring is on
+        self._ledger: list = []
+        self.ledger_dropped = 0  # records past the ledger's bound
+        self.steady_at: float | None = None  # the first warm dispatch
+        self.small_traces = [0, 0.0]  # traces under TRACE_FLOOR_S: count, sum
 
     # -- recording -----------------------------------------------------
 
@@ -199,17 +238,47 @@ class Tracer:
         self, name: str, cat: str, t0: float, dur: float,
         attrs: dict | None = None, depth: int | None = None,
     ) -> None:
-        """Record an externally-timed span into the ring (it cannot be
-        an annotation after the fact: the program's own sites use
-        ``with span(...)``)."""
-        if not self.enabled:
+        """Record an externally-timed span: into the ring while it is on,
+        into the start-up ledger where ``cat`` is one of
+        ``LEDGER_CATEGORIES`` (it cannot be an annotation after the fact:
+        the program's own sites use ``with span(...)``). With no ``depth``
+        the span is placed by containment: under the spans open on this
+        thread, and over the ledger's records that it holds."""
+        kept = cat in LEDGER_CATEGORIES
+        if not (kept or self.enabled):
             return
-        self._append({
+        rec = {
             "name": name, "cat": cat, "t0": t0, "dur": max(0.0, dur),
             "tid": threading.get_ident(),
             "depth": len(self._stack()) if depth is None else depth,
             "attrs": dict(attrs) if attrs else {},
-        })
+        }
+        if kept:
+            self._keep(rec, contains=depth is None)
+        if self.enabled:
+            self._append(rec)
+
+    def _keep(self, rec: dict, contains: bool) -> None:
+        """One record into the ledger, the bound counted. A record timed
+        from outside closes after what it holds: those records (this
+        thread's, started inside it) go one level down."""
+        with self._lock:
+            if contains:
+                for held in reversed(self._ledger):
+                    if held["tid"] != rec["tid"]:
+                        continue
+                    if held["t0"] < rec["t0"]:
+                        break
+                    held["depth"] += 1
+            if len(self._ledger) < LEDGER_CAPACITY:
+                self._ledger.append(rec)
+            else:
+                self.ledger_dropped += 1
+
+    def note_steady(self) -> None:
+        """Stamp the first warm dispatch: where start-up ends."""
+        if self.steady_at is None:
+            self.steady_at = time.perf_counter()
 
     def instant(self, name: str, cat: str = "other", **attrs) -> None:
         if not self.enabled:
@@ -235,6 +304,18 @@ class Tracer:
         with self._lock:
             self._buf.clear()
             self.dropped = 0
+
+    def startup_records(self) -> list:
+        """The start-up ledger, in the order its records closed."""
+        with self._lock:
+            return list(self._ledger)
+
+    def clear_startup(self) -> None:
+        with self._lock:
+            self._ledger.clear()
+            self.ledger_dropped = 0
+            self.steady_at = None
+            self.small_traces = [0, 0.0]
 
     def open_spans(self) -> list:
         """The current thread's in-flight span frames, innermost last."""
@@ -344,11 +425,13 @@ _ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is loaded
 def _annotation_type():
     """``jax.profiler.TraceAnnotation`` with a span's ``set``, found
     through ``sys.modules`` (this module imports no jax) and resolved
-    once; None while jax is not loaded (the launcher, the bench parent)."""
+    once; None while jax is not loaded (the launcher, the bench parent).
+    The ledger's compile listener is registered at the same moment."""
     global _ANNOTATION
     profiler = sys.modules.get("jax.profiler")
     if profiler is None:
         return None
+    _listen_to_compiles()
 
     class _Annotation(profiler.TraceAnnotation):
         __slots__ = ()
@@ -358,6 +441,100 @@ def _annotation_type():
 
     _ANNOTATION = _Annotation
     return _Annotation
+
+
+# -- jax's compile events, into the ledger ---------------------------------
+
+_JAX_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_JAX_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_JAX_BACKEND = "/jax/core/compile/backend_compile_duration"
+_JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+COMPILE_EVENTS = (
+    "compile.trace", "compile.lower", "compile.cache_read", "compile.xla",
+)
+# Every jnp function called while a program is traced is a jitted function
+# with a trace event of its own, ten thousand to a model and most under a
+# millisecond, each inside the trace of what called it. Those shorter than
+# this are counted (``Tracer.small_traces``) and not kept.
+TRACE_FLOOR_S = 0.005
+# what numbers the dispatches of an owner, and how far the dispatch in
+# flight is behind it: ``dispatch_span``'s count, the facade's steps
+_STEP_COUNTS = (("_telemetry_dispatches", -1), ("_opt_steps", 0))
+_LISTENING = False
+
+
+def _listen_to_compiles() -> None:
+    """Register the ledger's two listeners with ``jax.monitoring``, once.
+    Called where jax is known to be loaded (``_annotation_type``)."""
+    global _LISTENING
+    if _LISTENING:
+        return
+    _LISTENING = True
+    import jax.monitoring as monitoring  # jax is loaded: a dict lookup
+
+    monitoring.register_event_listener(_on_jax_event)
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def _on_jax_event(event: str, **_) -> None:
+    if event == _JAX_CACHE_HIT:  # its backend duration follows, same thread
+        _TRACER._tls.cache_hit = True
+
+
+def _on_jax_duration(event: str, duration: float, fun_name="", **_) -> None:
+    """One ledger record per trace, lowering and executable, by program
+    name. jax calls this at the event's end, on the thread that compiled,
+    and on compile paths only."""
+    if event == _JAX_TRACE:
+        if duration < TRACE_FLOOR_S:
+            small = _TRACER.small_traces
+            small[0] += 1
+            small[1] += duration
+            return
+        name = "compile.trace"
+    elif event == _JAX_LOWER:
+        name = "compile.lower"
+    elif event == _JAX_BACKEND:
+        tls = _TRACER._tls
+        hit, tls.cache_hit = getattr(tls, "cache_hit", False), False
+        name = "compile.cache_read" if hit else "compile.xla"
+    else:
+        return
+    now = time.perf_counter()
+    attrs = {"fun_name": str(fun_name)}
+    stack = _TRACER._stack()
+    if stack:  # a cold dispatch, a phase of start-up
+        attrs["program"] = stack[-1].name
+        if "step" in stack[-1].attrs:
+            attrs["step"] = stack[-1].attrs["step"]
+    else:  # a warm dispatch is an annotation: ask the thread's frames
+        attrs.update(_dispatch_in_flight())
+    _TRACER.add_span(name, "compile", now - duration, duration, attrs)
+
+
+def _dispatch_in_flight() -> dict:
+    """``program`` and ``step`` of the dispatch that a compile event fell
+    in, read off the calling thread's frames: the innermost ``self`` that
+    counts its dispatches (``_STEP_COUNTS``). A warm dispatch leaves
+    nothing on the ring's stack, and this runs only when something
+    compiled, so the steady path pays nothing for it."""
+    frame = sys._getframe(2)
+    try:
+        while frame is not None:
+            owner = frame.f_locals.get("self")
+            counts = getattr(owner, "__dict__", None)
+            if isinstance(counts, dict):
+                for key, behind in _STEP_COUNTS:
+                    n = counts.get(key)
+                    if isinstance(n, int):
+                        return {
+                            "program": type(owner).__name__,
+                            "step": n + behind,
+                        }
+            frame = frame.f_back
+    except Exception:  # noqa: BLE001 — a listener must never fail a compile
+        pass
+    return {}
 
 
 def _annotate(name: str, attrs: dict):
@@ -371,10 +548,14 @@ def _annotate(name: str, attrs: dict):
 
 def _open(tracer: "Tracer", name: str, cat: str, attrs: dict):
     """A span: an annotation only while ``tracer`` is off, a ring record
-    (with its annotation inside) while it is on."""
-    if not tracer.enabled:
-        return _annotate(name, attrs)
-    return _LiveSpan(tracer, name, cat, attrs)
+    (with its annotation inside) while it is on; one of
+    ``LEDGER_CATEGORIES`` is a record either way, for the ledger, once
+    jax is loaded (a process without jax compiles nothing)."""
+    if tracer.enabled or (
+        cat in LEDGER_CATEGORIES and (_ANNOTATION or _annotation_type())
+    ):
+        return _LiveSpan(tracer, name, cat, attrs)
+    return _annotate(name, attrs)
 
 
 class _LiveSpan:
@@ -501,6 +682,8 @@ def dispatch_span(owner, kind: str):
     """
     n = getattr(owner, "_telemetry_dispatches", 0)
     owner._telemetry_dispatches = n + 1
+    if n == 1:  # the first warm dispatch: where start-up ends
+        _TRACER.note_steady()
     return _dispatch(kind, bool(n), {"kind": kind, "step": n})
 
 
@@ -529,25 +712,6 @@ def bucket_dispatch_span(owner, kind: str, bucket):
     return _dispatch(kind, was_warm, {"kind": kind, "bucket": bucket})
 
 
-def note_recompile(owner, jitted, kind: str) -> None:
-    """Emit a ``recompile`` instant when a jitted callable's cache grew
-    after the owner's warm point (a mid-run retrace — shape drift).
-    No-op when the runtime doesn't expose ``_cache_size``."""
-    if not _TRACER.enabled:
-        return
-    try:
-        size = jitted._cache_size()
-    except Exception:  # noqa: BLE001 — introspection, version-dependent
-        return
-    seen = getattr(owner, "_telemetry_cache_seen", None)
-    owner._telemetry_cache_seen = size
-    if seen is not None and size > seen:
-        _TRACER.instant(
-            f"{kind}.recompile", "compile", kind=kind,
-            cache_entries=size,
-        )
-
-
 def add_span(name, cat, t0, dur, attrs=None, depth=None) -> None:
     _TRACER.add_span(name, cat, t0, dur, attrs, depth=depth)
 
@@ -558,6 +722,300 @@ def records() -> list:
 
 def clear() -> None:
     _TRACER.clear()
+
+
+# -- the start-up ledger's reader ---------------------------------------
+
+ORIGIN_SPAN = "runtime.initialize"  # the ledger's origin, where it is there
+REPORT_TOP = 10  # the costliest (event, fun_name) pairs a report names
+REPORT_AFTER_END = 20  # the compile events past the end a report lists
+
+
+def clock_anchor() -> dict:
+    """What aligns ``perf_counter`` (the ledger's clock) with a wall clock
+    and so with a profile taken later: both read at one moment, and the
+    process's age then, where ``/proc/self/stat`` gives it (everything
+    before the ledger's origin: the interpreter's start, the imports)."""
+    anchor = {"perf_counter": time.perf_counter(), "time_ns": time.time_ns()}
+    try:
+        with open("/proc/self/stat", encoding="ascii") as f:
+            # field 22, counted past the parenthesised command name
+            started = float(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime", encoding="ascii") as f:
+            uptime = float(f.read().split()[0])
+        anchor["process_age_s"] = uptime - started / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        pass
+    return anchor
+
+
+def startup_records() -> list:
+    return _TRACER.startup_records()
+
+
+def _union(spans) -> list:
+    """Sorted, disjoint ``(lo, hi)`` covering what ``spans`` cover."""
+    out: list = []
+    for lo, hi in sorted(s for s in spans if s[1] > s[0]):
+        if out and lo <= out[-1][1]:
+            if hi > out[-1][1]:
+                out[-1] = (out[-1][0], hi)
+        else:
+            out.append((lo, hi))
+    return out
+
+
+def _total(spans) -> float:
+    return sum(hi - lo for lo, hi in spans)
+
+
+def _covered(spans, by) -> float:
+    """Seconds of the disjoint ``spans`` inside the disjoint ``by``."""
+    return sum(
+        max(0.0, min(hi, b_hi) - max(lo, b_lo))
+        for lo, hi in spans for b_lo, b_hi in by
+    )
+
+
+def _minus(spans, by) -> list:
+    """The disjoint ``spans`` less the disjoint, sorted ``by``."""
+    out = []
+    for lo, hi in spans:
+        for b_lo, b_hi in by:
+            if b_hi <= lo or b_lo >= hi:
+                continue
+            if b_lo > lo:
+                out.append((lo, b_lo))
+            lo = max(lo, b_hi)
+        if hi > lo:
+            out.append((lo, hi))
+    return out
+
+
+def _clip(rec: dict, lo: float, hi: float) -> tuple:
+    lo, hi = max(lo, rec["t0"]), min(hi, rec["t0"] + rec["dur"])
+    return lo, max(lo, hi)
+
+
+def _nest(recs: list, lo: float, hi: float) -> list:
+    """``(record, interval, own, top)`` of one thread's records, nested by
+    containment: a record is inside the last one before it that has not
+    ended when it starts. ``interval`` is clipped to ``[lo, hi]`` and to
+    the record it is inside; ``own`` is the interval less the records
+    directly inside it, as disjoint intervals; ``top`` says that it is
+    inside none."""
+    rows, stack = [], []  # a row: [rec, interval, intervals inside, top]
+    for rec in sorted(recs, key=lambda r: (r["t0"], -r["dur"])):
+        while stack and rec["t0"] >= stack[-1][0]["t0"] + stack[-1][0]["dur"]:
+            stack.pop()
+        span = _clip(rec, *(stack[-1][1] if stack else (lo, hi)))
+        if stack:
+            stack[-1][2].append(span)
+        row = [rec, span, [], not stack]
+        rows.append(row)
+        stack.append(row)
+    return [
+        (rec, span, _minus([span], _union(inside)), top)
+        for rec, span, inside, top in rows
+    ]
+
+
+def startup_report(until: float | None = None) -> dict:
+    """The start-up ledger, read: where the time went between the origin
+    and ``until`` (a ``perf_counter`` stamp; by default the first warm
+    dispatch of a step, or now where there has been none). The origin is
+    the process's start where the ledger's first record
+    (``runtime.initialize``, else the earliest) carries the process's age,
+    so that the interpreter's start and the imports are the first gap,
+    ``process start`` to ``runtime.initialize``; else that record's start.
+
+    ``phases`` are the program's top-level spans on the origin's thread in
+    order (``seconds``; ``self_seconds`` less the spans and the compile
+    events inside; ``compile_seconds`` the union of the compile events
+    inside), ``gaps`` what lies under no program span of that thread, each
+    named by the spans on either side (``background_seconds`` of a gap
+    are under a top-level span of another thread: the loader's workers
+    starting while this thread waits; ``outside_program_s`` is the gaps
+    less those). ``seconds`` is the phases' seconds plus the gaps', and
+    ``split`` divides the same seconds into the program's own
+    (``program_s``), jax's compile events wherever they fell on that
+    thread (``compile_s``) and the rest of the gaps (``outside_s``).
+    ``trace_s``, ``lower_s``, ``cache_read_s`` and ``xla_s`` are UNIONS
+    per thread (a jitted function traced inside a jitted step counts
+    once), summed over threads; ``costliest`` ranks ``(event, fun_name)``
+    by self seconds, with counts (a name traced twice shows). ``by_name``
+    has every span name's union, self and compile seconds, ``background``
+    the top-level spans of other threads, ``after_end`` the compile events
+    that began past the end, with the ``step`` of the dispatch they fell
+    in: which step recompiled."""
+    recs = _TRACER.startup_records()
+    first = next(
+        (r for r in recs if r["name"] == ORIGIN_SPAN),
+        min(recs, key=lambda r: r["t0"], default=None),
+    )
+    now = time.perf_counter()
+    clock = {
+        k: first["attrs"][k]
+        for k in ("perf_counter", "time_ns", "process_age_s")
+        if first and k in first["attrs"]
+    }
+    born = "process_age_s" in clock  # the timeline starts with the process
+    if born:
+        origin = clock["perf_counter"] - clock["process_age_s"]
+    else:
+        origin = first["t0"] if first else now
+    end = until if until is not None else (_TRACER.steady_at or now)
+    end = max(end, origin)
+    main = first["tid"] if first else None
+    events = [r for r in recs if r["name"] in COMPILE_EVENTS]
+    before = [r for r in recs if r["t0"] < end]
+    threads = sorted({r["tid"] for r in before}, key=lambda t: t != main)
+
+    unions = dict.fromkeys(COMPILE_EVENTS, 0.0)
+    costs: dict = {}  # (event, fun_name) -> [self seconds, count]
+    phases, background, by_name = [], [], {}
+    program_s, main_busy = 0.0, []  # of the origin's thread
+    for tid in threads:
+        mine = [r for r in before if r["tid"] == tid]
+        happened = [r for r in mine if r["name"] in COMPILE_EVENTS]
+        for kind in COMPILE_EVENTS:
+            unions[kind] += _total(_union(
+                _clip(r, origin, end) for r in happened if r["name"] == kind
+            ))
+        busy = _union(_clip(r, origin, end) for r in happened)
+        if tid == main:
+            main_busy = busy
+        for rec, _, own, _ in _nest(happened, origin, end):
+            cost = costs.setdefault(
+                (rec["name"], rec["attrs"].get("fun_name", "")), [0.0, 0]
+            )
+            cost[0] += _total(own)
+            cost[1] += 1
+        named: dict = {}  # this thread's spans, by name
+        for rec, span, own, top in _nest(
+            [r for r in mine if r["name"] not in COMPILE_EVENTS], origin, end
+        ):
+            self_s = _total(own) - _covered(own, busy)
+            entry = named.setdefault(rec["name"], [0, [], 0.0])
+            entry[0] += 1
+            entry[1].append(span)
+            entry[2] += self_s
+            if tid == main:
+                program_s += self_s
+            if top:
+                row = {
+                    "name": rec["name"], "at": span[0] - origin,
+                    "seconds": span[1] - span[0], "self_seconds": self_s,
+                    "compile_seconds": _covered([span], busy),
+                    "attrs": {
+                        k: _jsonable(v) for k, v in rec["attrs"].items()
+                    },
+                }
+                if tid == main:
+                    phases.append(row)
+                else:
+                    background.append(dict(row, thread=tid))
+        for name, (count, spans, self_s) in named.items():
+            spans = _union(spans)
+            entry = by_name.setdefault(name, {
+                "count": 0, "seconds": 0.0, "self_seconds": 0.0,
+                "compile_seconds": 0.0,
+            })
+            entry["count"] += count
+            entry["seconds"] += _total(spans)
+            entry["self_seconds"] += self_s
+            entry["compile_seconds"] += _covered(spans, busy)
+
+    busy = main_busy
+    elsewhere = [
+        (origin + row["at"], origin + row["at"] + row["seconds"], row["name"])
+        for row in background
+    ]
+    gaps, edge, after = [], origin, "process start" if born else "origin"
+    for row in phases + [{"name": "end", "at": end - origin, "seconds": 0.0}]:
+        lo = origin + row["at"]
+        if lo > edge:
+            gaps.append({
+                "after": after, "before": row["name"], "at": edge - origin,
+                "seconds": lo - edge,
+                "compile_seconds": _covered([(edge, lo)], busy),
+                # what the program did meanwhile on its other threads
+                "background_seconds": _covered([(edge, lo)], _union(
+                    span[:2] for span in elsewhere
+                )),
+                "background": sorted({
+                    name for b_lo, b_hi, name in elsewhere
+                    if b_lo < lo and b_hi > edge
+                }),
+            })
+        edge, after = max(edge, lo + row["seconds"]), row["name"]
+    outside_program_s = sum(
+        g["seconds"] - g["background_seconds"] for g in gaps
+    )
+    late = sorted(
+        (r for r in events if r["t0"] >= end), key=lambda r: r["t0"]
+    )
+    return {
+        "origin": origin, "end": end, "seconds": end - origin,
+        "clock": clock or clock_anchor(), "records": len(recs),
+        "dropped": _TRACER.ledger_dropped,
+        # a SUM, most of it inside the traces that are kept
+        "small_traces": dict(
+            zip(("count", "seconds"), _TRACER.small_traces)
+        ),
+        "phases": phases, "gaps": gaps,
+        "outside_program_s": outside_program_s,
+        "split": {
+            "program_s": program_s, "compile_s": _total(busy),
+            "outside_s": sum(
+                g["seconds"] - g["compile_seconds"] for g in gaps
+            ),
+        },
+        **{k.split(".", 1)[1] + "_s": v for k, v in unions.items()},
+        "costliest": [
+            {"event": event, "fun_name": fun, "self_seconds": s, "count": n}
+            for (event, fun), (s, n) in sorted(
+                costs.items(), key=lambda kv: -kv[1][0]
+            )[:REPORT_TOP]
+        ],
+        "by_name": by_name, "background": background,
+        "after_end": [
+            {"event": r["name"], "at": r["t0"] - origin, "seconds": r["dur"],
+             **r["attrs"]}
+            for r in late[:REPORT_AFTER_END]
+        ],
+        "after_end_count": len(late),
+    }
+
+
+def describe_startup(report: dict) -> str:
+    """One line for a driver's log at its first steady step: the six
+    longest phases and gaps of a :func:`startup_report`, the compile
+    unions, and the costliest program name."""
+    parts = sorted(
+        [(p["seconds"], p["name"]) for p in report["phases"]]
+        + [(g["seconds"], f"{g['after']}..{g['before']}")
+           for g in report["gaps"]],
+        reverse=True,
+    )[:6]
+    line = (
+        f"start-up {report['seconds']:.2f} s to the first steady step: "
+        + ", ".join(f"{name} {seconds:.2f}" for seconds, name in parts)
+        + "; compiling "
+        + ", ".join(
+            f"{kind} {report[kind + '_s']:.2f}"
+            for kind in ("trace", "lower", "cache_read", "xla")
+        )
+    )
+    if report["costliest"]:
+        top = report["costliest"][0]
+        line += (
+            f"; costliest {top['event']} of {top['fun_name']} "
+            f"{top['self_seconds']:.2f} s"
+        )
+    if report["after_end_count"]:
+        line += f"; {report['after_end_count']} compile events since"
+    return line
 
 
 def export_chrome_trace(path: str | None = None) -> str:

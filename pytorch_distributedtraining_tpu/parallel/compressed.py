@@ -635,6 +635,4 @@ class CompressedGradStep:
 
         state = self._with_residuals(state)
         with telemetry.dispatch_span(self, "CompressedGradStep"):
-            out = self._jitted(state, batch, jnp.float32(lr_factor))
-        telemetry.note_recompile(self, self._jitted, "CompressedGradStep")
-        return out
+            return self._jitted(state, batch, jnp.float32(lr_factor))

@@ -555,9 +555,7 @@ class HierGradStep:
         # degraded inter-slice link stretching every sync
         fault_point("comm.dcn")
         with telemetry.dispatch_span(self, "HierGradStep"):
-            out = self._jitted(state, batch, jnp.float32(lr_factor))
-        telemetry.note_recompile(self, self._jitted, "HierGradStep")
-        return out
+            return self._jitted(state, batch, jnp.float32(lr_factor))
 
 
 # -- slow-slice degradation --------------------------------------------------

@@ -1089,6 +1089,4 @@ class PipelineStep:
         from ..observe import trace as telemetry
 
         with telemetry.dispatch_span(self, "PipelineStep"):
-            out = self._jitted(state, batch, jnp.float32(lr_factor))
-        telemetry.note_recompile(self, self._jitted, "PipelineStep")
-        return out
+            return self._jitted(state, batch, jnp.float32(lr_factor))

@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import logging
 
+from ..observe import trace as telemetry
 from ..precision import ScalerState
 from .policy import Policy
 from .spec import host_offload_supported, tree_shardings
@@ -54,8 +55,22 @@ def create_train_state(
     """Build a sharded TrainState; returns ``(state, sharding_tree)``.
 
     Either pass a Flax ``model`` + ``sample_input`` (``model.init`` is used)
-    or a custom ``init_fn(rng) -> (params, model_state)``.
+    or a custom ``init_fn(rng) -> (params, model_state)``. In the start-up
+    ledger this is ``state.create``: init's two traces (shapes, then the
+    program), its executable and its dispatch; the run itself is
+    asynchronous and ends wherever the caller next waits.
     """
+    with telemetry.span("state.create", "startup", policy=policy.name):
+        return _create_train_state(
+            model, sample_input, init_fn, tx, mesh, policy, rng,
+            scaler_state, init_kwargs,
+        )
+
+
+def _create_train_state(
+    model, sample_input, init_fn, tx, mesh, policy, rng, scaler_state,
+    init_kwargs,
+):
     rng = jax.random.PRNGKey(0) if rng is None else rng
 
     def build(rng):

@@ -566,9 +566,7 @@ class TrainStep:
         if not hasattr(self, "_telemetry_dispatches"):  # the first call
             remember_program(self._jitted, (state, batch, lr_factor))
         with telemetry.dispatch_span(self, "TrainStep"):
-            out = self._jitted(state, batch, lr_factor)
-        telemetry.note_recompile(self, self._jitted, "TrainStep")
-        return out
+            return self._jitted(state, batch, lr_factor)
 
 
 class MultiStep:

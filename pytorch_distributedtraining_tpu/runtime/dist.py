@@ -30,6 +30,8 @@ import logging
 
 import jax
 
+from ..observe import trace as telemetry
+
 logger = logging.getLogger(__name__)
 
 _INITIALIZED = False
@@ -153,12 +155,25 @@ def initialize(
     pod's native rendezvous). A single-process run (no env, no args) is a
     no-op — exactly like the reference running un-launched.
 
-    Idempotent; registers :func:`shutdown` via atexit.
+    Idempotent; registers :func:`shutdown` via atexit. The first call is the
+    origin of the start-up ledger (``observe.trace.startup_report``): its
+    span carries the clock pair that aligns the ledger with a profile taken
+    later, and the process's age.
     """
-    global _INITIALIZED
     if _INITIALIZED:
         return
+    with telemetry.span(
+        "runtime.initialize", "startup", **telemetry.clock_anchor()
+    ):
+        _initialize(
+            coordinator_address, num_processes, process_id, local_device_ids
+        )
 
+
+def _initialize(
+    coordinator_address, num_processes, process_id, local_device_ids
+) -> None:
+    global _INITIALIZED
     # comm/compute overlap flags must be in the env before the backend
     # (and before jax.distributed.initialize creates one); GRAFT_OVERLAP=0
     # opts out — see enable_latency_hiding_scheduler

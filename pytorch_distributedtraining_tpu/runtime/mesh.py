@@ -31,6 +31,8 @@ import jax
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: F401  (re-export)
 
+from ..observe import trace as telemetry
+
 AXIS_ORDER = ("pp", "dp", "fsdp", "sp", "tp", "ep")
 
 # id(mesh) -> (mesh, name of its DCN/slice axis). Populated by
@@ -116,23 +118,26 @@ def make_mesh(spec: MeshSpec | None = None, *, devices=None, **axes) -> Mesh:
 
     Uses ``mesh_utils.create_device_mesh`` so the axis order maps well onto
     the ICI torus (innermost axes get the fastest links); falls back to a
-    plain reshape for virtual/CPU devices.
+    plain reshape for virtual/CPU devices. Its span in the start-up ledger
+    (``mesh.make``) holds the backend's start where this is the first call
+    that asks for devices.
     """
     if spec is None:
         spec = MeshSpec(**axes)
-    devices = list(jax.devices()) if devices is None else list(devices)
-    if spec.size != len(devices):
-        raise ValueError(
-            f"MeshSpec wants {spec.size} devices ({spec.shape()}), "
-            f"got {len(devices)}"
-        )
-    shape = tuple(spec.shape().values())
-    names = tuple(spec.shape().keys())
-    if devices[0].platform == "tpu":
-        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    else:
-        dev_array = np.asarray(devices).reshape(shape)
-    return Mesh(dev_array, names)
+    with telemetry.span("mesh.make", "startup", **spec.shape()):
+        devices = list(jax.devices()) if devices is None else list(devices)
+        if spec.size != len(devices):
+            raise ValueError(
+                f"MeshSpec wants {spec.size} devices ({spec.shape()}), "
+                f"got {len(devices)}"
+            )
+        shape = tuple(spec.shape().values())
+        names = tuple(spec.shape().keys())
+        if devices[0].platform == "tpu":
+            dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
+        else:
+            dev_array = np.asarray(devices).reshape(shape)
+        return Mesh(dev_array, names)
 
 
 def make_hybrid_mesh(

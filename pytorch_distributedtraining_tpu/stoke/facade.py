@@ -552,6 +552,7 @@ class Stoke:
         fused_optimizer: bool | None = None,
     ):
         _dist.initialize()
+        t_construct = time.perf_counter()
         self._module = _apply_scan_layers_env(model)
         self._loss_callable = loss
         self.batch_size_per_device = int(batch_size_per_device)
@@ -964,6 +965,10 @@ class Stoke:
         # every facade span (never ``state.step``: that is a device value)
         self.programs = {}
         self._opt_steps = 0
+        _telemetry.add_span(
+            "facade.construct", "startup", t_construct,
+            time.perf_counter() - t_construct, {"policy": self.policy.name},
+        )
 
         if sample_input is not None:
             self.init(sample_input)
@@ -988,6 +993,18 @@ class Stoke:
         automatically by the first ``.model(inputs)``."""
         if self._state is not None:
             return self
+        with _telemetry.span("facade.init_state", "startup"):
+            self._init_state(sample_input)
+        if self.verbose:
+            n = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(self._state.params))
+            self.print_on_devices(
+                f"Stoke[tpu]: {type(self._module).__name__} {n/1e6:.2f}M params, "
+                f"policy={self.policy.name}, mesh={dict(self.mesh.shape)}, "
+                f"precision={self.fp16 or 'fp32'}, accum={self.grad_accum_steps}"
+            )
+        return self
+
+    def _init_state(self, sample_input) -> None:
         sample = jax.tree.map(
             lambda x: jnp.asarray(x)[:1] if hasattr(x, "shape") else x, sample_input
         )
@@ -1010,14 +1027,6 @@ class Stoke:
         if self._pending_pretrained is not None:
             self.load_model_state(self._pending_pretrained)
             self._pending_pretrained = None
-        if self.verbose:
-            n = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(self._state.params))
-            self.print_on_devices(
-                f"Stoke[tpu]: {type(self._module).__name__} {n/1e6:.2f}M params, "
-                f"policy={self.policy.name}, mesh={dict(self.mesh.shape)}, "
-                f"precision={self.fp16 or 'fp32'}, accum={self.grad_accum_steps}"
-            )
-        return self
 
     @property
     def state(self):
@@ -1301,10 +1310,15 @@ class Stoke:
         at its first call its abstract signature is kept
         (``observe.profiling.remember_program``)."""
         n = self.programs.get(name, 0)
-        if not n:
-            remember_program(self._jits[name], args, kwargs)
         self.programs[name] = n + 1
-        return getattr(self, name)(*args, **kwargs)
+        if n:
+            return getattr(self, name)(*args, **kwargs)
+        remember_program(self._jits[name], args, kwargs)
+        with _telemetry.span(
+            "facade.program.compile+dispatch", "compile", program=name,
+            step=self._opt_steps,
+        ):
+            return getattr(self, name)(*args, **kwargs)
 
     # -- eager-parity runtime surface --------------------------------------
 

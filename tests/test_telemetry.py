@@ -110,27 +110,6 @@ class TestSpans:
         assert recs[1]["name"] == "train_step.dispatch"
         assert recs[1]["cat"] == "step"
 
-    def test_note_recompile_fires_on_cache_growth(self, live_tracer):
-        class Owner:
-            pass
-
-        class FakeJit:
-            def __init__(self):
-                self.n = 1
-
-            def _cache_size(self):
-                return self.n
-
-        o, j = Owner(), FakeJit()
-        trace.note_recompile(o, j, "train_step")  # seeds the baseline
-        trace.note_recompile(o, j, "train_step")  # unchanged: no event
-        j.n = 2
-        trace.note_recompile(o, j, "train_step")  # growth: retrace marker
-        instants = [r for r in trace.records() if r.get("instant")]
-        assert len(instants) == 1
-        assert instants[0]["name"] == "train_step.recompile"
-        assert instants[0]["attrs"]["cache_entries"] == 2
-
     def test_configure_from_env(self, live_tracer, monkeypatch):
         monkeypatch.setattr(trace, "install_crash_handler", lambda: None)
         assert trace.configure_from_env(
