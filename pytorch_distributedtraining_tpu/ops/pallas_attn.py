@@ -7,17 +7,20 @@ one [bq, dh] query tile stays resident while K/V stream through in [bk, dh]
 tiles — O(T) HBM traffic instead of the O(T^2) logits round-trip, f32
 accumulators on the MXU (`/opt/skills/guides/pallas_guide.md` patterns).
 
-The backward is the FlashAttention-2 scheme as two Pallas kernels with
-in-kernel recompute from the saved per-row logsumexp (no O(T^2) residuals
-ever touch HBM, fwd or bwd):
-
-  - dq kernel: one query tile resident, K/V stream; recomputes P from lse,
-    dS = P*(dO V^T - delta), dq += dS K.
-  - dk/dv kernel: one key tile resident, Q/dO stream; dv += P^T dO,
-    dk += dS^T Q.
+The backward recomputes P from the saved per-row logsumexp in the kernel
+(no O(T^2) residuals ever touch HBM, fwd or bwd), in ONE pass: one key tile
+resident, Q and dO whole in VMEM, the query blocks that see the tile
+streaming past it; per block pair it makes the scores, P, dO V^T and
+dS = P*(dO V^T - delta) once, then dv += P^T dO, dk += dS^T Q, and
+dq[block] += dS K into a float32 [T, dh] accumulator in VMEM that lives
+over the key tiles of a (batch, head) and leaves for HBM once. That costs
+the whole dQ again in VMEM (73 MiB at T = 16,384, dh = 128, blocks of 512).
+Where it does not fit (``backward_path``: T = 16,384 at dh = 256) the dq
+kernel (one query tile resident, K and V whole) and the dk/dv kernel run
+apart, computing the scores and dS twice: 74 MiB at that shape.
 
 ``delta = rowsum(dO * O)`` is a cheap elementwise XLA pass. Causal block
-skipping applies in all three kernels (upper-triangular tiles never run).
+skipping applies in every kernel (upper-triangular tiles never run).
 ``make_flash_attn_fn`` returns a drop-in ``attn_fn`` for the model zoo.
 The kernels compile for the TPU or raise; CPU tests exercise the same code
 by asking for ``interpret=True`` themselves.
@@ -51,9 +54,11 @@ from jax.experimental.pallas import tpu as pltpu
 _BIG_NEG = -1e30
 # What a kernel may ask of a TensorCore's VMEM (128 MiB on v5e/v6e, less a
 # margin for Mosaic's own scratch), and the scoped limit a kernel gets
-# without asking. K and V (forward, dq) or Q and dO (dk/dv) ride whole
-# [T, dh] in VMEM: past the default the limit is raised to what the blocks
-# need, and past the ceiling the shape is refused before Mosaic is.
+# without asking. K and V (forward, dq) or Q and dO (backward, dk/dv) ride
+# whole [T, dh] in VMEM, the fused backward's dQ block and its float32
+# accumulator too: past the default the limit is raised to what the blocks
+# need; past the ceiling the backward runs as two kernels, and a shape
+# that even those cannot hold is refused before Mosaic is.
 _VMEM_CEILING = 100 * 2**20
 _VMEM_DEFAULT = 16 * 2**20
 # Per-row stats (lse, delta) ride in [B, H, T, _STAT_LANES] instead of
@@ -247,14 +252,27 @@ def _dq_kernel(
 
 def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    *, bq, bk, causal, scale, window=None,
+    dq_ref=None, dq_acc=None, *, bq, bk, causal, scale, window=None,
 ):
+    """dK and dV of one key block, the query blocks it is visible to
+    streaming past it. Given ``dq_ref`` (the fused pass) it makes dQ too:
+    each visited query block's ``dS K`` is added to its rows of ``dq_acc``,
+    the whole sequence's float32 [T, dh] in VMEM, over the grid's key-block
+    axis (innermost, in order), and the sum leaves for ``dq_ref``, whose
+    block stays resident over that axis, once the last key block has run.
+    The scores, ``exp``, ``dO V^T`` and ``dS`` are then made once a block
+    pair, and a query block's dQ is still summed over its key blocks in
+    ascending order."""
     ki = pl.program_id(2)
     k = k_ref[0, 0]  # [bk, dh]
     v = v_ref[0, 0]
     t = q_ref.shape[2]
     dh = k.shape[-1]
     nq = t // bq
+    if dq_acc is not None:
+        @pl.when(ki == 0)
+        def _():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def body(i, carry, masked=causal):
         dk, dv = carry
@@ -278,6 +296,11 @@ def _dkv_kernel(
             preferred_element_type=jnp.float32,
         )  # [bq, bk]
         ds = p * (dp - delta[:, None])
+        if dq_acc is not None:
+            dq_acc[pl.ds(i * bq, bq), :] += jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [bq, dh]
         dk = dk + jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -299,6 +322,10 @@ def _dkv_kernel(
         )
     dk_ref[0, 0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+    if dq_acc is not None:
+        @pl.when(ki == pl.num_programs(2) - 1)
+        def _():
+            dq_ref[0, 0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _check_blocks(t, bq, bk):
@@ -306,19 +333,25 @@ def _check_blocks(t, bq, bk):
         raise ValueError(f"seq len {t} must divide block sizes ({bq},{bk})")
 
 
-def _vmem_params(what, t, dh, bq, bk, dtype, *, whole, tiles, stat_rows):
-    """Compiler parameters for a kernel that holds ``whole`` [T, dh]
-    blocks, ``tiles`` [max(bq, bk), dh] blocks and per-row statistics of
-    ``stat_rows`` rows in VMEM, each double-buffered by the pipeline (a
-    statistics block's 8 lanes pad to a 128-lane f32 tile). Raises where
-    the blocks cannot fit a TensorCore's VMEM: the sequence has to be
-    streamed then, which this kernel does not do."""
+def _vmem_need(t, dh, bq, bk, dtype, *, whole, tiles, stat_rows, scratch=0):
+    """Bytes of VMEM a kernel asks for that holds ``whole`` [T, dh] blocks,
+    ``tiles`` [max(bq, bk), dh] blocks and per-row statistics of
+    ``stat_rows`` rows, each double-buffered by the pipeline (a statistics
+    block's 8 lanes pad to a 128-lane f32 tile), and ``scratch`` bytes of
+    its own."""
     item = jnp.dtype(dtype).itemsize
-    need = 2 * (
+    return 2 * (
         whole * t * dh * item
         + tiles * max(bq, bk) * dh * item
         + stat_rows * 128 * 4
-    ) + 8 * bq * bk * 4  # the [bq, bk] f32 score-sized temporaries
+    ) + scratch + 8 * bq * bk * 4  # the [bq, bk] f32 score-sized temporaries
+
+
+def _vmem_params(what, t, dh, bq, bk, dtype, **blocks):
+    """Compiler parameters for a kernel of ``_vmem_need(**blocks)``. Raises
+    where the blocks cannot fit a TensorCore's VMEM: the sequence has to be
+    streamed then, which this kernel does not do."""
+    need = _vmem_need(t, dh, bq, bk, dtype, **blocks)
     if need > _VMEM_CEILING:
         raise ValueError(
             f"flash_attention {what}: T={t}, dh={dh}, {jnp.dtype(dtype).name} "
@@ -331,6 +364,32 @@ def _vmem_params(what, t, dh, bq, bk, dtype, *, whole, tiles, stat_rows):
         return None
     return pltpu.CompilerParams(
         vmem_limit_bytes=min(_VMEM_CEILING, max(_VMEM_DEFAULT, 2 * need))
+    )
+
+
+def _fused_backward_blocks(t, dh):
+    """The fused backward's VMEM: Q, dO and dQ whole (dQ's block stays
+    resident over the key blocks), the key-value tiles and dK, dV, both
+    statistics whole, and dQ's float32 accumulator, one buffer."""
+    return dict(whole=3, tiles=4, stat_rows=2 * t, scratch=t * dh * 4)
+
+
+def backward_path(t, dh, bq, bk, dtype):
+    """``(path, reason)`` of the split-head backward at this shape:
+    ``"fused"``, one pass, where its footprint fits under ``_VMEM_CEILING``;
+    else ``"two_kernels"``, dq and dk/dv apart, which need less (T = 16,384
+    at dh = 256: 106 MiB fused, 74 apart). The footprint is the only
+    reason there are two paths."""
+    need = _vmem_need(t, dh, bq, bk, dtype, **_fused_backward_blocks(t, dh))
+    ceiling = _VMEM_CEILING / 2**20
+    if need <= _VMEM_CEILING:
+        return "fused", (
+            f"one pass: {need / 2**20:.0f} MiB of VMEM, within the "
+            f"{ceiling:.0f} MiB a kernel may use"
+        )
+    return "two_kernels", (
+        f"one pass would need {need / 2**20:.0f} MiB of VMEM, over the "
+        f"{ceiling:.0f} MiB a kernel may use: dq and dk/dv run apart"
     )
 
 
@@ -409,6 +468,11 @@ def _flash_forward(q, k, v, *, causal, bq, bk, interpret, window=None):
 def _flash_backward(
     q, k, v, out, lse, do, *, causal, bq, bk, interpret, window=None
 ):
+    """dq, dk, dv in the caller's layout: one fused pass where
+    ``backward_path`` says it fits VMEM, else the dq and dk/dv kernels.
+    Each trace leaves the instant ``flash.backward`` saying which."""
+    from ..observe import trace
+
     b, t, h, dh = q.shape
     group = _check_heads(q, k, causal, window)
     bq, bk = min(bq, t), min(bk, t)
@@ -438,42 +502,61 @@ def _flash_backward(
         (1, 1, t, _STAT_LANES), lambda b_, h_, i: (b_, h_, 0, 0)
     )
 
-    dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, bq=bq, bk=bk, causal=causal, scale=scale,
-            window=window,
-        ),
-        grid=(b, h, t // bq),
-        in_specs=[tile_q, full_kv, full_kv, tile_q, row_q, row_q],
-        out_specs=tile_q,
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
-        compiler_params=_vmem_params(
-            "dq", t, dh, bq, bk, q.dtype, whole=2, tiles=3, stat_rows=2 * bq
-        ),
-        interpret=interpret,
-    )(qt, kt, vt, dot_, lse, delta)
-
+    path, reason = backward_path(t, dh, bq, bk, q.dtype)
+    trace.instant(
+        "flash.backward", path=path, reason=reason, t=t, dh=dh, heads=h,
+        kv_heads=k.shape[2], window=window, bq=bq, bk=bk,
+    )
+    kernel = dict(bq=bq, bk=bk, causal=causal, scale=scale, window=window)
     # one program a QUERY head: where a key-value head serves a group, each
     # of its query heads writes its own part of dk and dv (float32) and one
     # pass sums the group's
     part = k.dtype if group == 1 else jnp.float32
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, bq=bq, bk=bk, causal=causal, scale=scale,
-            window=window,
-        ),
-        grid=(b, h, t // bk),
-        in_specs=[full_seq, tile_kv, tile_kv, full_seq, row_full, row_full],
-        out_specs=[tile_k, tile_k],
-        out_shape=[
-            jax.ShapeDtypeStruct(qt.shape[:3] + kt.shape[3:], part),
-            jax.ShapeDtypeStruct(qt.shape[:3] + vt.shape[3:], part),
-        ],
-        compiler_params=_vmem_params(
-            "dk/dv", t, dh, bq, bk, q.dtype, whole=2, tiles=4, stat_rows=2 * t
-        ),
-        interpret=interpret,
-    )(qt, kt, vt, dot_, lse, delta)
+    dkv_shapes = [
+        jax.ShapeDtypeStruct(qt.shape[:3] + kt.shape[3:], part),
+        jax.ShapeDtypeStruct(qt.shape[:3] + vt.shape[3:], part),
+    ]
+    dkv_in = [full_seq, tile_kv, tile_kv, full_seq, row_full, row_full]
+    if path == "fused":
+        dk, dv, dq = pl.pallas_call(
+            functools.partial(_dkv_kernel, **kernel),
+            grid=(b, h, t // bk),
+            in_specs=dkv_in,
+            out_specs=[tile_k, tile_k, full_seq],
+            out_shape=[*dkv_shapes, jax.ShapeDtypeStruct(qt.shape, q.dtype)],
+            scratch_shapes=[pltpu.VMEM((t, dh), jnp.float32)],
+            compiler_params=_vmem_params(
+                "backward", t, dh, bq, bk, q.dtype,
+                **_fused_backward_blocks(t, dh),
+            ),
+            interpret=interpret,
+            name="flash_bwd",
+        )(qt, kt, vt, dot_, lse, delta)
+    else:
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, **kernel),
+            grid=(b, h, t // bq),
+            in_specs=[tile_q, full_kv, full_kv, tile_q, row_q, row_q],
+            out_specs=tile_q,
+            out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+            compiler_params=_vmem_params(
+                "dq", t, dh, bq, bk, q.dtype, whole=2, tiles=3,
+                stat_rows=2 * bq,
+            ),
+            interpret=interpret,
+        )(qt, kt, vt, dot_, lse, delta)
+        dk, dv = pl.pallas_call(
+            functools.partial(_dkv_kernel, **kernel),
+            grid=(b, h, t // bk),
+            in_specs=dkv_in,
+            out_specs=[tile_k, tile_k],
+            out_shape=dkv_shapes,
+            compiler_params=_vmem_params(
+                "dk/dv", t, dh, bq, bk, q.dtype, whole=2, tiles=4,
+                stat_rows=2 * t,
+            ),
+            interpret=interpret,
+        )(qt, kt, vt, dot_, lse, delta)
     if group > 1:
         dk, dv = (
             jnp.sum(a.reshape(b, h // group, group, t, -1), axis=2).astype(

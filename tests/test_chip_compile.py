@@ -84,7 +84,8 @@ FLASH_MLA, FLASH_MLA_BLOCK = (2, 4096, 20, 256), 512
 GROUPED = (2 * 4096 * 4, 8, 2048, 1536)  # buffer rows, experts held, D, F
 # SmallThinker-21B-A3B's cell: 28 query heads of 128 on 4 key-value heads,
 # one sequence of 16,384, blocks of 512; K and V ride whole in VMEM (4 MiB
-# each), Q, dO and the row statistics whole in dk/dv (the limit is raised)
+# each), Q, dO, dQ and the row statistics whole in the backward (the limit is
+# raised)
 FLASH_GQA, FLASH_GQA_KV, FLASH_GQA_BLOCK = (1, 16384, 28, 128), 4, 512
 # Trinity-Mini's cell: 32 query heads of 128 on 4 key-value heads (8 each),
 # one sequence of 8,192, blocks of 512: a band of 2,048 is 4 blocks and the
@@ -630,18 +631,18 @@ def _attention_calls(text):
 def test_smallthinker_cell_step_one_chip(topo):
     """``smallthinker-21b-a3b.train-16k`` as the benchmark builds it (its
     family, its job's ``plan``): every attention core a kernel (per layer a
-    forward, dq and dk/dv: 12; a rematerialised layer keeps the forward's
-    output and row statistics, and ran it again, 16, before it did), no
+    forward and one backward pass: 8; 12 while the backward was dq and
+    dk/dv apart, 16 while a rematerialised layer ran the forward again), no
     T x T scores, no copy of k or v for the seven query heads that share
     them, and at least a quarter of the chip filled by the step's own
     plan."""
     text, planned = _cell_step(topo, "smallthinker-21b-a3b.train-16k")
     assert 4e9 < planned < 15.75e9
     attention = _attention_calls(text)
-    assert len(attention) == 12
-    assert sum("/attention_sliding/" in line for line in attention) == 9
-    assert sum("/attention_global/" in line for line in attention) == 3
-    assert _kernel_calls(text) == 12 + 4 * 12  # and the grouped matmuls
+    assert len(attention) == 8
+    assert sum("/attention_sliding/" in line for line in attention) == 6
+    assert sum("/attention_global/" in line for line in attention) == 2
+    assert _kernel_calls(text) == 8 + 4 * 12  # and the grouped matmuls
     assert not re.findall(r"\[(?:\d+,)*16384,16384\]", text)
     # k and v stay at 4 heads: nothing of [.., 28, ..] is made from them
     assert not re.findall(
@@ -655,7 +656,7 @@ def test_smallthinker_cell_step_moves_only_the_rows_that_land(topo):
     """The same step (PR 33): the held-experts layer's dispatch and combine
     are row loops over buffers that are allocated, not filled, and combine
     makes no float32 copy of the whole N x k buffer; the attention kernels
-    are the 12 of the test above."""
+    are the 8 of the test above."""
     text, _ = _cell_step(topo, "smallthinker-21b-a3b.train-16k")
     assert "f32[16384,6,2560]" not in text
     buffers = [
@@ -665,27 +666,29 @@ def test_smallthinker_cell_step_moves_only_the_rows_that_land(topo):
     assert buffers and all(
         "/dispatch/" in line or "/combine/" in line for line in buffers
     )
-    assert len(_attention_calls(text)) == 12
+    assert len(_attention_calls(text)) == 8
 
 
 @pytest.mark.slow
 def test_glm_cell_step_one_chip(topo):
     """``glm-4.7-flash.train-4k`` as the benchmark builds it: five layers,
-    each attention core a kernel run once forward and twice backward (15
-    calls; 20 while the rematerialised forward ran again), no T x T scores,
+    each attention core a kernel run once forward and once backward (10
+    calls; 15 while the backward was dq and dk/dv apart, 20 while the
+    rematerialised forward ran again), no T x T scores,
     and at least a quarter of the chip filled by the step's own plan."""
     text, planned = _cell_step(topo, "glm-4.7-flash.train-4k")
     assert 4e9 < planned < 15.75e9
-    assert len(_attention_calls(text)) == 15
+    assert len(_attention_calls(text)) == 10
     assert not re.findall(r"\[(?:\d+,)*4096,4096\]", text)
 
 
 @pytest.mark.slow
 def test_trinity_mini_cell_step_one_chip(topo):
     """``trinity-mini.train-8k`` as the benchmark builds it (its family, its
-    job's ``plan``): every attention core a kernel (per layer a forward, dq
-    and dk/dv: 15, 12 of them in the four sliding layers; 20 and 16 while
-    the rematerialised forward ran again), no T x T scores, no copy of k or
+    job's ``plan``): every attention core a kernel (per layer a forward and
+    one backward pass: 10, 8 of them in the four sliding layers; 15 and 12
+    while the backward was dq and dk/dv apart, 20 and 16 while the
+    rematerialised forward ran again), no T x T scores, no copy of k or
     v for the eight query heads
     that share them, the held-experts layer's buffers allocated and not
     filled, and at least a quarter of the chip filled by the step's own
@@ -693,10 +696,10 @@ def test_trinity_mini_cell_step_one_chip(topo):
     text, planned = _cell_step(topo, "trinity-mini.train-8k")
     assert 4e9 < planned < 15.75e9
     attention = _attention_calls(text)
-    assert len(attention) == 15
-    assert sum("/attention_sliding/" in line for line in attention) == 12
-    assert sum("/attention_global/" in line for line in attention) == 3
-    assert _kernel_calls(text) == 15 + 4 * 12  # and the grouped matmuls
+    assert len(attention) == 10
+    assert sum("/attention_sliding/" in line for line in attention) == 8
+    assert sum("/attention_global/" in line for line in attention) == 2
+    assert _kernel_calls(text) == 10 + 4 * 12  # and the grouped matmuls
     assert not re.findall(r"\[(?:\d+,)*8192,8192\]", text)
     # k and v stay at 4 heads: nothing of [.., 32, ..] is made from them
     assert not re.findall(

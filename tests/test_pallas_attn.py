@@ -6,6 +6,8 @@ import numpy as np
 import pytest
 
 from pytorch_distributedtraining_tpu.models.gpt2 import default_attention
+from pytorch_distributedtraining_tpu.observe import trace
+from pytorch_distributedtraining_tpu.ops import pallas_attn
 from pytorch_distributedtraining_tpu.ops.pallas_attn import (
     flash_attention,
     make_flash_attn_fn,
@@ -57,7 +59,7 @@ def test_gradients_match(qkv):
 
 @pytest.mark.parametrize("causal", [True, False])
 def test_gradients_match_blocks(qkv, causal):
-    """Pallas dq/dk/dv kernels vs XLA AD across block shapes (bwd is
+    """The Pallas backward vs XLA AD across block shapes (bwd is
     in-kernel recompute, not an XLA fallback)."""
     q, k, v = qkv
 
@@ -97,32 +99,142 @@ def test_indivisible_seq_raises(qkv):
         flash_attention(q[:, :100], k[:, :100], v[:, :100], True, 64, 64, True)
 
 
-@pytest.mark.parametrize("group", [1, 7])
-@pytest.mark.parametrize("window", [None, 20, 32, 75, 128])
-def test_band_and_grouped_heads_match_the_masked_einsum(window, group):
-    """A causal window (shorter than a block of 32, one block, several
-    blocks, the whole sequence; None = the causal kernel) and ``group``
-    query heads reading one key-value head where it lies: forward and the
-    three gradients (dk and dv summed over the group) against the masked
-    einsum, at blocks that divide the band unevenly too."""
+def _masked_einsum(q, k, v, causal, window):
     from pytorch_distributedtraining_tpu.models.smallthinker import (
         banded_attention,
     )
 
+    if causal:
+        return banded_attention(q, k, v, window=window)
+    group = q.shape[2] // k.shape[2]
+    return default_attention(
+        q, jnp.repeat(k, group, 2), jnp.repeat(v, group, 2), causal=False
+    )
+
+
+# (window, group, causal, dtype): the causal window shorter than a block of
+# 32, one block, several blocks, the whole sequence, None = the causal
+# triangle; then the whole square, and bf16 operands
+BAND_CASES = [
+    *((w, g, True, jnp.float32) for g in (1, 7) for w in (None, 20, 32, 75, 128)),
+    (None, 1, False, jnp.float32),
+    (None, 7, False, jnp.float32),
+    (None, 7, True, jnp.bfloat16),
+    (75, 7, True, jnp.bfloat16),
+    (None, 1, False, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize("window,group,causal,dtype", BAND_CASES)
+def test_band_and_grouped_heads_match_the_masked_einsum(
+    window, group, causal, dtype
+):
+    """A causal window, the causal triangle or the whole square, and
+    ``group`` query heads reading one key-value head where it lies: forward
+    and the three gradients (dk and dv summed over the group) against the
+    masked einsum in float32, at blocks that divide the band unevenly too
+    and with several query and key blocks, so that the fused backward's dq
+    is summed over the key-block axis and across the band's edges."""
     keys = jax.random.split(jax.random.PRNGKey(group), 4)
     q, do = (jax.random.normal(k, (2, T, 2 * group, DH)) for k in keys[:2])
     k, v = (jax.random.normal(k, (2, T, 2, DH)) for k in keys[2:])
-    for bq, bk in ((32, 32), (64, 32)):
+    # bf16 operands against float32 arithmetic: the operands' rounding
+    atol = (2e-5, 1e-4) if dtype == jnp.float32 else (3e-2, 6e-2)
+    want, want_vjp = jax.vjp(
+        lambda *a: _masked_einsum(*a, causal, window), q, k, v
+    )
+    for bq, bk in ((32, 32), (64, 32), (32, 64)):
         out, vjp = jax.vjp(
-            lambda *a: flash_attention(*a, True, bq, bk, True, window), q, k, v
+            lambda *a: flash_attention(*a, causal, bq, bk, True, window),
+            *(a.astype(dtype) for a in (q, k, v)),
         )
-        want, want_vjp = jax.vjp(
-            lambda *a: banded_attention(*a, window=window), q, k, v
+        assert out.dtype == dtype
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), want, atol=atol[0]
         )
-        np.testing.assert_allclose(out, want, atol=2e-5)
-        for g, w in zip(vjp(do), want_vjp(do)):
+        for g, w in zip(vjp(do.astype(dtype)), want_vjp(do)):
             assert g.shape == w.shape
-            np.testing.assert_allclose(g, w, atol=1e-4)
+            np.testing.assert_allclose(
+                np.asarray(g, np.float32), w, atol=atol[1]
+            )
+
+
+@pytest.mark.parametrize(
+    "window,group,causal,dtype",
+    [
+        (None, 1, True, jnp.float32),
+        (75, 7, True, jnp.float32),
+        (None, 7, False, jnp.float32),
+        (20, 1, True, jnp.bfloat16),
+    ],
+)
+def test_the_fused_backward_is_the_two_kernels(
+    monkeypatch, window, group, causal, dtype
+):
+    """One pass makes the dq, dk and dv the dq and dk/dv kernels make on
+    the same inputs, to float32 rounding: each query block's dq is summed
+    over the same key blocks in the same order."""
+    keys = jax.random.split(jax.random.PRNGKey(38), 4)
+    q, do = (
+        jax.random.normal(k, (1, 2 * T, 2 * group, DH), dtype)
+        for k in keys[:2]
+    )
+    k, v = (jax.random.normal(k, (1, 2 * T, 2, DH), dtype) for k in keys[2:])
+
+    def grads():
+        _, vjp = jax.vjp(
+            lambda *a: flash_attention(*a, causal, 64, 32, True, window),
+            q, k, v,
+        )
+        return vjp(do)
+
+    fused = grads()
+    monkeypatch.setattr(
+        pallas_attn, "backward_path", lambda *a: ("two_kernels", "asked")
+    )
+    apart = grads()
+    for f, a in zip(fused, apart):
+        assert f.dtype == a.dtype == dtype
+        f, a = np.asarray(f, np.float32), np.asarray(a, np.float32)
+        ulp = 1e-6 if dtype == jnp.float32 else 1e-2
+        np.testing.assert_allclose(f, a, rtol=ulp, atol=ulp * np.abs(a).max())
+
+
+@pytest.mark.parametrize(
+    "dh,path,calls", [(128, "fused", 2), (256, "two_kernels", 3)]
+)
+def test_the_backward_path_follows_its_vmem_footprint(dh, path, calls):
+    """At T = 16,384 and blocks of 512 the fused backward's footprint (Q,
+    dO and dQ whole, dQ's float32 accumulator) fits the ceiling at head
+    size 128 (73 MiB) and not at 256 (106 MiB), where the two kernels, which
+    need 74, still run: the choice, the instant ``flash.backward`` each
+    trace of the backward rule leaves, and the kernels the gradient holds
+    (forward + 1, or forward + 2) agree. Traced, not run."""
+    t, block = 16384, 512
+    assert pallas_attn.backward_path(t, dh, block, block, jnp.bfloat16)[0] == path
+    q = jax.ShapeDtypeStruct((1, t, 2, dh), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, True, block, block, False, 4096)
+        return jnp.sum(out.astype(jnp.float32))
+
+    tracer = trace.get_tracer()
+    was = tracer.enabled
+    trace.enable(crash_handler=False)
+    trace.clear()
+    try:
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
+        said = [
+            r["attrs"] for r in trace.records()
+            if r["name"] == "flash.backward"
+        ]
+    finally:
+        trace.clear()
+        tracer.enabled = was
+    assert [s["path"] for s in said] == [path]
+    assert said[0]["t"] == t and said[0]["dh"] == dh and said[0]["window"] == 4096
+    assert "MiB" in said[0]["reason"]
+    assert str(jaxpr).count("pallas_call") == calls
 
 
 def test_a_window_is_causal_and_heads_must_divide(qkv):
