@@ -17,7 +17,9 @@ raw input; the held-experts layer is one piece of code for both,
 ``.held_experts``) and ``Afmoe`` (Trinity-Mini: the same layer kinds with
 a norm on every head of q and k, a sigmoid gate on the core's output and a
 norm after each branch as well as before it; GLM's expert layer as it
-stands, 8 of 128 a token).
+stands, 8 of 128 a token) and ``Lfm2Moe`` (LFM2-8B-A1B: gated short
+convolutions beside QK-normed grouped-query attention at heads of 64, the
+held-experts layer with no shared expert, a tied head).
 
 All models are Flax linen modules in NHWC (images) / [B, T, D] (sequences) —
 the layouts XLA:TPU tiles best — with bf16-friendly parameterization.
@@ -47,6 +49,8 @@ _LAZY = {
     "Glm4MoeLiteConfig": ".glm4_moe_lite",
     "Afmoe": ".afmoe",
     "AfmoeConfig": ".afmoe",
+    "Lfm2Moe": ".lfm2_moe",
+    "Lfm2MoeConfig": ".lfm2_moe",
     "SmallThinker": ".smallthinker",
     "SmallThinkerConfig": ".smallthinker",
     "ViT": ".vit",

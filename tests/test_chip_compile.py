@@ -91,6 +91,11 @@ FLASH_GQA, FLASH_GQA_KV, FLASH_GQA_BLOCK = (1, 16384, 28, 128), 4, 512
 # one sequence of 8,192, blocks of 512: a band of 2,048 is 4 blocks and the
 # two masked edges
 FLASH_GQA8, FLASH_GQA8_WINDOW = (1, 8192, 32, 128), 2048
+# LFM2-8B-A1B's cell: 32 query heads of 64 on 8 key-value heads, 2 sequences
+# of 4,096, blocks of 512; its conv mixers' bcx [2, 4096, 3 x 2,048] bf16 and
+# taps [2,048, 3]
+FLASH_GQA_64, FLASH_GQA_64_KV = (2, 4096, 32, 64), 8
+SHORT_CONV = (2, 4096, 2048, 3)
 GPT2_HEADS, GPT2_HEAD_DIM, PAGE = 12, 64, 16
 
 
@@ -136,6 +141,25 @@ def _flash(dev, *, dtype, grad, shape=FLASH, block=128, kv_heads=None,
             lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)), argnums=(0, 1, 2)
         ),
         (qkv, kv, kv),
+    )
+
+
+def _short_conv(dev, *, grad):
+    """A conv mixer's gated short convolution, the Pallas pair."""
+    from pytorch_distributedtraining_tpu.ops.short_conv import short_conv_kernel
+
+    b, t, d, taps = SHORT_CONV
+    args = (
+        _on(dev, (b, t, 3 * d), jnp.bfloat16), _on(dev, (d, taps), jnp.bfloat16)
+    )
+    if not grad:
+        return short_conv_kernel, args
+    return (
+        jax.grad(
+            lambda x, w: jnp.sum(short_conv_kernel(x, w).astype(jnp.float32)),
+            argnums=(0, 1),
+        ),
+        args,
     )
 
 
@@ -290,6 +314,12 @@ KERNEL_CASES = {
         lambda d: _flash(d, dtype=jnp.bfloat16, grad=True, shape=FLASH_GQA8,
                          block=FLASH_GQA_BLOCK, kv_heads=FLASH_GQA_KV), True,
     ),
+    "flash_bwd_bf16_gqa4_4k_head_64": (
+        lambda d: _flash(d, dtype=jnp.bfloat16, grad=True, shape=FLASH_GQA_64,
+                         block=FLASH_GQA_BLOCK, kv_heads=FLASH_GQA_64_KV), True,
+    ),
+    "short_conv_fwd_bf16_lfm2": (lambda d: _short_conv(d, grad=False), True),
+    "short_conv_bwd_bf16_lfm2": (lambda d: _short_conv(d, grad=True), True),
     "core_qkv_fwd_gpt2_125m": (
         lambda d: _causal_qkv(d, shape=CORE_125M, grad=False), True,
     ),
@@ -718,6 +748,41 @@ def test_trinity_mini_cell_step_one_chip(topo):
     )
     # the scopes the new readers look for are in the compiled names
     for scope in ("/qk_norm/", "/attention_gate/", "/post_norm/"):
+        assert scope in text, scope
+
+
+@pytest.mark.slow
+def test_lfm2_cell_step_one_chip(topo):
+    """``lfm2-8b-a1b.train-4k`` as the benchmark builds it (its family, its
+    job's ``plan``): the one attention layer's core a kernel run once
+    forward (its residuals kept) and once backward, 32 query heads of 64
+    reading 8 key-value heads where they lie; each of the four conv layers'
+    short convolution the Pallas pair, its forward run TWICE (the
+    rematerialised layer runs it again: its output feeds ``out_proj``'s
+    weight gradient and no policy keeps it) and its backward once; the
+    grouped matmuls as in the other sparse cells; no T x T scores; at least
+    a quarter of the chip filled by the step's own plan. The three other
+    sparse cells' programs are pinned by the tests above: this cell adds no
+    line to any code they run."""
+    text, planned = _cell_step(topo, "lfm2-8b-a1b.train-4k")
+    assert 4e9 < planned < 15.75e9 - 1.5e9
+    attention = _attention_calls(text)
+    assert len(attention) == 2
+    conv = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "/short_conv/" in line
+    ]
+    assert len(conv) == 12
+    assert sum("rematted_computation" in line for line in conv) == 4
+    assert sum("transpose(" in line for line in conv) == 8
+    assert _kernel_calls(text) == 2 + 12 + 4 * 12  # and the grouped matmuls
+    assert not re.findall(r"\[(?:\d+,)*4096,4096\]", text)
+    # k and v stay at 8 heads: nothing of [.., 32, ..] is made from them
+    assert not re.findall(
+        r"bf16\[2,32,4096,64\]\S* broadcast\(|bf16\[2,4096,8,4,64\]", text
+    )
+    for scope in ("/short_conv/", "/qk_norm/", "/router/", "/experts/",
+                  "/mlp_dense/", "/head/"):
         assert scope in text, scope
 
 

@@ -22,25 +22,37 @@ globals().update({  # its tests, and the fixtures they name
 
 
 def test_the_manifest_lists_the_cell_and_its_metrics(monkeypatch):
-    """``chipbench``'s own test holds PR 35's two metrics to the END of
-    ``per_layer``; a later PR appends after them (PR 37: six) and may not
-    edit a file the benchmark has (PERF.md section 7). Here the same test
-    reads the manifest as PR 35 left it, and what follows is held to be
-    appended: entries of other layers' readers, none of PR 35's names."""
+    """``chipbench``'s own test holds the Trinity-Mini cell and configuration
+    to the END of ``workloads`` and ``configs`` and its two metrics to the end
+    of ``per_layer``; later additions append after them (the start-up
+    ledger's six metrics; LFM2-8B-A1B's configuration, cell and two metrics)
+    and may not edit a file the benchmark has (PERF.md section 7). Here the
+    same test reads the manifest as the Trinity-Mini cell left it, and what
+    follows is held to be appended: entries of other cells, configurations
+    and readers, none of Trinity-Mini's names."""
     import json
 
-    after = []
+    after = {}
+
+    def cut(manifest, key, last):
+        names = [entry["name"] for entry in manifest[key]]
+        at = names.index(last) + 1
+        after[key] = names[at:]
+        manifest[key] = manifest[key][:at]
 
     class AsPR35LeftIt:
         @staticmethod
         def load(f):
             manifest = json.load(f)
-            names = [m["name"] for m in manifest["per_layer"]]
-            cut = names.index("post_norm_ms_per_step") + 1
-            after.extend(names[cut:])
-            manifest["per_layer"] = manifest["per_layer"][:cut]
+            cut(manifest, "per_layer", "post_norm_ms_per_step")
+            cut(manifest, "workloads", _module.CELL)
+            cut(manifest, "configs", "trinity-mini")
             return manifest
 
     monkeypatch.setattr(_module, "json", AsPR35LeftIt)
     _module.test_the_manifest_lists_the_cell_and_its_metrics()
-    assert not {"attention_gate_ms_per_step", "post_norm_ms_per_step"} & set(after)
+    assert not {"attention_gate_ms_per_step", "post_norm_ms_per_step"} & set(
+        after["per_layer"]
+    )
+    assert _module.CELL not in after["workloads"]
+    assert "trinity-mini" not in after["configs"]
