@@ -8,16 +8,18 @@ tiles — O(T) HBM traffic instead of the O(T^2) logits round-trip, f32
 accumulators on the MXU (`/opt/skills/guides/pallas_guide.md` patterns).
 
 The backward recomputes P from the saved per-row logsumexp in the kernel
-(no O(T^2) residuals ever touch HBM, fwd or bwd), in ONE pass: one key tile
-resident, Q and dO whole in VMEM, the query blocks that see the tile
-streaming past it; per block pair it makes the scores, P, dO V^T and
-dS = P*(dO V^T - delta) once, then dv += P^T dO, dk += dS^T Q, and
-dq[block] += dS K into a float32 [T, dh] accumulator in VMEM that lives
-over the key tiles of a (batch, head) and leaves for HBM once. That costs
-the whole dQ again in VMEM (73 MiB at T = 16,384, dh = 128, blocks of 512).
-Where it does not fit (``backward_path``: T = 16,384 at dh = 256) the dq
-kernel (one query tile resident, K and V whole) and the dk/dv kernel run
-apart, computing the scores and dS twice: 74 MiB at that shape.
+(no O(T^2) residuals ever touch HBM, fwd or bwd), in ONE pass in both
+families below: one key tile resident, Q and dO whole in VMEM, the query
+blocks that see the tile streaming past it; per block pair it makes the
+scores, P, dO V^T and dS = P*(dO V^T - delta) once, then dv += P^T dO,
+dk += dS^T Q, and dq[block] += dS K into a float32 [T, dh] accumulator in
+VMEM that lives over the key tiles of a (batch, head or lane block) and
+leaves for HBM once. That costs the whole dQ again in VMEM (73 MiB at
+T = 16,384, dh = 128, blocks of 512). Where it does not fit
+(``backward_path``: T = 16,384 at dh = 256, or at two packed heads of 64)
+the dq kernel (one query tile resident, K and V whole) and the dk/dv kernel
+run apart, computing the scores and dS twice: 74 and 89 MiB at those
+shapes.
 
 ``delta = rowsum(dO * O)`` is a cheap elementwise XLA pass. Causal block
 skipping applies in every kernel (upper-triangular tiles never run).
@@ -33,7 +35,11 @@ straight out of ``qkv`` a lane block at a time and write ``out`` and
 ``d qkv`` the same way: no ``[B, T, H, dh] <-> [B, H, T, dh]`` transpose, no
 half-filled 64-lane tile, a head taken out of its block by a lane mask
 (``(q * m_h) k^T`` contracts over all 128 lanes: the MXU pass a contraction
-of 64 costs), the causal mask paid on the diagonal blocks only. Where the
+of 64 costs), the causal mask paid on the diagonal blocks only. Its
+backward is the one pass above (``flash_packed_bwd``): the heads of a lane
+block add ``dS_h (k * m_h)`` into one float32 [T, 128] accumulator, each
+into its own columns, and ``backward_path`` counts the statistics of all
+of them. Where the
 count is odd (GPT-2 XL: 25 heads, k starts at column 1,600 = 12.5 blocks)
 q, k and v are first copied into thirds of their own with a head of zeros
 behind. ``kernel_contract`` says which shapes it takes.
@@ -367,20 +373,26 @@ def _vmem_params(what, t, dh, bq, bk, dtype, **blocks):
     )
 
 
-def _fused_backward_blocks(t, dh):
+def _fused_backward_blocks(t, dh, hb=1):
     """The fused backward's VMEM: Q, dO and dQ whole (dQ's block stays
     resident over the key blocks), the key-value tiles and dK, dV, both
-    statistics whole, and dQ's float32 accumulator, one buffer."""
-    return dict(whole=3, tiles=4, stat_rows=2 * t, scratch=t * dh * 4)
+    statistics of the block's ``hb`` heads whole, and dQ's float32
+    accumulator, one buffer."""
+    return dict(whole=3, tiles=4, stat_rows=2 * hb * t, scratch=t * dh * 4)
 
 
-def backward_path(t, dh, bq, bk, dtype):
-    """``(path, reason)`` of the split-head backward at this shape:
-    ``"fused"``, one pass, where its footprint fits under ``_VMEM_CEILING``;
-    else ``"two_kernels"``, dq and dk/dv apart, which need less (T = 16,384
-    at dh = 256: 106 MiB fused, 74 apart). The footprint is the only
-    reason there are two paths."""
-    need = _vmem_need(t, dh, bq, bk, dtype, **_fused_backward_blocks(t, dh))
+def backward_path(t, dh, bq, bk, dtype, hb=1):
+    """``(path, reason)`` of the backward at this shape: ``"fused"``, one
+    pass, where its footprint fits under ``_VMEM_CEILING``; else
+    ``"two_kernels"``, dq and dk/dv apart, which need less (T = 16,384 at
+    dh = 256: 106 MiB fused, 74 apart). ``dh`` is the width of a block's
+    lanes and ``hb`` the heads side by side in them: the split-head family
+    runs one head of ``dh`` a block, the packed family ``hb`` heads in 128
+    lanes (T = 16,384 at heads of 64: 105 MiB fused, 89 apart). The
+    footprint is the only reason there are two paths."""
+    need = _vmem_need(
+        t, dh, bq, bk, dtype, **_fused_backward_blocks(t, dh, hb)
+    )
     ceiling = _VMEM_CEILING / 2**20
     if need <= _VMEM_CEILING:
         return "fused", (
@@ -784,23 +796,34 @@ def _packed_dq_kernel(
 
 def _packed_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    *, bq, bk, hb, dh, scale,
+    dq_ref=None, dq_acc=None, *, bq, bk, hb, dh, scale,
 ):
+    """dK and dV of one key block for the ``hb`` heads of a lane block, the
+    query blocks that see it streaming past. Given ``dq_ref`` (the fused
+    pass) it makes dQ too, as ``_dkv_kernel`` does: each visited query
+    block's ``dS_h k_h`` (``k_h`` the key tile with the other heads' lanes
+    zeroed, so the heads of a block share one accumulator and need no merge)
+    is added to its rows of ``dq_acc``, the lane block's float32 [T, 128]
+    over the grid's key-block axis, and leaves for ``dq_ref`` once the last
+    key block has run."""
     ki = pl.program_id(2)
     k, v = k_ref[0], v_ref[0]  # [bk, 128]
     nq = q_ref.shape[1] // bq
     first, whole = _query_blocks(ki, bq, bk, nq)
+    if dq_acc is not None:
+        @pl.when(ki == 0)
+        def _():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def one_head(h, mask, outs):
         k_h, v_h = _only(mask, k), _only(mask, v)
-        if _folds(scale):
-            k_h = k_h * jnp.asarray(scale, k_h.dtype)
+        k_s = k_h * jnp.asarray(scale, k_h.dtype) if _folds(scale) else k_h
 
         def body(i, carry, masked):
             dk, dv = carry
             at = pl.ds(pl.multiple_of(i * bq, bq), bq)
             q, do = q_ref[0, at, :], do_ref[0, at, :]  # every head's columns
-            s = _dot(q, k_h, _NT)  # [bq, bk]: k_h's zeros leave this head's
+            s = _dot(q, k_s, _NT)  # [bq, bk]: k_s's zeros leave this head's
             if not _folds(scale):
                 s = s * scale
             if masked:
@@ -808,6 +831,8 @@ def _packed_dkv_kernel(
             p = jnp.exp(s - lse_ref[0, h, at, 0][:, None])
             dv = dv + _dot(p.astype(do.dtype), do, _TN)  # [bk, 128]
             ds = p * (_dot(do, v_h, _NT) - delta_ref[0, h, at, 0][:, None])
+            if dq_acc is not None:  # this head's columns, scaled at the end
+                dq_acc[at, :] += _dot(ds.astype(k.dtype), k_h, _NN)
             dk = dk + _dot(ds.astype(q.dtype), q, _TN)
             return dk, dv
 
@@ -825,6 +850,10 @@ def _packed_dkv_kernel(
     )
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+    if dq_acc is not None:
+        @pl.when(ki == pl.num_programs(2) - 1)
+        def _():
+            dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _lane_specs(first):
@@ -887,9 +916,27 @@ def _packed_forward(qkv, first, n, dh, bq, bk, interpret):
     )(*qkv)
 
 
-@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
 def _packed_backward(qkv, first, n, dh, out, lse, do, bq, bk, interpret):
-    """-> ``(dq, dk, dv)``, each ``[B, T, n * 128]``."""
+    """-> ``(dq, dk, dv)``, each ``[B, T, n * 128]``: one fused pass where
+    ``backward_path`` says it fits VMEM, else the dq and dk/dv kernels. Each
+    trace leaves the instant ``flash.backward`` saying which."""
+    from ..observe import trace
+
+    t, hb = qkv[0].shape[1], _LANES // dh
+    path, reason = backward_path(t, _LANES, bq, bk, qkv[0].dtype, hb)
+    trace.instant(
+        "flash.backward", path=path, reason=reason, t=t, dh=dh, heads=n * hb,
+        kv_heads=n * hb, window=None, bq=bq, bk=bk, family="packed", hb=hb,
+    )
+    return _packed_backward_kernels(
+        qkv, first, n, dh, out, lse, do, bq, bk, interpret, path
+    )
+
+
+@functools.partial(jax.jit, static_argnames=(*_KERNEL_STATICS, "path"))
+def _packed_backward_kernels(
+    qkv, first, n, dh, out, lse, do, bq, bk, interpret, path
+):
     b, t, _ = qkv[0].shape
     hb = _LANES // dh
     # delta_i = dO_i . O_i per head: one elementwise pass, lane-broadcast
@@ -905,6 +952,26 @@ def _packed_backward(qkv, first, n, dh, out, lse, do, bq, bk, interpret):
     third = jax.ShapeDtypeStruct((b, t, n * _LANES), qkv[0].dtype)
     q_at, k_at, v_at = (_lane_specs(f) for f in first)
     own, stats = _lane_specs(0), _stat_specs(hb)  # out, d out, d q / k / v
+    dkv_in = [
+        q_at(t, whole=True), k_at(bk), v_at(bk), own(t, whole=True),
+        stats(t, whole=True), stats(t, whole=True),
+    ]
+    if path == "fused":
+        dk, dv, dq = pl.pallas_call(
+            functools.partial(_packed_dkv_kernel, **kernel),
+            grid=(b, n, t // bk),
+            in_specs=dkv_in,
+            out_specs=[own(bk), own(bk), own(t, whole=True)],
+            out_shape=[third] * 3,
+            scratch_shapes=[pltpu.VMEM((t, _LANES), jnp.float32)],
+            compiler_params=_vmem_params(
+                "backward", t, _LANES, bq, bk, qkv[0].dtype,
+                **_fused_backward_blocks(t, _LANES, hb),
+            ),
+            interpret=interpret,
+            name="flash_packed_bwd",
+        )(*qkv, do, lse, delta)
+        return dq, dk, dv
 
     dq = pl.pallas_call(
         functools.partial(_packed_dq_kernel, **kernel),
@@ -926,10 +993,7 @@ def _packed_backward(qkv, first, n, dh, out, lse, do, bq, bk, interpret):
     dk, dv = pl.pallas_call(
         functools.partial(_packed_dkv_kernel, **kernel),
         grid=(b, n, t // bk),
-        in_specs=[
-            q_at(t, whole=True), k_at(bk), v_at(bk), own(t, whole=True),
-            stats(t, whole=True), stats(t, whole=True),
-        ],
+        in_specs=dkv_in,
         out_specs=[own(bk), own(bk)],
         out_shape=[third, third],
         compiler_params=_vmem_params(
@@ -1029,7 +1093,7 @@ def kernel_contract(t, heads, dh, dtype):
     blocks = attention_blocks(t)
     if blocks is None:
         return f"T={t} is not a multiple of the smallest block, 128"
-    try:
+    try:  # the dk/dv kernel's footprint: the least a backward path needs
         _vmem_params(
             "dk/dv", t, _LANES, *blocks, dtype,
             whole=2, tiles=4, stat_rows=2 * (_LANES // dh) * t,

@@ -280,10 +280,11 @@ def test_rematerialised_scan_on_eight_devices_runs_the_forward_once(
 ):
     """``remat_block(..., in_scan=True)`` under ``nn.scan``, the core under
     the ``shard_map`` a step's mesh asks for (the way GPT-2 XL's cell runs):
-    the gradient's scanned bodies hold three kernels (forward; dq and dk/dv
-    in the backward's body) under every policy, as many as with no remat at
-    all. A forward rule that did not name its residuals ran a fourth, the
-    forward again. Packed heads (2) and an odd count padded to lanes (3)."""
+    the gradient's scanned bodies hold two kernels (forward; the one fused
+    backward pass in the backward's body) under every policy, as many as
+    with no remat at all. A forward
+    rule that did not name its residuals ran one more, the forward again.
+    Packed heads (2) and an odd count padded to lanes (3)."""
     cfg = GPT2Config.tiny(
         n_embd=64 * heads, n_head=heads, n_positions=128, n_layer=2,
         scan_layers=True, remat=remat,
@@ -301,9 +302,9 @@ def test_rematerialised_scan_on_eight_devices_runs_the_forward_once(
         assert "shard_map" in jaxpr and "scan[" in jaxpr
         return jaxpr.count("pallas_call[")
 
-    assert kernels() == 3
+    assert kernels() == 2
     monkeypatch.setattr(pallas_attn, "_kept", lambda out, lse: (out, lse))
-    assert kernels() == (3 if remat is False else 4)
+    assert kernels() == (2 if remat is False else 3)
 
 
 def test_remat_says_what_it_keeps():

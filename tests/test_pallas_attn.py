@@ -1,5 +1,7 @@
 """Pallas flash attention vs XLA reference (interpret mode on CPU)."""
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -200,41 +202,138 @@ def test_the_fused_backward_is_the_two_kernels(
         np.testing.assert_allclose(f, a, rtol=ulp, atol=ulp * np.abs(a).max())
 
 
-@pytest.mark.parametrize(
-    "dh,path,calls", [(128, "fused", 2), (256, "two_kernels", 3)]
-)
-def test_the_backward_path_follows_its_vmem_footprint(dh, path, calls):
-    """At T = 16,384 and blocks of 512 the fused backward's footprint (Q,
-    dO and dQ whole, dQ's float32 accumulator) fits the ceiling at head
-    size 128 (73 MiB) and not at 256 (106 MiB), where the two kernels, which
-    need 74, still run: the choice, the instant ``flash.backward`` each
-    trace of the backward rule leaves, and the kernels the gradient holds
-    (forward + 1, or forward + 2) agree. Traced, not run."""
-    t, block = 16384, 512
-    assert pallas_attn.backward_path(t, dh, block, block, jnp.bfloat16)[0] == path
-    q = jax.ShapeDtypeStruct((1, t, 2, dh), jnp.bfloat16)
-
-    def loss(q, k, v):
-        out = flash_attention(q, k, v, True, block, block, False, 4096)
-        return jnp.sum(out.astype(jnp.float32))
-
+@contextlib.contextmanager
+def _backward_said():
+    """The ``path`` attributes, and the rest, of the ``flash.backward``
+    instants that the traces made inside leave."""
     tracer = trace.get_tracer()
     was = tracer.enabled
     trace.enable(crash_handler=False)
     trace.clear()
+    said = []
     try:
-        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
-        said = [
+        yield said
+        said.extend(
             r["attrs"] for r in trace.records()
             if r["name"] == "flash.backward"
-        ]
+        )
     finally:
         trace.clear()
         tracer.enabled = was
+
+
+def _packed_core(core, heads, dh, bq, bk, interpret):
+    """``(fn, last dims of its arguments, of its output)`` for a packed
+    core: ``qkv`` as the fused projection wrote it, or q, k, v padded to
+    whole lane blocks."""
+    if core == "qkv":
+        return (
+            lambda qkv: pallas_attn.flash_attention_qkv(
+                qkv, heads, bq, bk, interpret
+            ),
+            [3 * heads * dh], heads * dh,
+        )
+    lanes = -(-heads * dh // 128) * 128
+    return (
+        lambda q, k, v: pallas_attn.flash_attention_lanes(
+            q, k, v, dh, bq, bk, interpret
+        ),
+        [lanes] * 3, lanes,
+    )
+
+
+@pytest.mark.parametrize(
+    "family,t,dh,path,calls",
+    [
+        pytest.param("split_head", 16384, 128, "fused", 2, id="128-fused-2"),
+        pytest.param(
+            "split_head", 16384, 256, "two_kernels", 3, id="256-two_kernels-3"
+        ),
+        ("packed", 1024, 64, "fused", 2),
+        ("packed", 16384, 64, "two_kernels", 3),
+    ],
+)
+def test_the_backward_path_follows_its_vmem_footprint(
+    family, t, dh, path, calls
+):
+    """At blocks of 512 the fused backward's footprint (Q, dO and dQ whole,
+    dQ's float32 accumulator, the statistics of a block's heads) fits the
+    ceiling for split heads at T = 16,384 and head size 128 (73 MiB) and not
+    at 256 (106 MiB), where the two kernels, which need 74, still run; for
+    two packed heads of 64 at T = 1,024 (15 MiB) and not at 16,384 (105
+    MiB; the two kernels 89). The choice, the instant ``flash.backward``
+    each trace of the backward rule leaves, and the kernels the gradient
+    holds (forward + 1, or forward + 2) agree. Traced, not run."""
+    block = 512
+    if family == "split_head":
+        hb, lanes, window = 1, dh, 4096
+        fn = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v, True, block, block, False, window
+        )
+        widths = [(2, dh)] * 3
+    else:
+        hb, lanes, window = 128 // dh, 128, None
+        fn, widths, _ = _packed_core("qkv", 2, dh, block, block, False)
+        widths = [(w,) for w in widths]
+    args = [jax.ShapeDtypeStruct((1, t, *w), jnp.bfloat16) for w in widths]
+
+    def loss(*a):
+        return jnp.sum(fn(*a).astype(jnp.float32))
+
+    with _backward_said() as said:
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)[:len(args)]))(
+            *args
+        )
+    assert pallas_attn.backward_path(
+        t, lanes, block, block, jnp.bfloat16, hb
+    )[0] == path
     assert [s["path"] for s in said] == [path]
-    assert said[0]["t"] == t and said[0]["dh"] == dh and said[0]["window"] == 4096
+    assert said[0]["t"] == t and said[0]["dh"] == dh
+    assert said[0]["window"] == window
+    packed = family == "packed"
+    assert said[0].get("family") == ("packed" if packed else None)
+    assert said[0].get("hb") == (hb if packed else None)
     assert "MiB" in said[0]["reason"]
     assert str(jaxpr).count("pallas_call") == calls
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "core,heads,dh",
+    [("qkv", 4, 64), ("lanes", 5, 64), ("qkv", 2, 128), ("lanes", 3, 128)],
+)
+@pytest.mark.parametrize("bq,bk", [(64, 32), (32, 64)])
+def test_the_fused_packed_backward_is_the_two_kernels(
+    monkeypatch, core, heads, dh, dtype, bq, bk
+):
+    """The packed family's one pass makes the dq, dk and dv its dq and dk/dv
+    kernels make, to the bit: two heads of 64 a lane block read out of
+    ``qkv`` where they lie, an odd count padded to lanes with a head of
+    zeros behind (GPT-2 XL's layout), a head of 128 a block (a scale that is
+    no power of two), with several query and key blocks each way."""
+    fn, widths, width = _packed_core(core, heads, dh, bq, bk, True)
+    keys = jax.random.split(jax.random.PRNGKey(40), len(widths) + 1)
+    args = [
+        jax.random.normal(k, (2, 256, w), dtype) for k, w in zip(keys, widths)
+    ]
+    do = jax.random.normal(keys[-1], (2, 256, width), dtype)
+
+    def grads():
+        with _backward_said() as said:
+            _, vjp = jax.vjp(fn, *args)
+            out = vjp(do)
+        return out, [s["path"] for s in said]
+
+    fused, said = grads()
+    assert said == ["fused"]
+    monkeypatch.setattr(
+        pallas_attn, "backward_path", lambda *a: ("two_kernels", "asked")
+    )
+    apart, said = grads()
+    assert said == ["two_kernels"]
+    for f, a in zip(fused, apart):
+        assert f.dtype == a.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(f), np.asarray(a))
 
 
 def test_a_window_is_causal_and_heads_must_divide(qkv):

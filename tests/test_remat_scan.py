@@ -148,11 +148,9 @@ KERNEL_LAYERS = {
     "flash_attention_qkv": _layer_qkv,
     "flash_attention_lanes": _layer_lanes,
 }
-# a layer's gradient holds its forward kernel and these: the split-head
-# family's one backward pass, the packed family's dq and dk/dv
-BACKWARD_KERNELS = {
-    "flash_attention": 1, "flash_attention_qkv": 2, "flash_attention_lanes": 2,
-}
+# a layer's gradient holds its forward kernel and one backward pass, in both
+# families
+BACKWARD_KERNELS = 1
 
 
 def _layer_inputs():
@@ -186,7 +184,7 @@ def test_checkpointed_layer_runs_the_forward_kernel_once(kernel, policy):
     would else run the forward kernel again. Gradients equal the
     uncheckpointed layer's to the bit."""
     layer, args = KERNEL_LAYERS[kernel], _layer_inputs()
-    want = 1 + BACKWARD_KERNELS[kernel]
+    want = 1 + BACKWARD_KERNELS
     assert _kernels_in(_grad_of(layer, policy), *args) == want
     got = jax.jit(_grad_of(layer, policy))(*args)
     want = jax.jit(_grad_of(layer, "none"))(*args)
@@ -201,7 +199,7 @@ def test_untagged_forward_rule_runs_twice_under_full(kernel, monkeypatch):
     rematerialised computation holds a second forward kernel."""
     monkeypatch.setattr(pallas_attn, "_kept", lambda out, lse: (out, lse))
     layer, args = KERNEL_LAYERS[kernel], _layer_inputs()
-    want = 1 + BACKWARD_KERNELS[kernel]
+    want = 1 + BACKWARD_KERNELS
     assert _kernels_in(_grad_of(layer, "full"), *args) == want + 1
     assert _kernels_in(_grad_of(layer, "none"), *args) == want
 
