@@ -19,7 +19,10 @@ a norm on every head of q and k, a sigmoid gate on the core's output and a
 norm after each branch as well as before it; GLM's expert layer as it
 stands, 8 of 128 a token) and ``Lfm2Moe`` (LFM2-8B-A1B: gated short
 convolutions beside QK-normed grouped-query attention at heads of 64, the
-held-experts layer with no shared expert, a tied head).
+held-experts layer with no shared expert, a tied head) and ``KeyeVL2``
+(Keye-VL-2.0-30B-A3B's language trunk: a learned sparse-attention indexer
+picks the keys of QK-normed grouped-query attention and a KL loss trains
+it, softmax-routed held experts, M-RoPE).
 
 All models are Flax linen modules in NHWC (images) / [B, T, D] (sequences) —
 the layouts XLA:TPU tiles best — with bf16-friendly parameterization.
@@ -49,6 +52,8 @@ _LAZY = {
     "Glm4MoeLiteConfig": ".glm4_moe_lite",
     "Afmoe": ".afmoe",
     "AfmoeConfig": ".afmoe",
+    "KeyeVL2": ".keye_vl2",
+    "KeyeVL2Config": ".keye_vl2",
     "Lfm2Moe": ".lfm2_moe",
     "Lfm2MoeConfig": ".lfm2_moe",
     "SmallThinker": ".smallthinker",

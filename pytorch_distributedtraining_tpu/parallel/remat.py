@@ -72,7 +72,9 @@ import jax
 # the first kept by every policy that rematerialises, ``full`` included,
 # the second beside it where the kept residuals are stacked over a scan's
 # layers (kept, it is what the backward reads, and the padded ``lse`` is
-# pruned).
+# pruned). `ops/sparse_attn.py` gives the first name to its core's ``o`` and
+# ``lse`` and to the indexer's selection and ``lse_I``: a checkpointed
+# layer's backward reads the selection, it does not select again.
 KERNEL_RESIDUALS = "attn_kernel_residuals"
 KERNEL_LSE_ROWS = "attn_kernel_lse_rows"
 CHECKPOINT_SAVED_NAMES = ("attn_out", KERNEL_RESIDUALS)

@@ -18,6 +18,7 @@ hands the described devices and shapes to the jitted function itself.
 """
 
 import dataclasses
+import functools
 import os
 import re
 import subprocess
@@ -100,6 +101,11 @@ FLASH_GQA8, FLASH_GQA8_WINDOW = (1, 8192, 32, 128), 2048
 FLASH_GQA_64, FLASH_GQA_64_KV = (2, 4096, 32, 64), 8
 SHORT_CONV = (2, 4096, 2048, 3)
 GPT2_HEADS, GPT2_HEAD_DIM, PAGE = 12, 64, 16
+# Keye-VL-2.0-30B-A3B's cell: one sequence of 16,384, 32 query heads of 128
+# on 4 key-value heads, the indexer's 16 heads of 64 on one key head, 2,048
+# keys a query, blocks of 512
+SPARSE = (16384, 32, 4, 128, 16, 64)
+SPARSE_TOPK, SPARSE_BLOCK = 2048, 512
 
 
 def _window(dev, *, mask, grad, dtype=jnp.float32, shape=None):
@@ -144,6 +150,35 @@ def _flash(dev, *, dtype, grad, shape=FLASH, block=128, kv_heads=None,
             lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)), argnums=(0, 1, 2)
         ),
         (qkv, kv, kv),
+    )
+
+
+def _sparse(dev, *, part):
+    """The learned sparse attention of ``ops/sparse_attn.py`` at the cell's
+    widths: the selection alone, or the core and the KL's value with their
+    gradient (the selection given)."""
+    from pytorch_distributedtraining_tpu.ops import sparse_attn
+
+    t, h, kvh, d, hi, di = SPARSE
+    qi, ki = _on(dev, (hi, t, di), jnp.bfloat16), _on(dev, (t, di), jnp.bfloat16)
+    if part == "select":
+        return functools.partial(
+            sparse_attn.sparse_select, topk=SPARSE_TOPK, blk=SPARSE_BLOCK
+        ), (qi, ki, _on(dev, (t, hi), jnp.float32))
+    n = t // SPARSE_BLOCK
+    selection = _on(dev, (n, n, SPARSE_BLOCK // 32, SPARSE_BLOCK), jnp.int32)
+    lse_i = _on(dev, (t, 8), jnp.float32)
+
+    def loss(q, k, v, qi, ki, w, selection, lse_i):
+        o, kl = sparse_attn.sparse_attention(
+            q, k, v, qi, ki, w, selection, lse_i, SPARSE_BLOCK
+        )
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(kl)
+
+    return jax.value_and_grad(loss, argnums=tuple(range(6))), (
+        _on(dev, (h, t, d), jnp.bfloat16), _on(dev, (kvh, t, d), jnp.bfloat16),
+        _on(dev, (kvh, t, d), jnp.bfloat16), qi, ki,
+        _on(dev, (t, hi), jnp.float32), selection, lse_i,
     )
 
 
@@ -340,6 +375,10 @@ KERNEL_CASES = {
     ),
     "core_qkv_bwd_two_kernels_past_it": (
         lambda d: _causal_qkv(d, shape=CORE_TWO_KERNELS, grad=True), True,
+    ),
+    "sparse_select_keye_16k": (lambda d: _sparse(d, part="select"), True),
+    "sparse_core_and_kl_fwd_bwd_keye_16k": (
+        lambda d: _sparse(d, part="core"), True,
     ),
     "grouped_matmul_fwd": (lambda d: _grouped(d, grad=False), True),
     "grouped_matmul_bwd": (lambda d: _grouped(d, grad=True), True),
@@ -793,6 +832,35 @@ def test_lfm2_cell_step_one_chip(topo):
     )
     for scope in ("/short_conv/", "/qk_norm/", "/router/", "/experts/",
                   "/mlp_dense/", "/head/"):
+        assert scope in text, scope
+
+
+@pytest.mark.slow
+def test_keye_cell_step_one_chip(topo):
+    """``keye-vl-2.0-30b-a3b.train-16k`` as the benchmark builds it (its
+    family, its job's ``plan``): per layer the indexer's selection once (its
+    bits and ``lse_I`` are kept under remat, not selected again), the
+    core's forward once (its ``o`` and ``lse`` kept), the KL's value once
+    and the core's two backward kernels; the grouped matmuls as in the
+    other sparse cells; no ``[16384, 16384]`` array of any type anywhere;
+    the plan under the 15.75 GB the compiler may use and over a quarter of
+    the chip."""
+    text, planned = _cell_step(topo, "keye-vl-2.0-30b-a3b.train-16k")
+    assert 4e9 < planned < 15.75e9
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    for name, scope in (
+        ("sparse_select", "/indexer/"), ("sparse_kl_fwd", "/indexer/"),
+        ("sparse_core_fwd", "/sparse_attention/"),
+        ("sparse_core_dq", "/sparse_attention/"),
+        ("sparse_core_dkv", "/sparse_attention/"),
+    ):
+        mine = [line for line in calls if re.search(rf"%{name}(\.\d+)? = ", line)]
+        assert len(mine) == 4, name
+        assert all(scope in line for line in mine), name
+    assert _kernel_calls(text) == 5 * 4 + 4 * 12  # and the grouped matmuls
+    assert not re.findall(r"\[(?:\d+,)*16384,16384\]", text)
+    for scope in ("/indexer/", "/sparse_attention/", "/qk_norm/", "/router/",
+                  "/experts/", "/head/"):
         assert scope in text, scope
 
 
