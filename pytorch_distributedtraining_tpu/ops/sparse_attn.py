@@ -12,7 +12,7 @@ For one sequence, query ``t`` and key ``s <= t``::
     kl[t]   = KL(p_t || softmax(I[t, S_t]))
 
 ``w`` arrives with the indexer's scales folded in (``HI^-1/2 DI^-1/2``). No
-``[T, T]`` array is made. Five Pallas kernels, on blocks of ``blk`` queries
+``[T, T]`` array is made. Four Pallas kernels, on blocks of ``blk`` queries
 and ``blk`` keys (``blk`` a multiple of 32), causal blocks only:
 
 - ``sparse_select``: one program a query block scores its causal key blocks
@@ -31,11 +31,20 @@ and ``blk`` keys (``blk`` a multiple of 32), causal blocks only:
 - ``sparse_kl_fwd`` (``kl_divergence``): ``p`` from the core's scores and
   statistics, ``I`` again, and per row ``sum p log p`` and ``sum p I``, so
   that ``kl = those two + lse_I``: a value, under ``stop_gradient``.
-- ``sparse_core_dq`` / ``sparse_core_dkv``: the core's backward (dQ over the
-  key blocks of a query block; dK, dV over the query blocks of a key block),
-  each with the indexer's part of the KL's gradient beside it, since both
-  already make every head's ``P``: ``dI = g_kl (softmax_S(I) - p)`` on the
-  selection, then ``dw``, ``dqI`` in the first and ``dkI`` in the second.
+- ``sparse_core_dkv``: the core's backward in one pass over the causal
+  tiles, query block outer. Each tile's ``S``, ``P``, ``dO V^T`` and ``dS``
+  of every head, and the indexer's scores, are made once; beside the core's
+  gradient the indexer's part of the KL's gradient, from the same ``P``:
+  ``dI = g_kl (softmax_S(I) - p)`` on the selection, then ``dw``, ``dqI``
+  and ``dkI``. dQ and dqI are float32 VMEM for the query block, written at
+  the diagonal; a key tile's dK, dV and ``dkI^T`` are float32 HBM tiles
+  (``[KVH, T, D]`` twice and ``[DI, T]``: 68 MiB at T = 16,384, 4 kv
+  heads of 128, ``DI`` 64), read into one of two VMEM slots while the
+  other's are written home, added to, and written back (initialised at the
+  tile's first visit, the diagonal); scaled and cast after the kernel. Its
+  VMEM does not grow with ``T``: blocks and scratch of about 38 MiB at
+  blocks of 512, 32 heads of 128 and 16 indexer heads of 64, besides a
+  tile's ``[blk, blk]`` temporaries.
 
 ``sparse_core`` is the ``custom_vjp`` of the core: ``(o, lse, zero)`` of q,
 k, v and the indexer's ``qI``, ``kI``, ``w``, given the selection. ``zero``
@@ -374,10 +383,37 @@ def _grad_of_scores(keep, p, qi_ref, ki, w, lse_i_ref, gkl_ref):
     return gkl_ref[:, :1] * (q_of_i - p)
 
 
-def _core_dq_kernel(sel_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    qi_ref, ki_ref, w_ref, lse_i_ref, gkl_ref,
-                    dq_ref, dqi_ref, dw_ref, dq_acc, dqi_acc, *, group, scale):
+def _core_bwd_kernel(sel_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                     delta_ref, qi_ref, ki_ref, w_ref, lse_i_ref, gkl_ref,
+                     dq_ref, dqi_ref, dw_ref, dk_hbm, dv_hbm, dki_hbm,
+                     dq_acc, dqi_acc, dk_buf, dv_buf, dki_buf, sems, *,
+                     group, scale):
     i, j = pl.program_id(0), pl.program_id(1)
+    blk = q_ref.shape[1]
+    n = dk_hbm.shape[1] // blk
+    # the live tiles in grid order; consecutive ones take the two slots in
+    # turn, a key tile's float32 sums read into one while the other's are
+    # written home
+    step = jax.lax.shift_right_logical(i * (i + 1), 1) + j
+    slot = step & 1
+
+    def copies(s, col, read):
+        at = pl.ds(pl.multiple_of(col * blk, blk), blk)
+        pairs = ((dk_hbm.at[:, at], dk_buf.at[s]),
+                 (dv_hbm.at[:, at], dv_buf.at[s]),
+                 (dki_hbm.at[:, at], dki_buf.at[s]))
+        return [
+            pltpu.make_async_copy(*(p if read else p[::-1]), sems.at[int(read), s])
+            for p in pairs
+        ]
+
+    def start(s, col, read):
+        for c in copies(s, col, read):
+            c.start()
+
+    def wait(s, col, read):
+        for c in copies(s, col, read):
+            c.wait()
 
     @pl.when(j == 0)
     def _():
@@ -387,15 +423,45 @@ def _core_dq_kernel(sel_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(j <= i)
     def _():
+        dk, dv, dki = dk_buf.at[slot], dv_buf.at[slot], dki_buf.at[slot]
+
+        @pl.when(j < i)
+        def _():
+            wait(slot, j, read=True)
+
+        @pl.when(j == i)  # the key tile's first visit
+        def _():
+            dk[...] = jnp.zeros(dk.shape, jnp.float32)
+            dv[...] = jnp.zeros(dv.shape, jnp.float32)
+            dki[...] = jnp.zeros(dki.shape, jnp.float32)
+
         keep = _unpack(sel_ref[0, 0])
         delta, w = delta_ref[...], w_ref[...]
 
         def each(h, kv, p):
-            dp = _dot(do_ref[h], v_ref[kv], _NT)
+            do = do_ref[h]
+            dv[kv] += _dot(p.astype(do.dtype), do, _TN)
+            dp = _dot(do, v_ref[kv], _NT)
             ds = p * (dp - _column(delta, h))
+            dk[kv] += _dot(ds.astype(q_ref.dtype), q_ref[h], _TN)
             dq_acc[h] += _dot(ds.astype(k_ref.dtype), k_ref[kv], _NN)
 
         p = _head_probs(keep, q_ref, k_ref, lse_ref, group, scale, each)
+
+        # the previous tile's sums are home, so its slot can take the next
+        # live tile's, whose last write (a row back) is home too
+        prev = jnp.where(j > 0, j - 1, i - 1)
+        nxt_i = jnp.where(j < i, i, i + 1)
+        nxt_j = jnp.where(j < i, j + 1, 0)
+
+        @pl.when(step >= 2)
+        def _():
+            wait(1 - slot, prev, read=False)
+
+        @pl.when((step >= 1) & (nxt_j < nxt_i) & (nxt_i < n))
+        def _():
+            start(1 - slot, nxt_j, read=True)
+
         ki = ki_ref[...]
         d_score = _grad_of_scores(
             keep, p * (1.0 / q_ref.shape[0]), qi_ref, ki, w, lse_i_ref,
@@ -408,11 +474,24 @@ def _core_dq_kernel(sel_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dw_ref[...] += jnp.where(
                 lane == h, _rowsum(d_score * jnp.maximum(z, 0.0)), 0.0
             )
-            dz = jnp.where(z > 0, d_score * _column(w, h), 0.0)
-            dqi_acc[h] += _dot(dz.astype(ki.dtype), ki, _NN)
+            dz = jnp.where(z > 0, d_score * _column(w, h), 0.0).astype(ki.dtype)
+            dqi_acc[h] += _dot(dz, ki, _NN)
+            dki[...] += _dot(qi_ref[h], dz, _TN)  # dkI transposed
             return c
 
         jax.lax.fori_loop(0, qi_ref.shape[0], head, 0)
+        start(slot, j, read=False)
+
+        # the first tile is read back in the next step: no step in between
+        # to hide its write
+        @pl.when((step == 0) & (n > 1))
+        def _():
+            wait(slot, j, read=False)
+            start(1 - slot, 0, read=True)
+
+        @pl.when(step == n * (n + 1) // 2 - 1)
+        def _():
+            wait(slot, j, read=False)
 
     @pl.when(j == i)
     def _():
@@ -420,73 +499,27 @@ def _core_dq_kernel(sel_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dqi_ref[...] = dqi_acc[...].astype(dqi_ref.dtype)
 
 
-def _core_dkv_kernel(sel_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                     delta_ref, qi_ref, ki_ref, w_ref, lse_i_ref, gkl_ref,
-                     dk_ref, dv_ref, dki_ref, dk_acc, dv_acc, dki_acc, *,
-                     group, scale):
-    j, i = pl.program_id(0), pl.program_id(1)  # key block, query block
-
-    @pl.when(i == 0)
-    def _():
-        dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
-        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
-        dki_acc[...] = jnp.zeros(dki_acc.shape, jnp.float32)
-
-    @pl.when(i >= j)
-    def _():
-        keep = _unpack(sel_ref[0, 0])
-        delta, w = delta_ref[...], w_ref[...]
-
-        def each(h, kv, p):
-            do = do_ref[h]
-            dv_acc[kv] += _dot(p.astype(do.dtype), do, _TN)
-            dp = _dot(do, v_ref[kv], _NT)
-            ds = p * (dp - _column(delta, h))
-            dk_acc[kv] += _dot(ds.astype(q_ref.dtype), q_ref[h], _TN)
-
-        p = _head_probs(keep, q_ref, k_ref, lse_ref, group, scale, each)
-        ki = ki_ref[...]
-        d_score = _grad_of_scores(
-            keep, p * (1.0 / q_ref.shape[0]), qi_ref, ki, w, lse_i_ref,
-            gkl_ref,
-        )
-
-        def head(h, c):
-            z = _dot(qi_ref[h], ki, _NT)
-            dz = jnp.where(z > 0, d_score * _column(w, h), 0.0)
-            dki_acc[...] += _dot(dz.astype(ki.dtype), qi_ref[h], _TN)
-            return c
-
-        jax.lax.fori_loop(0, qi_ref.shape[0], head, 0)
-
-    @pl.when(i == pl.num_programs(1) - 1)
-    def _():
-        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
-        dki_ref[...] = dki_acc[...].astype(dki_ref.dtype)
-
-
-def _specs(blk, row, col):
-    """BlockSpecs of a (row block, column block) grid: ``row(a, b)`` and
-    ``col(a, b)`` give the query and the key block of grid step ``(a, b)``."""
+def _specs(blk):
+    """BlockSpecs of a (query block i, key block j) grid; the tiles past the
+    diagonal fetch nothing new."""
     sel = pl.BlockSpec(
-        (1, 1, blk // 32, blk), lambda a, b: (row(a, b), col(a, b), 0, 0)
+        (1, 1, blk // 32, blk), lambda i, j: (i, _tile(i, j), 0, 0)
     )
 
     def by_query(lead, lanes):
         # fetched once a row of tiles: one buffer is room enough
         return pl.BlockSpec(
-            (lead, blk, lanes), lambda a, b: (0, row(a, b), 0),
+            (lead, blk, lanes), lambda i, j: (0, i, 0),
             pipeline_mode=pl.Buffered(1),
         )
 
     def by_key(lead, lanes):
         if lead is None:
-            return pl.BlockSpec((blk, lanes), lambda a, b: (col(a, b), 0))
-        return pl.BlockSpec((lead, blk, lanes), lambda a, b: (0, col(a, b), 0))
+            return pl.BlockSpec((blk, lanes), lambda i, j: (_tile(i, j), 0))
+        return pl.BlockSpec((lead, blk, lanes), lambda i, j: (0, _tile(i, j), 0))
 
     def rows(lanes=_STAT):
-        return pl.BlockSpec((blk, lanes), lambda a, b: (row(a, b), 0))
+        return pl.BlockSpec((blk, lanes), lambda i, j: (i, 0))
 
     return sel, by_query, by_key, rows
 
@@ -504,7 +537,7 @@ def _core_forward(q, k, v, selection, *, blk, interpret):
     """-> ``(o [H, T, D], lse [H, T, 8])``."""
     heads, t, d = q.shape
     kvh, n = k.shape[0], t // blk
-    sel, by_query, by_key, rows = _specs(blk, lambda i, j: i, _tile)
+    sel, by_query, by_key, rows = _specs(blk)
     return pl.pallas_call(
         functools.partial(
             _core_fwd_kernel, group=heads // kvh, scale=d**-0.5
@@ -535,7 +568,7 @@ def kl_divergence(q, k, lse, qi, ki, w, selection, lse_i, *, blk,
     A value only: its gradient is ``sparse_core``'s."""
     c = _statics(q, k, qi)
     n = c["t"] // blk
-    sel, by_query, by_key, rows = _specs(blk, lambda i, j: i, _tile)
+    sel, by_query, by_key, rows = _specs(blk)
     ent, p_i = pl.pallas_call(
         functools.partial(_kl_fwd_kernel, group=c["group"], scale=c["scale"]),
         grid=(n, n),
@@ -560,61 +593,43 @@ def _backward(q, k, v, qi, ki, w, selection, lse_i, o, lse, do, gkl, *, blk,
     n = c["t"] // blk
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1).T
     gkl = jnp.broadcast_to(gkl.astype(jnp.float32)[:, None], (c["t"], _STAT))
-    kernel = dict(group=c["group"], scale=c["scale"])
-    args = (selection, q, k, v, do, lse, delta, qi, ki, w, lse_i, gkl)
-
-    def inputs(sel, by_query, by_key, rows):
-        return [
+    sel, by_query, by_key, rows = _specs(blk)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    dq, dqi, dw, dk, dv, dki = pl.pallas_call(
+        functools.partial(_core_bwd_kernel, group=c["group"], scale=c["scale"]),
+        grid=(n, n),
+        in_specs=[
             sel, by_query(c["heads"], c["d"]), by_key(c["kvh"], c["d"]),
             by_key(c["kvh"], c["d"]), by_query(c["heads"], c["d"]),
             rows(c["heads"]), rows(c["heads"]),
             by_query(c["hi"], c["di"]), by_key(None, c["di"]),
             rows(c["hi"]), rows(), rows(),
-        ]
-
-    sel, by_query, by_key, rows = _specs(blk, lambda i, j: i, _tile)
-    dq, dqi, dw = pl.pallas_call(
-        functools.partial(_core_dq_kernel, **kernel),
-        grid=(n, n),
-        in_specs=inputs(sel, by_query, by_key, rows),
+        ],
         out_specs=[by_query(c["heads"], c["d"]), by_query(c["hi"], c["di"]),
-                   rows(c["hi"])],
+                   rows(c["hi"]), hbm, hbm, hbm],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(qi.shape, qi.dtype),
             jax.ShapeDtypeStruct(w.shape, jnp.float32),
+            jax.ShapeDtypeStruct(k.shape, jnp.float32),
+            jax.ShapeDtypeStruct(v.shape, jnp.float32),
+            jax.ShapeDtypeStruct(ki.shape[::-1], jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((c["heads"], blk, c["d"]), jnp.float32),
             pltpu.VMEM((c["hi"], blk, c["di"]), jnp.float32),
+            pltpu.VMEM((2, c["kvh"], blk, c["d"]), jnp.float32),
+            pltpu.VMEM((2, c["kvh"], blk, c["d"]), jnp.float32),
+            pltpu.VMEM((2, c["di"], blk), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
-        compiler_params=_params("parallel", "arbitrary"),
-        interpret=interpret,
-        name="sparse_core_dq",
-    )(*args)
-    # grid (key block j, query block i): the query blocks before the
-    # diagonal do not run and fetch nothing new
-    specs = _specs(blk, lambda j, i: jnp.maximum(i, j), lambda j, i: j)
-    dk, dv, dki = pl.pallas_call(
-        functools.partial(_core_dkv_kernel, **kernel),
-        grid=(n, n),
-        in_specs=inputs(*specs),
-        out_specs=[specs[2](c["kvh"], c["d"]), specs[2](c["kvh"], c["d"]),
-                   specs[2](None, c["di"])],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-            jax.ShapeDtypeStruct(ki.shape, ki.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((c["kvh"], blk, c["d"]), jnp.float32),
-            pltpu.VMEM((c["kvh"], blk, c["d"]), jnp.float32),
-            pltpu.VMEM((blk, c["di"]), jnp.float32),
-        ],
-        compiler_params=_params("parallel", "arbitrary"),
+        # the key tiles' sums carry from one row of tiles to the next
+        compiler_params=_params("arbitrary", "arbitrary"),
         interpret=interpret,
         name="sparse_core_dkv",
-    )(*args)
+    )(selection, q, k, v, do, lse, delta, qi, ki, w, lse_i, gkl)
+    dk = (dk * c["scale"]).astype(k.dtype)
+    dv, dki = dv.astype(v.dtype), dki.T.astype(ki.dtype)
     return dq, dk, dv, dqi, dki, dw
 
 

@@ -841,7 +841,7 @@ def test_keye_cell_step_one_chip(topo):
     family, its job's ``plan``): per layer the indexer's selection once (its
     bits and ``lse_I`` are kept under remat, not selected again), the
     core's forward once (its ``o`` and ``lse`` kept), the KL's value once
-    and the core's two backward kernels; the grouped matmuls as in the
+    and the core's one backward kernel; the grouped matmuls as in the
     other sparse cells; no ``[16384, 16384]`` array of any type anywhere;
     the plan under the 15.75 GB the compiler may use and over a quarter of
     the chip."""
@@ -851,13 +851,12 @@ def test_keye_cell_step_one_chip(topo):
     for name, scope in (
         ("sparse_select", "/indexer/"), ("sparse_kl_fwd", "/indexer/"),
         ("sparse_core_fwd", "/sparse_attention/"),
-        ("sparse_core_dq", "/sparse_attention/"),
         ("sparse_core_dkv", "/sparse_attention/"),
     ):
         mine = [line for line in calls if re.search(rf"%{name}(\.\d+)? = ", line)]
         assert len(mine) == 4, name
         assert all(scope in line for line in mine), name
-    assert _kernel_calls(text) == 5 * 4 + 4 * 12  # and the grouped matmuls
+    assert _kernel_calls(text) == 4 * 4 + 4 * 12  # and the grouped matmuls
     assert not re.findall(r"\[(?:\d+,)*16384,16384\]", text)
     for scope in ("/indexer/", "/sparse_attention/", "/qk_norm/", "/router/",
                   "/experts/", "/head/"):

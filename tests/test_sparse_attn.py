@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from pytorch_distributedtraining_tpu.ops import sparse_attn
 
@@ -15,19 +16,19 @@ T, BLK, TOPK = 96, 32, 16
 H, KVH, D, HI, DI = 4, 1, 16, 2, 16
 
 
-def _inputs(seed, dtype=jnp.float32, integer=False):
+def _inputs(seed, dtype=jnp.float32, integer=False, t=T, kvh=KVH):
     keys = jax.random.split(jax.random.PRNGKey(seed), 6)
     if integer:  # small integers: every score exact, ties planted
-        qi = jax.random.randint(keys[3], (HI, T, DI), -2, 3).astype(dtype)
-        ki = jax.random.randint(keys[4], (T, DI), -2, 3).astype(dtype)
-        w = jax.random.randint(keys[5], (HI, T), -2, 3).astype(jnp.float32)
+        qi = jax.random.randint(keys[3], (HI, t, DI), -2, 3).astype(dtype)
+        ki = jax.random.randint(keys[4], (t, DI), -2, 3).astype(dtype)
+        w = jax.random.randint(keys[5], (HI, t), -2, 3).astype(jnp.float32)
     else:
-        qi = jax.random.normal(keys[3], (HI, T, DI), dtype)
-        ki = jax.random.normal(keys[4], (T, DI), dtype)
-        w = jax.random.normal(keys[5], (HI, T), jnp.float32)
-    q = jax.random.normal(keys[0], (H, T, D), dtype)
-    k = jax.random.normal(keys[1], (KVH, T, D), dtype)
-    v = jax.random.normal(keys[2], (KVH, T, D), dtype)
+        qi = jax.random.normal(keys[3], (HI, t, DI), dtype)
+        ki = jax.random.normal(keys[4], (t, DI), dtype)
+        w = jax.random.normal(keys[5], (HI, t), jnp.float32)
+    q = jax.random.normal(keys[0], (H, t, D), dtype)
+    k = jax.random.normal(keys[1], (kvh, t, D), dtype)
+    v = jax.random.normal(keys[2], (kvh, t, D), dtype)
     return q, k, v, qi, ki, w
 
 
@@ -113,12 +114,15 @@ def test_core_and_kl_forward():
     assert float(jnp.min(kl)) >= -1e-5 and float(jnp.max(kl)) > 0
 
 
-def test_gradients_of_both_outputs():
-    q, k, v, qi, ki, w = _inputs(4)
-    sel, lse_i = _select(qi, ki, w)
+@pytest.mark.parametrize("t", [96, 128])  # key tile 0's sums read back 2, 3 times
+@pytest.mark.parametrize("topk", [16, 40])  # 40: rows 32-39 take their whole past
+@pytest.mark.parametrize("kvh", [1, 2])
+def test_gradients_of_both_outputs(kvh, topk, t):
+    q, k, v, qi, ki, w = _inputs(4, t=t, kvh=kvh)
+    sel, lse_i = _select(qi, ki, w, topk=topk)
     mask = sparse_attn.unpack_selection(sel)
-    c_o = jax.random.normal(jax.random.PRNGKey(9), (H, T, D))
-    c_kl = jax.random.uniform(jax.random.PRNGKey(10), (T,))
+    c_o = jax.random.normal(jax.random.PRNGKey(9), (H, t, D))
+    c_kl = jax.random.uniform(jax.random.PRNGKey(10), (t,))
 
     def kernels(q, k, v, qi, ki, w):
         o, kl = sparse_attn.sparse_attention(
@@ -136,6 +140,27 @@ def test_gradients_of_both_outputs():
     for name, a, b in zip(("q", "k", "v", "qi", "ki", "w"), got, want):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=name)
 
+
+@pytest.mark.parametrize("t", [32, 64, 128])
+def test_backward_under_the_tpu_interpreter(t):
+    """The one backward pass under the interpreter that models the TPU's
+    DMAs (each copy lands at its wait, fresh buffers read NaN): every key
+    tile's float32 sums are read back only after their write is waited on,
+    so the gradients are those of the plain interpreter to the bit."""
+    q, k, v, qi, ki, w = (a.astype(jnp.bfloat16) for a in _inputs(11, t=t, kvh=2))
+    w = _rows(w.astype(jnp.float32))
+    sel, lse_i = sparse_attn.sparse_select(qi, ki, w, topk=TOPK, blk=BLK,
+                                           interpret=True)
+    o, lse = sparse_attn._core_forward(q, k, v, sel, blk=BLK, interpret=True)
+    do = jax.random.normal(jax.random.PRNGKey(12), q.shape, q.dtype)
+    gkl = jax.random.uniform(jax.random.PRNGKey(13), (t,))
+    args = (q, k, v, qi, ki, w, sel, lse_i, o, lse, do, gkl)
+    tpu = pltpu.InterpretParams(uninitialized_memory="nan")
+    got = sparse_attn._backward(*args, blk=BLK, interpret=tpu)
+    want = sparse_attn._backward(*args, blk=BLK, interpret=True)
+    for name, a, b in zip(("dq", "dk", "dv", "dqi", "dki", "dw"), got, want):
+        assert a.dtype == b.dtype, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
 
 def test_output_gradient_never_reaches_the_indexer():
     q, k, v, qi, ki, w = _inputs(5)
